@@ -131,6 +131,9 @@ def main() -> None:
 
     if os.environ.get("JAX_PLATFORMS", "") == "cpu":
         jax.config.update("jax_platforms", "cpu")
+    from greptimedb_tpu.compile.xla_cache import configure_xla_cache
+
+    configure_xla_cache()
     from greptimedb_tpu.utils.telemetry import REGISTRY
 
     db = build_db()

@@ -158,6 +158,9 @@ def main() -> None:
 
     import jax
 
+    from greptimedb_tpu.compile.xla_cache import configure_xla_cache
+
+    configure_xla_cache()
     backend = jax.default_backend()
     print(f"backend={backend} groups={args.groups} rows/batch={args.rows}")
 

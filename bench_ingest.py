@@ -213,6 +213,9 @@ def main() -> None:
 
     if os.environ.get("JAX_PLATFORMS", "") == "cpu":
         jax.config.update("jax_platforms", "cpu")
+    from greptimedb_tpu.compile.xla_cache import configure_xla_cache
+
+    configure_xla_cache()
     backend = jax.devices()[0].platform
 
     import tempfile

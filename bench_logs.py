@@ -139,6 +139,9 @@ def counters() -> dict:
 def main() -> None:
     import jax
 
+    from greptimedb_tpu.compile.xla_cache import configure_xla_cache
+
+    configure_xla_cache()
     from greptimedb_tpu.servers import HttpServer
     from greptimedb_tpu.standalone import GreptimeDB
 

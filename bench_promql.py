@@ -164,6 +164,9 @@ def main() -> None:
     signal.signal(signal.SIGINT, _on_term)
     if os.environ.get("JAX_PLATFORMS"):
         jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    from greptimedb_tpu.compile.xla_cache import configure_xla_cache
+
+    configure_xla_cache()
 
     global _backend
     db = build_db()

@@ -279,8 +279,9 @@ KNOB_DOCS: dict[str, str] = {
         "Idle-economy weight overrides, `name=weight,...` (substring "
         "match on the consumer name)."),
     "GREPTIME_SORTED_SEGMENTS": (
-        "Segment-reduction strategy: `auto` picks scatter on CPU / "
-        "sorted on TPU; `force`/`off` override for A/B."),
+        "Segment-reduction strategy: `auto` and `off` take the scatter "
+        "form on every backend; `force` takes the sorted form where "
+        "the layout allows it (A/B, tests)."),
     "GREPTIME_TENANT_INFLIGHT": (
         "Default per-tenant concurrent-query cap (0 = unlimited)."),
     "GREPTIME_TENANT_MEM_BYTES": (

@@ -15,6 +15,7 @@ import sys
 def cmd_standalone(args) -> int:
     import jax
 
+    from greptimedb_tpu.compile.xla_cache import configure_xla_cache
     from greptimedb_tpu.servers import HttpServer
     from greptimedb_tpu.standalone import GreptimeDB
     from greptimedb_tpu.storage.region import RegionOptions
@@ -27,6 +28,7 @@ def cmd_standalone(args) -> int:
         opts.http.addr = args.http_addr
     if opts.device.platform:
         jax.config.update("jax_platforms", opts.device.platform)
+    configure_xla_cache()
     db = GreptimeDB(
         opts.storage.data_home,
         region_options=RegionOptions(
@@ -121,7 +123,10 @@ def cmd_datanode(args) -> int:
         import jax
 
         jax.config.update("jax_platforms", args.platform)
+    from greptimedb_tpu.compile.xla_cache import configure_xla_cache
     from greptimedb_tpu.rpc.datanode import serve
+
+    configure_xla_cache()
 
     serve(args.node_id, args.data_home, host=args.host, port=args.port,
           managed=args.managed, remote_wal_dir=args.remote_wal_dir)

@@ -24,8 +24,8 @@ The three legs of ROADMAP open item 5 (the compile-latency attack):
   disk in a CRC-enveloped store (the PR-9 GTM1 discipline) keyed by
   (shape-class fingerprint, jaxlib version, backend, device topology,
   machine), so a restarted node recompiles nothing it has seen before.
-  ``GREPTIME_COMPILE_CACHE=on`` additionally wires jax's own
-  ``jax_compilation_cache_dir`` hook so non-routed jits persist too.
+  ``GREPTIME_COMPILE_CACHE=on`` additionally places jax's own
+  compilation cache (``xla_cache.py``) so non-routed jits persist too.
 
 - **AOT warmup** (``warmup.py`` + ``journal.py``): a per-instance usage
   journal records each shape class with enough replay context (the

@@ -13,9 +13,10 @@ invoking the builder directly.  The compiler then:
 3. otherwise returns a kernel that lowers + compiles on first call and
    persists the executable for every later process.
 
-Everything is reject-to-fallback: an unconfigured store, an anonymous
-class, a serialization failure, or an artifact that refuses its
-arguments all degrade to exactly the pre-existing ``jax.jit`` path.
+An unconfigured store, an anonymous class, a serialization failure, or
+an artifact that refuses its arguments (signature drift) all degrade to
+exactly the pre-existing ``jax.jit`` path; an error from the compiler
+or the device does not — it propagates.
 """
 
 from __future__ import annotations
@@ -54,11 +55,19 @@ M_CACHE_DISK = REGISTRY.gauge(
 )
 
 
+# What a ``Compiled`` raises when handed arguments that differ from the
+# ones it was lowered for (pytree structure, shape, dtype, sharding) —
+# signature drift, the one failure the kernels below answer by going
+# back to jit.  Errors from the compiler or the device (XlaRuntimeError
+# / JaxRuntimeError) are not drift and propagate.
+_DRIFT = (TypeError, ValueError)
+
+
 class _PersistingKernel:
     """Fresh build: lower+compile on first call (inside the caller's
     timed compile phase, so device-phase attribution stays honest), then
     persist the executable.  Falls back to the plain jitted function when
-    AOT lowering/serialization is unsupported for this program."""
+    the arguments drift from the signature it was compiled for."""
 
     aot = False
 
@@ -72,22 +81,18 @@ class _PersistingKernel:
         # executor lock; a racing duplicate first-call would just compile
         # twice and persist last-writer-wins (atomic file replace)
         if self._compiled is None:
-            try:
-                compiled = self._jitted.lower(*args).compile()
-            except Exception:  # noqa: BLE001 — AOT unsupported: plain jit
-                M_COMPILE_EVENTS.labels("persist_error").inc()
-                self._compiled = self._jitted
-            else:
-                self._compiled = compiled
-                self._persist_cb(compiled)
+            # a program the compiler or the device refuses raises here,
+            # as it would under plain jit
+            self._compiled = self._jitted.lower(*args).compile()
+            self._persist_cb(self._compiled)
         if self._compiled is self._jitted:
             return self._jitted(*args)
         try:
             return self._compiled(*args)
-        except Exception:  # noqa: BLE001 — a Compiled is pytree/shape-
-            # STRICT where jit would retrace (signature drift the class
-            # key failed to capture): restore jit semantics permanently
-            # for this class and re-execute
+        except _DRIFT:
+            # a Compiled is pytree/shape-STRICT where jit would retrace
+            # (signature drift the class key failed to capture): restore
+            # jit semantics permanently for this class and re-execute
             M_COMPILE_EVENTS.labels("fallback").inc()
             self._compiled = self._jitted
             return self._jitted(*args)
@@ -108,7 +113,7 @@ class _AotKernel:
     def __call__(self, *args):
         try:
             return self._fn(*args)
-        except Exception:  # noqa: BLE001 — drift: one rebuild, then real
+        except _DRIFT:  # one rebuild, then the real error
             if self._rebuild is None:
                 raise
             M_COMPILE_EVENTS.labels("fallback").inc()

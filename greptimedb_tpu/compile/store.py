@@ -192,10 +192,17 @@ class ArtifactStore:
                 return None
             if canon is not None and doc.get("canon") not in (None, canon):
                 raise ValueError("artifact canon mismatch")
+            import jax
             from jax.experimental import serialize_executable as _se
 
+            # load onto the devices the program was compiled for (one
+            # device, or a mesh's): the default is EVERY device of the
+            # backend, which breaks a one-device program's first call
+            # wherever more than one device is visible
+            by_id = {d.id: d for d in jax.devices()}
             fn = _se.deserialize_and_load(
-                doc["payload"], doc["in_tree"], doc["out_tree"])
+                doc["payload"], doc["in_tree"], doc["out_tree"],
+                execution_devices=[by_id[i] for i in doc["devices"]])
         except Exception:  # noqa: BLE001 — undeserializable ⇒ quarantine
             self._quarantine(path)
             return None
@@ -217,6 +224,8 @@ class ArtifactStore:
                 "canon": canon,
                 "engine": engine,
                 "env": self.env,
+                "devices": [d.id for d in
+                            compiled.runtime_executable().local_devices()],
                 "payload": payload,
                 "in_tree": in_tree,
                 "out_tree": out_tree,

@@ -278,13 +278,17 @@ def sorted_segment_reduce(
 ) -> jnp.ndarray:
     """Scatter-free segment reduction for NONDECREASING seg_ids.
 
-    TPU lowers jax.ops.segment_* to scatter, which serializes badly; when
-    the group ids are sorted (data laid out by (series, time) with group
-    keys monotone in that order — the TSBS/PromQL hot path), the same
-    reductions become cumulative sums diffed at group boundaries
-    (sum/count/mean) or a segmented associative scan (min/max) — all
-    TPU-friendly primitives. Caller guarantees sortedness of the VALID
-    rows' ids; invalid rows may hold any id (they are neutralized).
+    When the group ids are sorted (data laid out by (series, time) with
+    group keys monotone in that order), the reductions jax.ops.segment_*
+    lowers to scatter become cumulative sums diffed at group boundaries
+    (count, int sums) or a segmented associative scan (float sums,
+    min/max). Caller guarantees sortedness of the VALID rows' ids;
+    invalid rows may hold any id (they are neutralized).
+
+    Reached only under GREPTIME_SORTED_SEGMENTS=force: the TPU compiler
+    takes minutes over the associative scan past ~1M rows and does not
+    finish at table size (ROADMAP A5), so no backend is handed this form
+    by default even though the scatter form is slow on the chip.
 
     Semantics identical to segment_reduce.
     """

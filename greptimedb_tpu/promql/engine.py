@@ -368,6 +368,21 @@ def _gather_ts_mat(ts_s, start, cnt_s, L: int):
     return jnp.where(j[None, :] < cnt_s[:, None], mat, _I64_MAX)
 
 
+def _prefix_sum(x):
+    """Inclusive prefix sum of a 1-D array by doubling shifts: log2(n)
+    whole-array adds instead of ``jnp.cumsum``.  The table-wide prefixes
+    below have to be f64 (they reach 1e9 and windows difference them),
+    and the TPU compiler takes minutes over an f64 ``cumsum`` at any
+    length (ROADMAP A4 has the seconds), while it builds this form in
+    seconds.  Same dtype, same sums, pairwise instead of running
+    order."""
+    k = 1
+    while k < x.shape[0]:
+        x = x + jnp.concatenate([jnp.zeros((k,), x.dtype), x[:-k]])
+        k *= 2
+    return x
+
+
 def _window_kernel(p: WindowParams):  # gl: warm-path
     """Build the jitted kernel computing window stats for selected series.
 
@@ -407,7 +422,7 @@ def _window_body(p: WindowParams):  # gl: warm-path
         )
         prev_val = jnp.concatenate([val_s[:1] * 0, val_s[:-1]])
         drop = jnp.where(prev_same & (prev_val > val_s), prev_val, 0.0)
-        gdrop = jnp.cumsum(drop.astype(jnp.float64))
+        gdrop = _prefix_sum(drop.astype(jnp.float64))
         # offset at series start: first valid index per selected series found
         # via searchsorted of tsid*K
         adj = val_s.astype(jnp.float64) + gdrop  # minus series-start gdrop via window diff
@@ -415,7 +430,7 @@ def _window_body(p: WindowParams):  # gl: warm-path
         # cumulative sums (leading zero) over sorted order
         def cs(x):
             x64 = x.astype(jnp.float64)
-            return jnp.concatenate([jnp.zeros(1, jnp.float64), jnp.cumsum(x64)])
+            return jnp.concatenate([jnp.zeros(1, jnp.float64), _prefix_sum(x64)])
 
         cs_v = cs(jnp.where(valid_s, val_s, 0.0))
         cs_v2 = cs(jnp.where(valid_s, val_s.astype(jnp.float64) ** 2, 0.0))

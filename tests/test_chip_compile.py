@@ -1,0 +1,187 @@
+"""Main-path programs compiled for the chip, without the chip.
+
+The TPU's compiler is installed here and compiles for a v5e that is
+described, not attached (``/opt/skills/guides/on-chip-measurement``,
+section 2).  These are the jitted programs the served TSBS path runs
+(``chip_smoke.py``), at the shapes of that deployment: 4000 hosts
+(4096 padded), 12 h at 10 s (a [10, 4096, 6144] resident grid, 18.87M
+padded rows on the row path), so a later PR that makes one of them
+uncompilable for the chip — or minutes slow to compile — fails here at
+no chip time.  A compile that passes is not a chip run.
+
+This is the only file that describes the TPU, and it does so inside a
+fixture: the process that loads the TPU's library keeps it, so nothing
+here touches the topology at import, ``skipif`` or ``parametrize`` time.
+"""
+
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+SPAD, TPAD, FIELDS = 4096, 6144, 10   # resident grid [C, S, T]
+NB = 18                               # hour buckets the padded grid spans
+ROWS = 18_874_368                     # pad_rows(4000 * 4320)
+HOSTS = 4000
+FIELD_NAMES = tuple(f"f{i}" for i in range(FIELDS))
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device compile is written to the persistent cache but
+    # cannot be read back without a chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _bm_kernel():
+    """double-groupby-all over the resident bucket-major partials."""
+    from greptimedb_tpu.query.physical import Executor
+
+    return Executor()._bm_kernel_fn(
+        ("hostname",), ["hostname"], [SPAD], 12, 3_600_000, None,
+        [(f"avg({f})", "mean", i) for i, f in enumerate(FIELD_NAMES)])
+
+
+def _bm_args(sh_sums, sh_cnts, sh_tags, sh_scalar):
+    return (_shape((FIELDS, SPAD, NB), jnp.float32, sh_sums),
+            _shape((SPAD, NB), jnp.float32, sh_cnts),
+            (_shape((SPAD,), jnp.int32, sh_tags),),
+            _shape((), jnp.int32, sh_scalar),
+            _shape((), jnp.int64, sh_scalar))
+
+
+def _grid_kernel():
+    """cpu-max-all-8's shape: max of every field by hour over the grid's
+    sliced window, tag-only WHERE as a per-series mask."""
+    from greptimedb_tpu.query.physical import Executor
+
+    def arg(i):
+        return lambda env: env[FIELD_NAMES[i]]
+
+    specs = [(f"max({f})", "max", arg(i), True, i)
+             for i, f in enumerate(FIELD_NAMES)]
+    return Executor()._build_grid_kernel(
+        FIELD_NAMES, "ts", ("hostname",), [], [], True,
+        360, 8, 2880, 0, 0, 3_600_000,
+        lambda env: env["hostname"] < 8, True, specs,
+        1451606400000, 10_000, True)
+
+
+def _grid_args(sh):
+    return (_shape((FIELDS, SPAD, TPAD), jnp.float32, sh),
+            _shape((SPAD, TPAD), jnp.bool_, sh),
+            (_shape((SPAD,), jnp.int32, sh),),
+            _shape((), jnp.int64, sh), _shape((), jnp.int64, sh),
+            _shape((), jnp.int64, sh), _shape((), jnp.int32, sh))
+
+
+def _promql_params():
+    from greptimedb_tpu.promql.engine import WindowParams
+
+    # sum by (hostname)(rate(cpu{__field__=..}[5m])), 1 h at 60 s
+    return WindowParams(step_ms=60_000, num_steps=61, range_ms=300_000,
+                        num_sel=SPAD, total_series=HOSTS, kind="counter")
+
+
+def _layout_args(sh):
+    return (_shape((ROWS,), jnp.int64, sh), _shape((ROWS,), jnp.int64, sh),
+            _shape((ROWS,), jnp.float32, sh), _shape((ROWS,), jnp.int32, sh),
+            _shape((ROWS,), jnp.bool_, sh),
+            _shape((), jnp.int64, sh), _shape((), jnp.int64, sh),
+            _shape((SPAD,), jnp.int32, sh), _shape((), jnp.int64, sh))
+
+
+def _promql_window():
+    from greptimedb_tpu.promql.engine import _window_body
+
+    return _window_body(_promql_params())
+
+
+def _promql_fused():
+    from greptimedb_tpu.compile.fused import _build_fused
+
+    return _build_fused(_promql_params(), "rate", "sum", HOSTS, HOSTS, 300)
+
+
+def _segment(form, op, rows, sh):
+    from greptimedb_tpu.ops import segment
+
+    fn = {"scatter": segment.segment_reduce,
+          "sorted": segment.sorted_segment_reduce}[form]
+    return ((lambda v, i: fn(v, i, 48_000, op)),
+            (_shape((rows,), jnp.float32, sh), _shape((rows,), jnp.int32, sh)))
+
+
+CASES = {
+    "grid-bucket-major": lambda sh: (_bm_kernel(), _bm_args(sh, sh, sh, sh)),
+    "grid-window-max": lambda sh: (_grid_kernel(), _grid_args(sh)),
+    "promql-window": lambda sh: (_promql_window(), _layout_args(sh)),
+    "promql-fused": lambda sh: (
+        _promql_fused(),
+        _layout_args(sh) + (_shape((HOSTS,), jnp.int32, sh),)),
+    # the form `auto` takes on every backend, at table size
+    "segment-scatter-mean": lambda sh: _segment("scatter", "mean", ROWS, sh),
+    # the form only `force` reaches: its scan is minutes slow past this
+    "segment-sorted-max-64k": lambda sh: _segment("sorted", "max", 1 << 16,
+                                                  sh),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_compiles_for_one_v5e(one_chip, case):
+    fn, args = CASES[case](one_chip)
+    t0 = time.time()
+    compiled = jax.jit(fn).lower(*args).compile()
+    seconds = time.time() - t0
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert total < 12 << 30, f"{case}: {total} bytes on a 16 GB chip"
+    assert seconds < 60, f"{case}: {seconds:.0f} s to compile"
+
+
+def test_bucket_major_shards_over_the_mesh(topo, one_chip):
+    """The four-chip program: partials split on the series axis (the
+    placement parallel/dist.py bucket_major_shardings gives them), the
+    series→group merge as a collective, a quarter of the bytes each."""
+    from greptimedb_tpu.parallel.dist import bucket_major_shardings
+
+    mesh = Mesh(np.array(topo.devices), ("shard",))
+    sh = bucket_major_shardings(mesh, SPAD)
+    rep = NamedSharding(mesh, P())
+    args = _bm_args(sh["sums"], sh["cnts"],
+                    NamedSharding(mesh, P("shard")), rep)
+    compiled = jax.jit(_bm_kernel()).lower(*args).compile()
+    text = compiled.as_text()
+    assert any(c in text for c in ("all-reduce", "all-gather",
+                                   "reduce-scatter", "collective-permute"))
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    whole = jax.jit(_bm_kernel()).lower(*_bm_args(*[one_chip] * 4)) \
+        .compile().memory_analysis().argument_size_in_bytes
+    assert per_device < 0.3 * whole

@@ -147,6 +147,39 @@ class TestStore:
         assert float(fn(jnp.ones((8,), jnp.float32))) == 16.0
         assert store.bytes() > 0
 
+    def test_one_of_eight_devices_loads_on_that_device(self, tmp_path):
+        """A program compiled for ONE of the eight virtual devices comes
+        back from the store on that device and runs — no rebuild behind
+        the scenes (the fallback counter stays where it was)."""
+        import jax
+        import jax.numpy as jnp
+
+        from greptimedb_tpu.compile.service import PlanCompiler
+
+        dev = jax.devices()[3]
+        x = jax.device_put(jnp.arange(8, dtype=jnp.float32), dev)
+        key = ("one_device_roundtrip", 8)
+
+        def kernel_of(compiler):
+            compiler.configure(str(tmp_path / "cc"))
+            return compiler.get_or_build(
+                "sql", key, lambda: jax.jit(lambda v: v * 2))
+
+        fallback0 = REGISTRY.value(
+            "greptime_compile_cache_events_total", ("fallback",))
+        first = PlanCompiler()
+        assert kernel_of(first)(x).devices() == {dev}
+        assert first.persists == 1
+        second = PlanCompiler()
+        kern = kernel_of(second)
+        assert kern.aot and second.aot_hits == 1
+        out = kern(x)
+        assert out.devices() == {dev}
+        np.testing.assert_array_equal(np.asarray(out), 2 * np.arange(8))
+        assert kern.aot, "the artifact was replaced by a rebuild"
+        assert REGISTRY.value("greptime_compile_cache_events_total",
+                              ("fallback",)) == fallback0
+
     def test_corrupt_artifact_quarantines(self, tmp_path):
         store = self._store_with_artifact(tmp_path)
         path = glob.glob(os.path.join(store.aot_dir, "*.gtc"))[0]
