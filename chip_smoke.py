@@ -385,16 +385,16 @@ def run_promql(cl: Client, data: np.ndarray):
 # ---------------------------------------------------------------------------
 
 def _compile_seconds() -> float:
-    """What the program's own timers put down to compilation: the SQL
-    kernels' compile phase and PromQL's xla_compile stage.  The raw-row
-    SELECT and the PromQL sort layout keep no such timer; their compile
-    time shows only as first run minus warm run."""
+    """What the program's own timer puts down to compilation: the
+    ``xla_compile`` stage of the SQL kernels and of PromQL's window and
+    fused programs.  The raw-row SELECT and the PromQL sort layout keep
+    no such stage; their compile time shows only as first run minus warm
+    run."""
     from greptimedb_tpu.utils.telemetry import REGISTRY
 
     return sum(child.sum for name, _k, _l, key, child in REGISTRY.snapshot()
-               if (name, key[-1:]) in (
-                   ("greptime_device_phase_seconds", ("compile",)),
-                   ("greptime_promql_stage_seconds", ("xla_compile",))))
+               if (name, key) == ("greptime_query_stage_seconds",
+                                  ("xla_compile",)))
 
 
 def timed_runs(name: str, run, check, stats: dict) -> dict:

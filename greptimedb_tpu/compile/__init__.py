@@ -40,7 +40,7 @@ from __future__ import annotations
 
 import os
 
-__all__ = ["fusion_enabled", "PlanCompiler"]
+__all__ = ["fusion_enabled", "named_jit", "PlanCompiler"]
 
 
 def fusion_enabled() -> bool:
@@ -50,6 +50,20 @@ def fusion_enabled() -> bool:
     test compares against."""
     return os.environ.get("GREPTIME_PLAN_FUSION", "on").lower() not in (
         "off", "0", "false")
+
+
+def named_jit(name: str, **jit_kwargs):
+    """``jax.jit`` under a stable name: ``@named_jit("sql_grid")``.  The
+    name is a program FAMILY, never a shape or a literal; the compiled
+    module and the profiler's ``XLA Modules`` line read ``jit_<name>``,
+    so per-program device time can be followed across refactors."""
+    import jax
+
+    def wrap(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(fn, **jit_kwargs)
+
+    return wrap
 
 
 def __getattr__(name):  # lazy: keep `import greptimedb_tpu.compile` light

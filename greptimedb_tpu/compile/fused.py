@@ -26,11 +26,10 @@ wholesale.
 
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 
+from greptimedb_tpu.compile import named_jit
 from greptimedb_tpu.errors import TableNotFound
 from greptimedb_tpu.utils.tracing import TRACER
 
@@ -193,11 +192,10 @@ def try_fused_aggregation(ev, e):
     args, p, tsids, labels, pinned, _start, rng = prep
     if pinned or len(tsids) == 0:
         return None
-    t0 = time.perf_counter()
-    with TRACER.stage("group_agg", op=e.op):
+    with TRACER.stage("group_agg", op=e.op) as st:
         gid_dev, ng, out_labels, _ro, _ss = ev._group_series_of(
             e, labels, len(tsids))
-    ev._stage_mark("group_agg", t0)
+    ev._stage_mark("group_agg", st)
     range_s = sel.range_s if func in _NEEDS_RANGE else None
     key = ("promql_fused", p, func, e.op, ng, len(tsids), range_s)
     kern = pe._KERNEL_CACHE.get(key)
@@ -209,7 +207,7 @@ def try_fused_aggregation(ev, e):
             default_compiler()
         kern = compiler.get_or_build(
             "promql", key,
-            lambda: jax.jit(_build_fused(
+            lambda: named_jit(f"promql_fused_{e.op}")(_build_fused(
                 p, func, e.op, ng, len(tsids), range_s)),
             persist=True)
         pe._KERNEL_CACHE[key] = kern
@@ -236,13 +234,9 @@ def try_fused_aggregation(ev, e):
         fused_args = tuple(place(a) for a in fused_args)
     # AOT-store hits deserialize — first call is NOT an XLA compile
     compiling = jit_miss and not getattr(kern, "aot", False)
-    t0 = time.perf_counter()
-    with TRACER.stage("fused_kernel", op=e.op, func=func or "instant"):
-        vals = kern(*fused_args)
-        if jit_miss or TRACER.enabled or (
-                getattr(ev.db, "stage_sink", None) is not None):
-            vals = jax.block_until_ready(vals)
-    ev._stage_mark("xla_compile" if compiling else "fused_kernel", t0)
+    vals = ev._timed_kernel(
+        "fused_kernel", lambda: kern(*fused_args), jit_miss, compiling,
+        op=e.op, func=func or "instant")
     from greptimedb_tpu.compile.service import M_FUSED_DISPATCH
 
     M_FUSED_DISPATCH.labels("promql").inc()

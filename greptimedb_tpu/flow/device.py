@@ -1089,7 +1089,7 @@ class FlowDeviceRuntime:
         # economy would grant interactive-contending work for free);
         # GREPTIME_SLO=off keeps the fully-async hot path byte-for-byte
         sink = {} if getattr(self.db, "slo", None) is not None else None
-        new_state, outs = timed_kernel_call(call, miss, sink, engine="flow")
+        new_state, outs = timed_kernel_call(call, miss, sink)
         st.slots = list(new_state)
         st.folds += 1
         self.fold_dispatches += 1

@@ -40,7 +40,8 @@ SHARD_AXIS = "shard"
 
 # Wall time of the collective exchange phase (shard_map partials + ICI
 # psum/pmin/pmax), labelled by mesh width and compile-vs-steady-state —
-# the mesh twin of query/physical.py's greptime_device_phase_seconds.
+# the mesh twin of the xla_compile / device_execute stages of
+# query/physical.py timed_kernel_call.
 M_MESH_COLLECTIVE = REGISTRY.histogram(
     "greptime_mesh_collective_seconds",
     "Mesh collective-exchange wall time (shard_map + ICI reductions)",

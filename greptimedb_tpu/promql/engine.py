@@ -23,34 +23,22 @@ import collections.abc
 import math
 import os
 import re
-import time
 from dataclasses import dataclass, field
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from greptimedb_tpu.compile import named_jit
 from greptimedb_tpu.errors import PlanError, TableNotFound, Unsupported
 from greptimedb_tpu.promql.parser import (
     Aggregation, BinaryExpr, FunctionCall, LabelMatcher, NumberLit, PromExpr,
     StringLit, SubqueryExpr, UnaryExpr, VectorSelector, parse_promql,
 )
 from greptimedb_tpu.storage.memtable import TSID
-from greptimedb_tpu.utils.telemetry import REGISTRY
 from greptimedb_tpu.utils.tracing import TRACER
 
 DEFAULT_LOOKBACK_S = 300.0
-
-# Per-stage wall time of the PromQL hot loop (selection → sort_layout →
-# window_kernel → group_agg → label_decode), the PromQL twin of the SQL
-# engine's stage marks.  Observed per evaluation; the disabled-tracer
-# path costs one perf_counter pair per stage.
-M_PROMQL_STAGE = REGISTRY.histogram(
-    "greptime_promql_stage_seconds",
-    "PromQL evaluation stage wall time",
-    labels=("stage",),
-)
 
 _I64_MAX = np.int64(np.iinfo(np.int64).max)
 
@@ -254,7 +242,7 @@ class WindowParams:
 _KERNEL_CACHE: dict[WindowParams, object] = {}
 
 
-@jax.jit
+@named_jit("promql_sort_layout")
 def _build_sort_layout(ts, val, tsid, mask):
     """Composite-key sort of a resident table, QUERY-INDEPENDENT: the key
     packs (tsid, ts − ts_min) with a stride covering the table's full time
@@ -345,7 +333,7 @@ def _sorted_window_bounds(p: WindowParams, key_s, ts_min, kp, sel_tsids,
     return lo, hi, cnt, has, sel_ok, n
 
 
-@jax.jit
+@named_jit("promql_series_ranges")
 def _series_ranges(key_s, kp, sel_tsids):
     """Query-independent row range of each selected series in the sorted
     layout: [start, start+cnt).  skey+kp−1 exceeds every key of the series
@@ -357,7 +345,7 @@ def _series_ranges(key_s, kp, sel_tsids):
     return start, jnp.where(sel_ok, (end - start).astype(jnp.int32), 0)
 
 
-@partial(jax.jit, static_argnums=3)
+@named_jit("promql_gather_ts_mat", static_argnums=3)
 def _gather_ts_mat(ts_s, start, cnt_s, L: int):
     """[S, L] per-series timestamp matrix (padding = I64_MAX so threshold
     compares never count it); rows gathered from the sorted layout."""
@@ -392,7 +380,7 @@ def _window_kernel(p: WindowParams):  # gl: warm-path
             start_ms scalar i64.
     Output dict of [S, T] arrays depending on p.kind.
     """
-    return jax.jit(_window_body(p))
+    return named_jit(f"promql_window_{p.kind}")(_window_body(p))
 
 
 def _window_body(p: WindowParams):  # gl: warm-path
@@ -557,7 +545,7 @@ def _count_max_kernel(p: WindowParams):  # gl: warm-path
     """Max samples in any (series, step) window — sizes the matrix
     kernels' static padded width (one cheap pass, cached per shape)."""
 
-    @jax.jit
+    @named_jit("promql_window_cnt_max")
     def kernel(key_s, ts_s, val_s, tsid_s, valid_s, ts_min, kp, sel_tsids,
                start_ms):
         _lo, _hi, cnt, _has, sel_ok, _n = _sorted_window_bounds(
@@ -584,7 +572,7 @@ def _matrix_kernel(p: WindowParams, lmax: int, kind: str):  # gl: warm-path
     """
     T, S = p.num_steps, p.num_sel
 
-    @jax.jit
+    @named_jit(f"promql_matrix_{kind}")
     def kernel(key_s, ts_s, val_s, tsid_s, valid_s, ts_min, kp, sel_tsids,
                start_ms, a1, a2):
         lo, hi, cnt, has, sel_ok, n = _sorted_window_bounds(
@@ -864,16 +852,37 @@ class PromEvaluator:
         # sort / group × hit / miss / reject) — surfaced to bench_promql
         self.cache_events: collections.Counter = collections.Counter()
         # per-stage wall ms for this evaluation (selection → sort_layout →
-        # window_kernel → group_agg → label_decode): mirrored into the
-        # registry histogram and, through execute_tql, into the standalone
-        # stage sink so slow TQL queries self-report their breakdown
+        # window_kernel → group_agg → label_decode), taken from the
+        # stages' own clock reads and handed, through execute_tql, to the
+        # standalone stage sink so slow TQL queries self-report their
+        # breakdown
         self.stage_ms: dict[str, float] = {}
 
-    def _stage_mark(self, name: str, t0: float) -> None:
-        dt = time.perf_counter() - t0
-        M_PROMQL_STAGE.labels(name).observe(dt)
+    def _stage_mark(self, name: str, stage) -> None:
+        """Add a closed ``TRACER.stage``'s time to ``stage_ms[name]``."""
         self.stage_ms[name] = round(
-            self.stage_ms.get(name, 0.0) + dt * 1000, 3)
+            self.stage_ms.get(name, 0.0) + stage.seconds * 1000, 3)
+
+    def _timed_kernel(self, name: str, call, sync: bool, compiling: bool,
+                      **attrs):
+        """Run one kernel dispatch inside stage ``name``; a call that
+        compiles sits in a nested ``xla_compile`` stage as well.  The
+        device is waited for only on a first call (``sync``) or where
+        the slow-query sink asked for the split: steady-state
+        evaluations keep the async dispatch pipeline, tracer on or off,
+        and their wait is the one ``device_wait`` where the result
+        leaves the device."""
+        sync = sync or getattr(self.db, "stage_sink", None) is not None
+        with TRACER.stage(name, **attrs) as st:
+            if compiling:
+                with TRACER.stage("xla_compile"):
+                    out = call()
+            else:
+                out = call()
+            if sync:
+                out = jax.block_until_ready(out)
+        self._stage_mark("xla_compile" if compiling else name, st)
+        return out
 
     def _compiler(self):
         """The db's PlanCompiler (persistent AOT store + usage journal),
@@ -919,10 +928,9 @@ class PromEvaluator:
         vector, Prometheus semantics)."""
         d = self.data_for(sel.metric)
         fieldcol = d.field_column(sel.matchers)
-        t0 = time.perf_counter()
-        with TRACER.stage("selection"):
+        with TRACER.stage("selection") as st:
             tsids, sel_dev, labels = d.select_series(sel.matchers)
-        self._stage_mark("selection", t0)
+        self._stage_mark("selection", st)
         S = int(sel_dev.shape[0])
         rng = range_ms
         if rng is None:
@@ -937,8 +945,7 @@ class PromEvaluator:
         else:
             start = self.start_ms - offset_ms
             num_steps = self.num_steps
-        t0 = time.perf_counter()
-        with TRACER.stage("sort_layout"):
+        with TRACER.stage("sort_layout") as st:
             layout = d.sort_layout(fieldcol)
             bounds_l = None
             extra: tuple = ()
@@ -951,7 +958,7 @@ class PromEvaluator:
                 if b is not None and S * num_steps * b[3] <= (1 << 27):
                     bounds_l = b[3]
                     extra = b[:3]
-        self._stage_mark("sort_layout", t0)
+        self._stage_mark("sort_layout", st)
         p = WindowParams(
             step_ms=self.step_ms,
             num_steps=num_steps,
@@ -988,17 +995,9 @@ class PromEvaluator:
         # happened, so the first call must not be attributed as one
         # (the promql twin of physical.aot_kernel_call's discipline)
         compiling = jit_miss and not getattr(kern, "aot", False)
-        t0 = time.perf_counter()
-        with TRACER.stage("window_kernel", kind=kind):
-            out = kern(*args)
-            if jit_miss or TRACER.enabled or (
-                getattr(self.db, "stage_sink", None) is not None
-            ):
-                # device sync only when someone reads the split: the first
-                # call (compile) is worth attributing always; steady-state
-                # evals keep the async dispatch pipeline
-                out = jax.block_until_ready(out)
-        self._stage_mark("xla_compile" if compiling else "window_kernel", t0)
+        out = self._timed_kernel(
+            "window_kernel", lambda: kern(*args), jit_miss, compiling,
+            kind=kind)
         out = {k: v[: len(tsids)] for k, v in out.items()}
         if pinned:
             out = {
@@ -1048,11 +1047,9 @@ class PromEvaluator:
         a2 = (jnp.broadcast_to(jnp.asarray(extras[1], jnp.float32),
                                (self.num_steps,))[:num_steps]
               if len(extras) > 1 else ones)
-        t0 = time.perf_counter()
-        with TRACER.stage("window_kernel", kind=kind):
-            vals = kern(*args, a1, a2)[: len(tsids)]
-        self._stage_mark("xla_compile" if compiling else "window_kernel",
-                         t0)
+        vals = self._timed_kernel(
+            "window_kernel", lambda: kern(*args, a1, a2), False, compiling,
+            kind=kind)[: len(tsids)]
         if pinned:
             vals = jnp.broadcast_to(vals, (vals.shape[0], self.num_steps))
         return vals, labels
@@ -1574,11 +1571,10 @@ class PromEvaluator:
         r = self.eval(e.expr)
         if r.num_series == 0:
             return r
-        t0 = time.perf_counter()
-        with TRACER.stage("group_agg", op=e.op):
+        with TRACER.stage("group_agg", op=e.op) as st:
             gid_dev, ng, out_labels, row_order_dev, seg_start = (
                 self._group_series(e, r))
-        self._stage_mark("group_agg", t0)
+        self._stage_mark("group_agg", st)
         v = r.values
         S = v.shape[0]
         present = ~jnp.isnan(v)
@@ -1907,10 +1903,10 @@ def execute_tql(db, stmt):
     if stmt.command in ("EXPLAIN",):
         return QueryResult(["plan"], [[f"PromQL: {expr}"]])
     res = ev.eval(expr)
-    vals = np.asarray(res.values)
+    with TRACER.stage("device_wait"):  # the result leaves the device
+        vals = np.asarray(res.values)
     steps = ev.steps_ms()
-    t0 = time.perf_counter()
-    with TRACER.stage("label_decode"):
+    with TRACER.stage("label_decode") as st:
         label_keys = sorted({k for lab in res.labels for k in lab})
         names = label_keys + ["ts", "val"]
         rows = []
@@ -1922,7 +1918,7 @@ def execute_tql(db, stmt):
                     continue
                 rows.append([str(lab.get(k, "")) for k in label_keys]
                             + [int(steps[t]), v])
-    ev._stage_mark("label_decode", t0)
+    ev._stage_mark("label_decode", st)
     sink = getattr(db, "stage_sink", None)
     if sink is not None:
         # slow-query self-reporting: the TQL stage breakdown rides the

@@ -19,7 +19,7 @@ from greptimedb_tpu.query.ast import (
     SelectItem, Star,
 )
 from greptimedb_tpu.query.exprs import TableContext, eval_host
-from greptimedb_tpu.query.physical import Executor
+from greptimedb_tpu.query.physical import Executor, fetch_host
 from greptimedb_tpu.query.planner import SelectPlan, plan_select
 from greptimedb_tpu.query.window import collect_windows, compute_window
 from greptimedb_tpu.utils.tracing import TRACER
@@ -370,7 +370,7 @@ class QueryEngine:
             check()
         # dense time-grid fast path: regular-cadence metric tables lower
         # (tags × time bucket) aggregation to reshape+reduce — no scatter
-        env = n = None
+        pending = None  # (device result, finish): see Executor.execute
         scanned = 0
         import os as _os
 
@@ -382,19 +382,20 @@ class QueryEngine:
 
             if grid_plan_candidate(plan):
                 scan_seq0 = _scan_stats_seq()
-                grid, ts_bounds = grid_fn(sel.table, plan)
+                with TRACER.stage("scan_cache"):
+                    grid, ts_bounds = grid_fn(sel.table, plan)
                 if grid is not None:
                     t = mark("scan_cache_ms", t)
                     _attach_scan_stats(metrics, scan_seq0)
                     with TRACER.stage("execute"):
-                        res = self.executor.execute_grid(
+                        pending = self.executor.execute_grid(
                             plan, grid, ts_bounds, metrics=metrics)
-                    if res is not None:
-                        env, n = res
+                    if pending is not None:
                         scanned = grid.spad * grid.tpad
                         if metrics is not None:
                             metrics["grid"] = True
-        if env is None and _os.environ.get("GREPTIME_MESH", "auto") != "off":
+        if pending is None and _os.environ.get(
+                "GREPTIME_MESH", "auto") != "off":
             # mesh row path: irregular/sparse tables the grid refuses
             # still aggregate across the device mesh when the query
             # decomposes at the commutativity boundary (the provider
@@ -413,17 +414,21 @@ class QueryEngine:
                         metrics["mesh_rows"] = True
                         metrics["output_rows"] = len(result.rows)
                     return result
-        if env is None:
+        if pending is None:
             scan_seq0 = _scan_stats_seq()
-            table, ts_bounds = self.provider.device_table(sel.table, plan)
+            with TRACER.stage("scan_cache"):
+                table, ts_bounds = self.provider.device_table(sel.table, plan)
             t = mark("scan_cache_ms", t)
             _attach_scan_stats(metrics, scan_seq0)
             with TRACER.stage("execute"):
-                env, n = self.executor.execute(plan, table, ts_bounds,
-                                               metrics=metrics)
+                pending = self.executor.execute(plan, table, ts_bounds,
+                                                metrics=metrics)
             scanned = table.padded_rows
+        out, finish = pending
+        host = fetch_host(out)
         t = mark("device_exec_ms", t)
         with TRACER.stage("materialize"):
+            env, n = finish(host)
             if plan.sliding is not None:
                 env, n = _apply_sliding(plan, env, n)
             result = self._shape(plan, env, n)
@@ -492,17 +497,20 @@ class QueryEngine:
                 plans.append(plan)
         except (PlanError, Unsupported, TableNotFound):
             return None
-        grid, ts_bounds = grid_fn(table, plans[0])
+        with TRACER.stage("scan_cache"):
+            grid, ts_bounds = grid_fn(table, plans[0])
         if grid is None:
             return None
         with TRACER.stage("execute", batch=len(plans)):
-            outs = self.executor.execute_grid_batch(
+            pending = self.executor.execute_grid_batch(
                 plans, grid, ts_bounds, metrics=metrics)
-        if outs is None:
+        if pending is None:
             return None
+        out, finish = pending
+        host = fetch_host(out)
         results = []
         with TRACER.stage("materialize", batch=len(plans)):
-            for plan, (env, n) in zip(plans, outs):
+            for plan, (env, n) in zip(plans, finish(host)):
                 results.append(self._shape(plan, env, n))
         return results
 

@@ -27,6 +27,7 @@ from greptimedb_tpu.ops.segment import (
     combine_keys, compact_groups, segment_distinct_count, segment_first_last,
     segment_reduce, segmented_sum_scan, sorted_segment_reduce,
 )
+from greptimedb_tpu.compile import named_jit
 from greptimedb_tpu.ops.time import bucket_index
 from greptimedb_tpu.query.ast import Column, Expr, FuncCall, Star
 from greptimedb_tpu.query.exprs import compile_device, eval_host
@@ -37,19 +38,6 @@ from greptimedb_tpu.utils.telemetry import REGISTRY
 from greptimedb_tpu.utils.tracing import TRACER
 
 DENSE_LIMIT = 1 << 22
-
-# Device-phase split (arXiv:2203.01877's planning/compile/execute
-# separation): "compile" observes the first invocation of a freshly built
-# kernel (XLA trace + compile + launch, a jit-cache miss); "execute"
-# observes the steady-state device wait measured around
-# block_until_ready, recorded only when a caller is collecting metrics
-# (EXPLAIN ANALYZE / slow-query sink / tracer) so the default async
-# dispatch pipeline is untouched.
-M_DEVICE_PHASE = REGISTRY.histogram(
-    "greptime_device_phase_seconds",
-    "Device-phase wall time split: jit compile vs steady-state execute",
-    labels=("engine", "phase"),
-)
 
 # diagnostics: counts every aggregate dispatch (including kernel-cache
 # hits) by which segment strategy it used; tests assert coverage.
@@ -98,54 +86,59 @@ _GRID_OPS = {"avg": "mean", "mean": "mean", "sum": "sum", "count": "count",
              "min": "min", "max": "max"}
 
 
-def timed_kernel_call(call, miss: bool, metrics: dict | None,
-                      engine: str = "sql"):
-    """Invoke a compiled kernel with device-phase accounting.
+def timed_kernel_call(call, miss: bool, metrics: dict | None):
+    """Invoke a compiled kernel with device-phase accounting
+    (arXiv:2203.01877's planning/compile/execute separation).
 
-    The compile phase (jit-cache ``miss``) is always observed — it
-    happens once per kernel class and its cost dwarfs the timer.  The
-    steady-state execute phase needs a device sync to measure, so it is
-    recorded only when someone is collecting (``metrics`` sink active or
-    tracer on); otherwise the dispatch stays fully async and the hot
-    path is untouched.
+    The compile phase (jit-cache ``miss``) is always its own stage,
+    ``xla_compile`` — it happens once per kernel class and its cost
+    dwarfs the timer.  The steady-state device wait is split out here,
+    as stage ``device_execute``, only for a caller that asked for the
+    split (``metrics``: EXPLAIN ANALYZE, the slow-query sink); otherwise
+    the dispatch stays async and the wait is ``fetch_host``'s
+    ``device_wait``.  The tracer never adds a sync: a traced run must
+    do what an untraced one does.
     """
-    import time as _time
-
     DISPATCH_STATS["dispatches"] += 1
     if metrics is not None:
         metrics["device_dispatches"] = metrics.get("device_dispatches", 0) + 1
-    t0 = _time.perf_counter()
     if miss:
-        with TRACER.stage("xla_compile"):
+        with TRACER.stage("xla_compile") as st:
             out = call()
-        dt = _time.perf_counter() - t0
-        M_DEVICE_PHASE.labels(engine, "compile").observe(dt)
         if metrics is not None:
             metrics["jit_cache"] = "miss"
-            metrics["xla_build_ms"] = round(dt * 1000, 3)
+            metrics["xla_build_ms"] = round(st.seconds * 1000, 3)
     else:
         out = call()
         if metrics is not None:
             metrics["jit_cache"] = "hit"
-    if metrics is not None or TRACER.enabled:
-        t1 = _time.perf_counter()
-        with TRACER.stage("device_execute"):
+    if metrics is not None:
+        with TRACER.stage("device_execute") as st:
             out = jax.block_until_ready(out)
-        dt = _time.perf_counter() - t1
-        M_DEVICE_PHASE.labels(engine, "execute").observe(dt)
-        if metrics is not None:
-            metrics["device_wait_ms"] = round(
-                metrics.get("device_wait_ms", 0.0) + dt * 1000, 3)
+        metrics["device_wait_ms"] = round(
+            metrics.get("device_wait_ms", 0.0) + st.seconds * 1000, 3)
     return out
 
 
-def aot_kernel_call(kernel, call, miss: bool, metrics: dict | None,
-                    engine: str = "sql"):
+def fetch_host(out: dict) -> dict:
+    """THE one place a kernel's result leaves the device: wait for it,
+    then copy every output to numpy.  The executor's ``execute*``
+    methods stop before it and hand back ``(out, finish)``, so that the
+    wait is a stage of its own, ``device_wait``, beside ``execute`` (the
+    host side of the dispatch) and ``materialize`` (``finish`` and the
+    result rows) and inside neither."""
+    with TRACER.stage("device_wait"):
+        # gl: allow[GL-H001] -- THE one host materialization per dispatch; finish() operates on these numpy arrays
+        return {k: np.asarray(v)
+                for k, v in jax.block_until_ready(out).items()}
+
+
+def aot_kernel_call(kernel, call, miss: bool, metrics: dict | None):
     """timed_kernel_call for compiler-routed kernels: an AOT-store hit
     (compile/service.py) skips XLA compilation entirely, so its first
     invocation must not be timed — or reported — as a compile."""
     aot = miss and getattr(kernel, "aot", False)
-    out = timed_kernel_call(call, miss and not aot, metrics, engine)
+    out = timed_kernel_call(call, miss and not aot, metrics)
     if aot and metrics is not None:
         metrics["jit_cache"] = "aot"
     return out
@@ -321,8 +314,11 @@ class Executor:
         table: DeviceTable,
         ts_bounds: tuple[int, int],
         metrics: dict | None = None,
-    ) -> tuple[dict[str, np.ndarray], int]:
-        """Run the device part; returns (host env of result columns, nrows)."""
+    ) -> tuple[dict, object]:
+        """Dispatch the device part.  Returns ``(out, finish)``: the
+        kernel's result, still on the device, and the function that
+        turns ``fetch_host(out)`` into (host env of result columns,
+        nrows)."""
         if plan.is_agg:
             return self._execute_agg(plan, table, ts_bounds, metrics=metrics)
         return self._execute_raw(plan, table)
@@ -346,7 +342,7 @@ class Executor:
     def _execute_agg(  # gl: warm-path
         self, plan: SelectPlan, table: DeviceTable,
         ts_bounds: tuple[int, int], metrics: dict | None = None,
-    ) -> tuple[dict[str, np.ndarray], int]:
+    ) -> tuple[dict, object]:
         ctx = plan.ctx
         ctx.table_dicts = table.dicts  # vector search / string-dict exprs
         ctx.table_dicts_version = getattr(table, "dicts_version", 0)
@@ -493,9 +489,15 @@ class Executor:
         out = aot_kernel_call(
             kernel, lambda: kernel(table, ts_lo, ts_hi, starts), jit_miss,
             metrics)
-        # gl: allow[GL-H001] -- THE one host materialization per dispatch; everything below operates on these numpy arrays
-        out = {k: np.asarray(v) for k, v in out.items()}
+        return out, lambda host: self._agg_env(
+            plan, table, agg_specs, sketch_codecs, batched, host)
 
+    @staticmethod
+    def _agg_env(plan: SelectPlan, table: DeviceTable, agg_specs,
+                 sketch_codecs: dict, batched, out: dict) -> tuple[dict, int]:
+        """Kernel outputs (on the host) → result env of the row-path
+        aggregate: the ``finish`` of ``_execute_agg``."""
+        ctx = plan.ctx
         gmask = out.pop("__gmask__").astype(bool)
         cnt_all_g = out.pop("__cnt_all__", None)
         n = int(gmask.sum())
@@ -570,9 +572,10 @@ class Executor:
     def execute_grid(
         self, plan: SelectPlan, grid, ts_bounds: tuple[int, int],
         metrics: dict | None = None,
-    ) -> tuple[dict[str, np.ndarray], int] | None:
+    ) -> tuple[dict, object] | None:
         """Aggregate over a GridTable: reshape+reduce per time bucket, then
         a tiny series-axis segment merge — no row scatter at any scale.
+        Returns ``(out, finish)`` as ``execute`` does.
 
         Returns None when this plan/grid combination is ineligible (query
         bucket not a multiple of the grid step, unsupported agg shape…);
@@ -740,7 +743,7 @@ class Executor:
     def _execute_grid_geom(  # gl: warm-path
         self, plan: SelectPlan, grid, g: "_GridGeom",
         metrics: dict | None,
-    ) -> tuple[dict[str, np.ndarray], int]:
+    ) -> tuple[dict, object]:
         ctx = plan.ctx
         specs = g.specs
         where_fn, where_series = g.where_fn, g.where_series
@@ -820,9 +823,7 @@ class Executor:
                     ts_lo, ts_hi, np.int64(int(bts0) + b_lo * step_q),
                     np.int32(s0),
                 ), jit_miss, metrics)
-        # gl: allow[GL-H001] -- THE one host materialization per grid dispatch
-        out = {k: np.asarray(v) for k, v in out.items()}
-        return self._grid_env(plan, specs, out)
+        return out, lambda host: self._grid_env(plan, specs, host)
 
     @staticmethod
     def _grid_env(plan: SelectPlan, specs, out: dict) -> tuple[dict, int]:
@@ -855,7 +856,7 @@ class Executor:
     def execute_grid_batch(  # gl: warm-path
         self, plans: list[SelectPlan], grid, ts_bounds: tuple[int, int],
         metrics: dict | None = None,
-    ) -> list[tuple[dict[str, np.ndarray], int]] | None:
+    ) -> tuple[dict, object] | None:
         """Stack N concurrent warm queries over the SAME (region, shape
         class) into one device dispatch: the bucket-major kernel vmapped
         over its per-window traced arguments (b_lo, bts0).  Eligibility
@@ -873,7 +874,10 @@ class Executor:
         Bit-exactness contract: the stacked kernel is jit(vmap(fn)) of
         the SAME fn the solo path jits; vmap maps the batch axis over
         slice+segment ops whose reduction dims are unbatched, so each
-        member's floats are identical to its solo run."""
+        member's floats are identical to its solo run.
+
+        Returns ``(out, finish)``; ``finish(fetch_host(out))`` gives one
+        (env, nrows) a member."""
         if len(plans) < 2:
             return None
         geoms: list[_GridGeom] = []
@@ -975,7 +979,7 @@ class Executor:
                        else (None, None, None, 0, 0))
             kernel = self.compiler.get_or_build(
                 "sql", vkey,
-                lambda: jax.jit(jax.vmap(
+                lambda: named_jit("sql_grid_batch")(jax.vmap(
                     self._bm_kernel_fn(
                         g0.tag_order, [k.column for k in g0.tag_keys],
                         g0.cards_tag, g0.nbw, g0.step_q, None,
@@ -993,16 +997,18 @@ class Executor:
             call_args = call_args + (smfs,)
         out = aot_kernel_call(
             kernel, lambda: kernel(*call_args), jit_miss, metrics)
-        # gl: allow[GL-H001] -- THE one host materialization for the whole stacked batch
-        out_np = {k: np.asarray(v) for k, v in out.items()}
         if metrics is not None:
             metrics["batched"] = n
             metrics["layout"] = "bucket_major_stacked"
-        results = []
-        for i, (p, g) in enumerate(zip(plans, geoms)):
-            out_i = {k: v[i] for k, v in out_np.items()}
-            results.append(self._grid_env(p, g.specs, out_i))
-        return results
+
+        def finish(out_np: dict) -> list:
+            results = []
+            for i, (p, g) in enumerate(zip(plans, geoms)):
+                out_i = {k: v[i] for k, v in out_np.items()}
+                results.append(self._grid_env(p, g.specs, out_i))
+            return results
+
+        return out, finish
 
     def _series_mask(self, plan, g: "_GridGeom", grid, tag_arrays):
         """Per-series WHERE mask [spad] f32 for one stacked-batch member:
@@ -1019,7 +1025,7 @@ class Executor:
             tag_order = g.tag_order
             spad = grid.spad
 
-            @jax.jit
+            @named_jit("sql_grid_series_mask")
             def fn(tag_arrays):
                 env_s = dict(zip(tag_order, tag_arrays))
                 return jnp.broadcast_to(
@@ -1139,7 +1145,8 @@ class Executor:
                 return sums, cnts
 
             build = self.compiler.get_or_build(
-                "sql", key, lambda: jax.jit(build_fn))
+                "sql", key,
+                lambda: named_jit("sql_grid_bm_build")(build_fn))
             self._cache[key] = build
         sums, cnts = build(grid.values, grid.valid)
         sums.block_until_ready()
@@ -1220,7 +1227,7 @@ class Executor:
         return kernel
 
     def _build_bm_kernel(self, *args):
-        return jax.jit(self._bm_kernel_fn(*args))
+        return named_jit("sql_grid_bm")(self._bm_kernel_fn(*args))
 
     def _build_grid_kernel(  # gl: warm-path
         self, field_names, ts_name, tag_order, tag_cols, cards_tag, has_time,
@@ -1244,7 +1251,7 @@ class Executor:
             ngt *= c
         nb = nbw
 
-        @jax.jit
+        @named_jit("sql_grid")
         def kernel(values, valid, tag_arrays, ts_lo, ts_hi, bts0, s0):
             # raw arrays, not the GridTable pytree: the pytree's aux data
             # (nt, dicts, …) changes on every append extension and would
@@ -1664,7 +1671,7 @@ class Executor:
             )
         }
 
-        @jax.jit
+        @named_jit("sql_rows_sorted" if use_sorted else "sql_rows_scatter")
         def kernel(table: DeviceTable, ts_lo, ts_hi, time_starts):
             env = dict(table.columns)
             pad_mask = table.row_mask  # padding rows, pre-WHERE
@@ -1903,7 +1910,7 @@ class Executor:
 
     def _execute_raw(
         self, plan: SelectPlan, table: DeviceTable
-    ) -> tuple[dict[str, np.ndarray], int]:
+    ) -> tuple[dict, object]:
         ctx = plan.ctx
         ctx.table_dicts = table.dicts  # vector search / string-dict exprs
         ctx.fulltext = self._fulltext_provider(plan, table)
@@ -1987,7 +1994,10 @@ class Executor:
             # DeviceTable-pytree kernel: never AOT-persisted (see the
             # agg path) — classified and journaled, served by plain jit
             kernel = self.compiler.get_or_build(
-                "sql", cache_key, lambda: jax.jit(kernel_fn),
+                "sql", cache_key,
+                lambda: named_jit(
+                    "sql_rows_raw" if topk is None else "sql_rows_topk"
+                )(kernel_fn),
                 persist=False)
             self._cache[cache_key] = kernel
         out = kernel(
@@ -1995,20 +2005,26 @@ class Executor:
             np.int64(lo) if lo is not None else _I64_MIN,
             np.int64(hi) if hi is not None else _I64_MAX,
         )
-        n = int(out.pop("__n__"))
-        env: dict[str, np.ndarray] = {}
-        for c in cols:
-            arr = np.asarray(out[c])[:n]
-            col = ctx.schema.column(c) if ctx.schema.has_column(c) else None
-            if col is not None and col.is_tag:
-                vals = ctx.encoders[c].values()
-            elif c in table.dicts:  # dictionary-encoded string FIELD
-                vals = table.dicts[c]
-            else:
-                env[c] = arr
-                continue
-            lookup = np.array(list(vals) + [None], dtype=object)
-            codes = arr.astype(np.int64)
-            codes = np.where((codes < 0) | (codes >= len(vals)), len(vals), codes)
-            env[c] = lookup[codes]
-        return env, n
+
+        def finish(out: dict) -> tuple[dict[str, np.ndarray], int]:
+            n = int(out.pop("__n__"))
+            env: dict[str, np.ndarray] = {}
+            for c in cols:
+                arr = out[c][:n]
+                col = (ctx.schema.column(c) if ctx.schema.has_column(c)
+                       else None)
+                if col is not None and col.is_tag:
+                    vals = ctx.encoders[c].values()
+                elif c in table.dicts:  # dictionary-encoded string FIELD
+                    vals = table.dicts[c]
+                else:
+                    env[c] = arr
+                    continue
+                lookup = np.array(list(vals) + [None], dtype=object)
+                codes = arr.astype(np.int64)
+                codes = np.where((codes < 0) | (codes >= len(vals)),
+                                 len(vals), codes)
+                env[c] = lookup[codes]
+            return env, n
+
+        return out, finish

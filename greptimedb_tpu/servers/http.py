@@ -36,7 +36,7 @@ from greptimedb_tpu.query.engine import QueryResult
 from greptimedb_tpu.utils import telemetry
 from greptimedb_tpu.utils.snappy import decompress as snappy_decompress
 from greptimedb_tpu.utils.tracing import (
-    TRACER, parse_trace_id, parse_traceparent,
+    GC_PAUSE, TRACER, parse_trace_id, parse_traceparent,
 )
 
 M_REQUESTS = telemetry.REGISTRY.counter(
@@ -142,6 +142,7 @@ class ThreadedAiohttpApp:
     def start(self) -> None:
         if getattr(self, "_started", None) is None:
             self._started = threading.Event()
+        GC_PAUSE.install()
 
         def run_loop():
             loop = asyncio.new_event_loop()
@@ -408,9 +409,13 @@ class HttpServer(ThreadedAiohttpApp):
             return self.db.sql(sql)
 
     async def h_sql(self, request: web.Request) -> web.Response:
+        ctx = _request_trace_context(request)
+        with TRACER.stage_in(ctx, "http_request", path="/v1/sql"):
+            return await self._h_sql(request, ctx)
+
+    async def _h_sql(self, request: web.Request, ctx) -> web.Response:
         t0 = time.perf_counter()
         sql = await self._param(request, "sql")
-        ctx = _request_trace_context(request)
         hold: list = []  # caller-held SLO sample (see scheduler._finish)
         with M_LATENCY.labels("/v1/sql").time():
             if not sql:
@@ -439,13 +444,15 @@ class HttpServer(ThreadedAiohttpApp):
                         res = await self._call(
                             self._traced_sql, sql, ctx)
                 # serialize BEFORE observing (ISSUE 18 fix): the JSON
-                # envelope build is part of what the client waits for,
-                # and the histogram previously closed at submit-return —
-                # under-reporting exactly the rows-heavy responses.  The
-                # scheduler's SLO sample is caller-held over the same
-                # span (record_held below), so sketch and histogram
-                # agree by construction.
-                body = _result_to_json(res, t0)
+                # envelope and its text are part of what the client
+                # waits for, and the histogram previously closed at
+                # submit-return — under-reporting exactly the rows-heavy
+                # responses.  The scheduler's SLO sample is caller-held
+                # over the same span (record_held below), so sketch and
+                # histogram agree by construction.
+                with TRACER.stage_in(ctx, "serialize"):
+                    resp = web.json_response(_result_to_json(res, t0),
+                                             headers=_trace_headers(ctx))
                 if timed:
                     M_PROTOCOL_QUERY.labels("http").observe(
                         time.perf_counter() - t0)
@@ -453,8 +460,7 @@ class HttpServer(ThreadedAiohttpApp):
                     if sched is not None and hold:
                         sched.record_held(hold)
                 M_REQUESTS.labels("/v1/sql", "200").inc()
-                return web.json_response(body,
-                                         headers=_trace_headers(ctx))
+                return resp
             except Exception as e:  # noqa: BLE001
                 sched = self.db.scheduler
                 if sched is not None and hold:
@@ -470,10 +476,14 @@ class HttpServer(ThreadedAiohttpApp):
                            step: float, lookback: float | None = None,
                            trace_ctx: tuple[str, str] | None = None,
                            tenant: str = "default"):
+        """(result with its values on the host, step timestamps)."""
+        import dataclasses
+
         from greptimedb_tpu.promql.engine import DEFAULT_LOOKBACK_S, PromEvaluator
         from greptimedb_tpu.promql.parser import parse_promql
 
-        expr = parse_promql(query)
+        with TRACER.stage_in(trace_ctx, "parse"):
+            expr = parse_promql(query)
 
         def run():
             with M_PROTOCOL_QUERY.labels("prometheus").time():
@@ -481,6 +491,12 @@ class HttpServer(ThreadedAiohttpApp):
                     ev = PromEvaluator(self.db, start, end, step,
                                        lookback or DEFAULT_LOOKBACK_S)
                     res = ev.eval(expr)
+                    # the one place the result leaves the device: here,
+                    # on the worker, so the event loop formats host data
+                    # and never waits for the chip
+                    with TRACER.stage("device_wait"):
+                        res = dataclasses.replace(
+                            res, values=np.asarray(res.values))
             return res, ev.steps_ms()
 
         sched = self.db.scheduler
@@ -491,51 +507,61 @@ class HttpServer(ThreadedAiohttpApp):
             # dedupe the heavy state)
             return await self._call_query(
                 lambda: sched.submit_fn(run, tenant=tenant,
+                                        trace_ctx=trace_ctx,
                                         label=query[:256],
                                         protocol="prometheus"))
         return await self._call(run)
 
-    async def h_prom_range(self, request: web.Request) -> web.Response:
+    async def _h_prom(self, request: web.Request, route: str, params,
+                      payload_name: str) -> web.Response:
+        """query_range and query: ``params(request)`` gives (query,
+        start, end, step); the histogram covers the handler to the
+        built response, as /v1/sql's does (ISSUE 18)."""
         ctx = _request_trace_context(request)
-        try:
-            query = await self._param(request, "query")
-            start = _parse_prom_time(await self._param(request, "start"))
-            end = _parse_prom_time(await self._param(request, "end"))
-            step = _parse_prom_duration(await self._param(request, "step", "60"))
-            with M_LATENCY.labels("/v1/prometheus/api/v1/query_range").time():
+        with TRACER.stage_in(ctx, "http_request", path=route), \
+                M_LATENCY.labels(route).time():
+            try:
+                query, start, end, step = await params(request)
                 res, steps = await self._eval_promql(
                     query, start, end, step, trace_ctx=ctx,
                     tenant=self._tenant(request))
-            from greptimedb_tpu.promql.format import range_payload
+                from greptimedb_tpu.promql import format as prom_format
 
-            M_REQUESTS.labels("/v1/prometheus/api/v1/query_range", "200").inc()
-            return web.json_response(range_payload(res, steps),
-                                     headers=_trace_headers(ctx))
-        except Exception as e:  # noqa: BLE001
-            M_REQUESTS.labels("/v1/prometheus/api/v1/query_range", "400").inc()
-            return web.json_response(
-                {"status": "error", "errorType": "bad_data", "error": str(e)},
-                status=400)
+                with TRACER.stage_in(ctx, "format"):
+                    payload = getattr(prom_format, payload_name)(res, steps)
+                with TRACER.stage_in(ctx, "serialize"):
+                    resp = web.json_response(payload,
+                                             headers=_trace_headers(ctx))
+                M_REQUESTS.labels(route, "200").inc()
+                return resp
+            except Exception as e:  # noqa: BLE001
+                M_REQUESTS.labels(route, "400").inc()
+                return web.json_response(
+                    {"status": "error", "errorType": "bad_data",
+                     "error": str(e)}, status=400)
+
+    async def h_prom_range(self, request: web.Request) -> web.Response:
+        async def params(request):
+            return (
+                await self._param(request, "query"),
+                _parse_prom_time(await self._param(request, "start")),
+                _parse_prom_time(await self._param(request, "end")),
+                _parse_prom_duration(
+                    await self._param(request, "step", "60")))
+
+        return await self._h_prom(
+            request, "/v1/prometheus/api/v1/query_range", params,
+            "range_payload")
 
     async def h_prom_query(self, request: web.Request) -> web.Response:
-        ctx = _request_trace_context(request)
-        try:
-            query = await self._param(request, "query")
-            t = _parse_prom_time(await self._param(request, "time", str(time.time())))
-            with M_LATENCY.labels("/v1/prometheus/api/v1/query").time():
-                res, steps = await self._eval_promql(
-                    query, t, t, 1, trace_ctx=ctx,
-                    tenant=self._tenant(request))
-            from greptimedb_tpu.promql.format import instant_payload
+        async def params(request):
+            t = _parse_prom_time(
+                await self._param(request, "time", str(time.time())))
+            return await self._param(request, "query"), t, t, 1
 
-            M_REQUESTS.labels("/v1/prometheus/api/v1/query", "200").inc()
-            return web.json_response(instant_payload(res, steps),
-                                     headers=_trace_headers(ctx))
-        except Exception as e:  # noqa: BLE001
-            M_REQUESTS.labels("/v1/prometheus/api/v1/query", "400").inc()
-            return web.json_response(
-                {"status": "error", "errorType": "bad_data", "error": str(e)},
-                status=400)
+        return await self._h_prom(
+            request, "/v1/prometheus/api/v1/query", params,
+            "instant_payload")
 
     async def h_prom_labels(self, request: web.Request) -> web.Response:
         def run():

@@ -409,7 +409,8 @@ class QueryScheduler:
         try:
             from greptimedb_tpu.query.parser import parse_sql
 
-            stmts = parse_sql(sql)
+            with TRACER.trace_context(trace_ctx), TRACER.stage("parse"):
+                stmts = parse_sql(sql)
         except Exception:  # noqa: BLE001 — worker re-parses for the error
             stmts = None
         e = _Entry(kind="sql", sql=sql, stmts=stmts, tenant=tenant,

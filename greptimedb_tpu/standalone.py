@@ -906,13 +906,12 @@ class GreptimeDB(TableProvider):
             # per-statement stage sink: engines write their stage/device
             # timings here (query/engine.py mark(), promql stage_ms) so a
             # slow query self-reports where its time went.  Activated only
-            # when someone will read it — the recorder or the tracer —
-            # keeping the default path at two attribute checks.
+            # when the recorder will read it: a sink makes the engines
+            # wait for the device after each dispatch, which the tracer
+            # must not cause (it observes; the stages carry its times).
             sink: dict | None = None
             outer_sink = getattr(self._proc_local, "stage_sink", None)
-            if outer_sink is None and (
-                self.slow_query_threshold_ms > 0 or TRACER.enabled
-            ):
+            if outer_sink is None and self.slow_query_threshold_ms > 0:
                 sink = {}
                 # scheduler columns: a worker thread stamps its queue
                 # wait/batch info before calling in, so slow_queries and

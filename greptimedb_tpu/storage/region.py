@@ -450,21 +450,14 @@ class Region:
         with self._write_lock:
             return self._write_locked(data, op, wire_payload)
 
-    def _write_locked(self, data, op: int,
-                      wire_payload: bytes | None = None) -> int:
-        from greptimedb_tpu.utils.tracing import TRACER
-
-        ts_name = self.ts_name
-        n = len(data[ts_name])
-        if self.memory is not None:
-            # rough batch footprint: ~16B/cell covers the typical mix of
-            # f64/int64 values plus object-array overhead for tags
-            self.memory.admit("ingest", n * len(data) * 16)
+    def _coerce_columns(self, data, n: int,
+                        wire_ok: bool) -> tuple[dict, bool]:
+        """The batch's columns in the schema's order and dtypes, and
+        whether its wire bytes may still stand for it in the WAL."""
         # wire_payload stays usable only while every schema column turns
         # out to have arrived structurally (typed ndarray / string-typed
         # DictColumn) — exactly the inputs replay_wal re-derives
         # identically from the raw wire stream
-        wire_ok = wire_payload is not None and op == OP_PUT
         cols: dict[str, np.ndarray] = {}
         for c in self.schema:
             if c.name not in data:
@@ -521,15 +514,30 @@ class Region:
                         raise InvalidArguments(
                             f"column {c.name}: {e}"
                         ) from None
-        seq = self.next_seq
-        self.next_seq += 1
-        chunk = dict(cols)
-        tag_codes: dict[str, np.ndarray] = {}
-        chunk[TSID] = self._encode_tags(cols, n, out_codes=tag_codes)
-        for tname, tcodes in tag_codes.items():
-            chunk[tagcode_col(tname)] = tcodes
-        chunk[SEQ] = np.full(n, seq, dtype=np.int64)
-        chunk[OP] = np.full(n, op, dtype=np.int8)
+        return cols, wire_ok
+
+    def _write_locked(self, data, op: int,
+                      wire_payload: bytes | None = None) -> int:
+        from greptimedb_tpu.utils.tracing import TRACER
+
+        ts_name = self.ts_name
+        n = len(data[ts_name])
+        if self.memory is not None:
+            # rough batch footprint: ~16B/cell covers the typical mix of
+            # f64/int64 values plus object-array overhead for tags
+            self.memory.admit("ingest", n * len(data) * 16)
+        with TRACER.stage("ingest_encode", region=self.region_id, rows=n):
+            cols, wire_ok = self._coerce_columns(
+                data, n, wire_payload is not None and op == OP_PUT)
+            seq = self.next_seq
+            self.next_seq += 1
+            chunk = dict(cols)
+            tag_codes: dict[str, np.ndarray] = {}
+            chunk[TSID] = self._encode_tags(cols, n, out_codes=tag_codes)
+            for tname, tcodes in tag_codes.items():
+                chunk[tagcode_col(tname)] = tcodes
+            chunk[SEQ] = np.full(n, seq, dtype=np.int64)
+            chunk[OP] = np.full(n, op, dtype=np.int8)
 
         # durability first (reference handle_write.rs: WAL before memtable);
         # non-durable stores (Noop) skip serialization entirely — encoding
@@ -566,6 +574,40 @@ class Region:
                         wal_cols[k] = pa.array(
                             v.tolist() if v.dtype == object else v)
                     self.wal.append(seq, encode_write(wal_cols, op=op))
+        with TRACER.stage("ingest_encode", region=self.region_id, rows=n):
+            mt_chunk, ts_lo, ts_hi, appendable = self._classify_batch(
+                chunk, op, n)
+
+        with TRACER.stage("ingest_memtable", region=self.region_id, rows=n):
+            self.memtable.append(
+                mt_chunk, ts_bounds=(ts_lo, ts_hi) if n else None, seq=seq)
+        self.generation += 1
+        # consumers like the streaming flow engine need to know whether
+        # this batch could have OVERWRITTEN existing rows (upsert) — an
+        # incremental aggregate may only fold in pure appends
+        self.last_write_appendable = appendable or n == 0
+        if appendable:
+            with self._append_log_lock:
+                self._append_log.append(mt_chunk)
+                if len(self._append_log) > MAX_APPEND_CHUNKS:
+                    # sustained ingest: trim the consumed front instead
+                    # of forcing a structure change — up-to-date
+                    # consumers (absolute positions) keep extending
+                    # forever; a consumer behind the trimmed window
+                    # rebuilds (it was stale anyway)
+                    drop = len(self._append_log) - MAX_APPEND_CHUNKS
+                    del self._append_log[:drop]
+                    self._append_base += drop
+        elif n > 0:
+            self._mark_structure_change()
+        # n == 0: nothing changed; keep resident tables valid
+        if self.memtable.bytes >= self.options.flush_threshold_bytes:
+            self.flush()
+        return seq
+
+    def _classify_batch(self, chunk: dict, op: int, n: int):
+        """(memtable chunk, ts_lo, ts_hi, appendable) of one encoded
+        batch; advances ``_max_ts_seen``."""
         # memtable stores ts as int64 under the schema's ts column name;
         # dictionary-coded tags materialize here — one vocabulary gather
         # per column (rows share the vocabulary's string objects)
@@ -606,33 +648,7 @@ class Region:
                     appendable = False
         if n > 0:
             self._max_ts_seen = max(self._max_ts_seen, ts_hi)
-
-        with TRACER.stage("ingest_memtable", region=self.region_id, rows=n):
-            self.memtable.append(
-                mt_chunk, ts_bounds=(ts_lo, ts_hi) if n else None, seq=seq)
-        self.generation += 1
-        # consumers like the streaming flow engine need to know whether
-        # this batch could have OVERWRITTEN existing rows (upsert) — an
-        # incremental aggregate may only fold in pure appends
-        self.last_write_appendable = appendable or n == 0
-        if appendable:
-            with self._append_log_lock:
-                self._append_log.append(mt_chunk)
-                if len(self._append_log) > MAX_APPEND_CHUNKS:
-                    # sustained ingest: trim the consumed front instead
-                    # of forcing a structure change — up-to-date
-                    # consumers (absolute positions) keep extending
-                    # forever; a consumer behind the trimmed window
-                    # rebuilds (it was stale anyway)
-                    drop = len(self._append_log) - MAX_APPEND_CHUNKS
-                    del self._append_log[:drop]
-                    self._append_base += drop
-        elif n > 0:
-            self._mark_structure_change()
-        # n == 0: nothing changed; keep resident tables valid
-        if self.memtable.bytes >= self.options.flush_threshold_bytes:
-            self.flush()
-        return seq
+        return mt_chunk, ts_lo, ts_hi, appendable
 
     def _mark_structure_change(self, content_preserving: bool = False) -> None:
         """Resident device tables for this region can no longer be extended
@@ -711,8 +727,17 @@ class Region:
             return self._flush_locked()
 
     def _flush_locked(self) -> SstMeta | None:
+        from greptimedb_tpu.utils.tracing import TRACER
+
         if self.memtable.is_empty:
             return None
+        with TRACER.stage("flush", region=self.region_id,
+                          rows=self.memtable.num_rows):
+            meta = self._flush_memtable()
+        self._maybe_compact()  # a stage of its own, beside flush
+        return meta
+
+    def _flush_memtable(self) -> SstMeta:
         frozen = self.memtable.freeze(dedup=not self.options.append_mode)
         flushed_seq = self.memtable.max_seq
         # storage keeps ts as int64 epoch in schema unit
@@ -735,7 +760,6 @@ class Region:
         self.wal.truncate(flushed_seq + 1)
         self.generation += 1
         self._mark_structure_change(content_preserving=True)
-        self._maybe_compact()
         return meta
 
     def replay_wal(self, repair: bool = True) -> int:
@@ -910,6 +934,13 @@ class Region:
         history for that key range — conservatively, when the input includes
         every SST file (full compaction); otherwise they are carried over.
         """
+        from greptimedb_tpu.utils.tracing import TRACER
+
+        with TRACER.stage("compaction", region=self.region_id,
+                          files=len(files)):
+            return self._merge_files(files)
+
+    def _merge_files(self, files: list[SstMeta]) -> SstMeta:
         from greptimedb_tpu.storage.scan import (
             estimate_staging_bytes, merge_parts, prefetch_store, read_parts,
         )

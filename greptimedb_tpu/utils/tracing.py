@@ -8,12 +8,18 @@ same wire format servers/trace.py parses — a greptimedb-tpu instance
 can export its own spans to another instance, or to any OTLP
 collector) and POSTs it over HTTP.
 
-Disabled tracers cost one attribute check per span.
+``Tracer.stage`` is the ONE stage boundary of the query and ingest
+paths.  One pair of ``perf_counter`` reads feeds three sinks: the
+always-on histogram ``greptime_query_stage_seconds{stage}``, a
+``jax.profiler.TraceAnnotation`` (recorded only while a profiler session
+is open, on the device trace's clock) and, with the tracer enabled, the
+OTLP span.  A disabled tracer never allocates a span record.
 """
 
 from __future__ import annotations
 
 import contextlib
+import gc
 import os
 import re
 import struct
@@ -25,9 +31,76 @@ from greptimedb_tpu.utils.proto import (  # the ONE wire encoder
     pb_fixed64 as _fixed64_field, pb_len as _field, pb_varint as _varint,
     pb_vint_field as _vint_field,
 )
+from greptimedb_tpu.utils.telemetry import REGISTRY
+
+# Always on, as upstream keeps greptime_query_stage_elapsed: what a
+# request's time went to must be readable from /metrics of a server that
+# was started with no tracing option at all.
+M_STAGE = REGISTRY.histogram(
+    "greptime_query_stage_seconds",
+    "Wall time of one stage of the query and ingest paths",
+    labels=("stage",),
+    buckets=(1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3, 0.01, 0.025, 0.05,
+             0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0),
+)
+M_GC_PAUSE = REGISTRY.histogram(
+    "greptime_gc_pause_seconds",
+    "Pause of one full (generation 2) Python garbage collection",
+    labels=("generation",),
+)
+
+_TRACE_ANNOTATION = None
 
 
-_NULL_CTX = contextlib.nullcontext()
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation``: an atomic load while no
+    profiler session is open, a host-plane event of the same
+    ``.xplane.pb`` as the device's ``XLA Ops`` while one is.  jax is
+    imported on the first stage, never with this module."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(name)
+
+
+class _Stage:
+    """One open stage (see ``Tracer.stage``).  ``seconds`` holds its
+    wall time once it has closed."""
+
+    __slots__ = ("_tracer", "_name", "_attrs", "_detached", "_ctx",
+                 "_span", "_ann", "_t0", "seconds")
+
+    def __init__(self, tracer, name, attrs, detached=False, ctx=None):
+        self._tracer = tracer
+        self._name = name
+        self._attrs = attrs
+        self._detached = detached
+        self._ctx = ctx
+        self._span = None
+        self.seconds = 0.0
+
+    def __enter__(self):
+        tr = self._tracer
+        if tr.enabled and not getattr(tr._tls, "suppress", False):
+            self._span = tr._open_span(self._name, self._attrs,
+                                       self._detached, self._ctx)
+        self._ann = _annotation(self._name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.seconds = dt = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
+        self._tracer._stage_child(self._name).observe(dt)
+        if self._span is not None:
+            self._tracer._close_span(
+                self._span, self._span["start_ns"] + int(dt * 1e9),
+                exc_type is not None)
+        return False
+
 
 # ---------------------------------------------------------------------------
 # Trace-context propagation (W3C Trace Context + the reference's
@@ -159,6 +232,7 @@ class Tracer:
         self._tls = threading.local()  # current span id (parenting)
         self._trace_id_base = os.urandom(12).hex()
         self._counter = 0
+        self._stage_children: dict[str, object] = {}
 
     def configure(self, endpoint: str | None = None,
                   service_name: str | None = None,
@@ -182,56 +256,80 @@ class Tracer:
         return (self._trace_id_base + struct.pack(">I", c & 0xFFFFFFFF).hex(),
                 os.urandom(8).hex())
 
-    def stage(self, name: str, **attributes):
-        """Hot-path span entry: ``span()`` when enabled, a SHARED null
-        context when disabled — one attribute check, no generator or
-        span-record allocation, so per-stage instrumentation inside the
-        query engines is free when tracing is off.  The suppress check
-        sits AFTER the enabled short-circuit: the disabled path still
-        costs exactly one attribute read."""
-        if not self.enabled or getattr(self._tls, "suppress", False):
-            return _NULL_CTX
-        return self.span(name, **attributes)
+    def stage(self, name: str, **attributes) -> _Stage:
+        """Hot-path stage boundary.  Tracer off: two clock reads, one
+        profiler annotation and one histogram observation, and no span
+        record; tracer on: the span as well, child of this thread's
+        current span."""
+        return _Stage(self, name, attributes)
+
+    def stage_in(self, ctx: tuple[str, str] | None, name: str,
+                 **attributes) -> _Stage:
+        """A stage of a coroutine: its span hangs under ``ctx``
+        ((trace_id, parent_span_id); a fresh trace without one) and this
+        thread's current span is left alone, because an event-loop
+        thread runs other requests between one request's awaits."""
+        return _Stage(self, name, attributes, detached=True, ctx=ctx)
+
+    def _stage_child(self, name: str):
+        child = self._stage_children.get(name)
+        if child is None:
+            child = self._stage_children[name] = M_STAGE.labels(name)
+        return child
+
+    def _open_span(self, name: str, attributes: dict,
+                   detached: bool = False,
+                   ctx: tuple[str, str] | None = None) -> dict:
+        """Start a span record: child of this thread's current span, and
+        the current span itself until it closes; or, ``detached``, child
+        of ``ctx`` with the thread's state untouched."""
+        if detached:
+            trace_id, parent_id = ctx or (self.new_trace_id(), "")
+        else:
+            parent = getattr(self._tls, "current", None)
+            trace_id, parent_id = parent or (self.new_trace_id(), "")
+        span_id = os.urandom(8).hex()
+        rec = {
+            "trace_id": trace_id,
+            "span_id": span_id,
+            "parent_span_id": parent_id,
+            "name": name,
+            "start_ns": time.time_ns(),
+            "end_ns": 0,
+            "attributes": dict(attributes),
+            "status_code": 0,
+        }
+        if not detached:
+            rec["_restore"] = (parent,)  # popped by _close_span
+            self._tls.current = (trace_id, span_id)
+        return rec
+
+    def _close_span(self, rec: dict, end_ns: int, failed: bool) -> None:
+        restore = rec.pop("_restore", None)
+        if restore is not None:
+            self._tls.current = restore[0]
+        rec["end_ns"] = end_ns
+        if failed:
+            rec["status_code"] = 2  # STATUS_CODE_ERROR
+        with self._lock:
+            self._spans.append(rec)
+            if len(self._spans) > self.max_buffer:
+                trim = len(self._spans) - self.max_buffer
+                del self._spans[:trim]
+                self._dropped += trim
 
     @contextlib.contextmanager
     def span(self, name: str, **attributes):
         if not self.enabled or getattr(self._tls, "suppress", False):
             yield None
             return
-        parent = getattr(self._tls, "current", None)
-        if parent is not None:
-            trace_id = parent[0]
-            parent_id = parent[1]
-        else:
-            trace_id, _ = self._next_ids()
-            parent_id = ""
-        span_id = os.urandom(8).hex()
-        self._tls.current = (trace_id, span_id)
-        start_ns = time.time_ns()
-        status = 0
+        rec = self._open_span(name, attributes)
+        failed = True
         try:
-            yield span_id
-        except BaseException:
-            status = 2  # STATUS_CODE_ERROR
-            raise
+            yield rec["span_id"]
+            failed = False
         finally:
-            self._tls.current = parent
-            rec = {
-                "trace_id": trace_id,
-                "span_id": span_id,
-                "parent_span_id": parent_id,
-                "name": name,
-                "start_ns": start_ns,
-                "end_ns": time.time_ns(),
-                "attributes": {k: v for k, v in attributes.items()},
-                "status_code": status,
-            }
-            with self._lock:
-                self._spans.append(rec)
-                if len(self._spans) > self.max_buffer:
-                    trim = len(self._spans) - self.max_buffer
-                    del self._spans[:trim]
-                    self._dropped += trim
+            self._close_span(rec, time.time_ns(), failed)
 
     # ---- trace-context propagation ------------------------------------
     def new_trace_id(self) -> str:
@@ -359,4 +457,34 @@ def render_span_tree(spans: list[dict]) -> str:
     return "\n".join(lines)
 
 
+class _GcPause:
+    """``gc.callbacks`` hook: a full collection's pause into
+    ``greptime_gc_pause_seconds{generation="2"}`` and a ``gc_pause``
+    profiler annotation.  The young generations return at once: they
+    run hundreds of times a request and take microseconds.  One slot is
+    enough, since the interpreter runs one collection at a time."""
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            ann = _annotation("gc_pause")
+            ann.__enter__()
+            self._open = (ann, time.perf_counter())
+        elif self._open is not None:
+            ann, t0 = self._open
+            self._open = None
+            M_GC_PAUSE.labels("2").observe(time.perf_counter() - t0)
+            ann.__exit__(None, None, None)
+
+    def install(self) -> None:
+        """Idempotent; called where a server starts."""
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
+
+
+GC_PAUSE = _GcPause()
 TRACER = Tracer()

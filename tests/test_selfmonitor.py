@@ -316,11 +316,18 @@ class TestDisabledZeroOverhead:
             if mod is not None:
                 sys.modules["greptimedb_tpu.utils.selfmonitor"] = mod
 
-    def test_disabled_tracer_stage_is_null_context(self):
-        assert not TRACER.enabled
-        from greptimedb_tpu.utils.tracing import _NULL_CTX
+    def test_disabled_tracer_stage_records_no_span(self):
+        # the stage boundary is always a timer (histogram + profiler
+        # annotation); only the span record is the tracer's
+        from greptimedb_tpu.utils.telemetry import REGISTRY
 
-        assert TRACER.stage("anything") is _NULL_CTX
+        assert not TRACER.enabled
+        n0 = REGISTRY.value("greptime_query_stage_seconds", ("anything",))
+        with TRACER.stage("anything", rows=1) as st:
+            pass
+        assert TRACER._spans == [] and st.seconds >= 0.0
+        assert REGISTRY.value(
+            "greptime_query_stage_seconds", ("anything",)) == n0 + 1
 
 
 # ---------------------------------------------------------------------------

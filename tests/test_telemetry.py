@@ -315,11 +315,17 @@ class TestQueryTelemetry:
         # a never-seen GROUP BY shape forces a jit-cache miss → the
         # compile phase is observed; EXPLAIN ANALYZE then shows the
         # steady-state device wait next to the jit_cache annotation
-        c0 = REGISTRY.value("greptime_device_phase_seconds",
-                            ("sql", "compile"))
+        c0 = REGISTRY.value("greptime_query_stage_seconds",
+                            ("xla_compile",))
+        w0 = REGISTRY.value("greptime_query_stage_seconds",
+                            ("device_wait",))
         db.sql("SELECT h, min(v), max(v), count(v) FROM cpu GROUP BY h")
-        assert REGISTRY.value("greptime_device_phase_seconds",
-                              ("sql", "compile")) > c0
+        assert REGISTRY.value("greptime_query_stage_seconds",
+                              ("xla_compile",)) > c0
+        # the wait for the device is a stage of every query, asked for
+        # or not: it is where the result leaves the device
+        assert REGISTRY.value("greptime_query_stage_seconds",
+                              ("device_wait",)) == w0 + 1
         r = db.sql("EXPLAIN ANALYZE SELECT h, min(v), max(v), count(v) "
                    "FROM cpu GROUP BY h")
         analyze = r.rows[1][1]
@@ -327,10 +333,12 @@ class TestQueryTelemetry:
         assert "device_wait_ms:" in analyze
 
     def test_promql_stage_histogram(self, db):
-        s0 = REGISTRY.value("greptime_promql_stage_seconds", ("selection",))
+        s0 = REGISTRY.value("greptime_query_stage_seconds", ("selection",))
         db.sql("TQL EVAL (0, 10, '5s') sum by(h) (cpu)")
         assert REGISTRY.value(
-            "greptime_promql_stage_seconds", ("selection",)) > s0
+            "greptime_query_stage_seconds", ("selection",)) > s0
+        assert "greptime_promql_stage_seconds" not in REGISTRY.render()
+        assert "greptime_device_phase_seconds" not in REGISTRY.render()
 
     def test_promql_cache_counters_mirror_registry(self, db):
         ev = "greptime_cache_events_total"
