@@ -26,6 +26,21 @@ class GtWalSpan(ctypes.Structure):
     ]
 
 
+class GtJsonCol(ctypes.Structure):
+    _fields_ = [
+        ("kind", ctypes.c_int32),
+        ("data", ctypes.c_void_p),
+        ("offsets", ctypes.c_void_p),
+        ("valid", ctypes.c_void_p),
+    ]
+
+
+# GtJsonKind of greptime_native.cpp, by numpy dtype kind
+_JSON_KIND = {"f": (0, "<f8"), "i": (1, "<i8"), "u": (2, "<u8"),
+              "b": (3, "u1")}
+_JSON_STR = 4
+
+
 def build(quiet: bool = True) -> bool:
     """Compile the library in place; returns success."""
     try:
@@ -76,6 +91,19 @@ def lib():
             ]
         except AttributeError:
             l._gt_no_wal = True
+        # a libstdc++ without floating-point to_chars leaves these out
+        try:
+            l.gt_json_rows_bound.restype = ctypes.c_size_t
+            l.gt_json_rows_bound.argtypes = [
+                ctypes.POINTER(GtJsonCol), ctypes.c_int32, ctypes.c_int64,
+            ]
+            l.gt_json_rows.restype = ctypes.c_size_t
+            l.gt_json_rows.argtypes = [
+                ctypes.POINTER(GtJsonCol), ctypes.c_int32, ctypes.c_int64,
+                ctypes.c_void_p,
+            ]
+        except AttributeError:
+            l._gt_no_json = True
         _LIB = l
     except OSError:
         _LIB = None
@@ -141,3 +169,49 @@ def wal_find_boundary(buf: bytes, start: int) -> int | None:
         return None
     off = l.gt_wal_find_boundary2(buf, len(buf), start)
     return None if off < 0 else int(off)
+
+
+def json_rows(columns) -> memoryview | None:
+    """The ``rows`` array of a /v1/sql reply, row-major, from whole numpy
+    columns of one length: the bytes ``json.dumps`` gives for the same
+    values as a list of lists (NaN as ``null``).  None when the library
+    or the symbol is missing, there is no column, or a column is of a
+    kind the encoder does not know (an object column holding anything
+    but ``str`` and ``None`` included): the caller keeps ``json.dumps``."""
+    l = lib()
+    if l is None or getattr(l, "_gt_no_json", False) or not columns:
+        return None
+    import numpy as np
+
+    n = len(columns[0])
+    cols = (GtJsonCol * len(columns))()
+    held = []  # what the pointers point into
+    for spec, col in zip(cols, columns):
+        kind = col.dtype.kind
+        if kind in _JSON_KIND and col.dtype.itemsize <= 8:
+            spec.kind, dtype = _JSON_KIND[kind]
+            data = np.ascontiguousarray(col, dtype=dtype)
+            spec.data = data.ctypes.data
+        elif kind in "OU":
+            import pyarrow as pa
+
+            try:
+                data = pa.array(col)
+                if pa.types.is_null(data.type):
+                    data = data.cast(pa.string())
+            except (pa.ArrowException, TypeError, ValueError):
+                return None  # not text: a lone surrogate, a number
+            if not (isinstance(data, pa.Array)
+                    and pa.types.is_string(data.type) and data.offset == 0):
+                return None
+            valid, offsets, text = data.buffers()
+            spec.kind = _JSON_STR
+            spec.offsets = offsets.address
+            spec.data = text.address if text is not None else None
+            spec.valid = valid.address if valid is not None else None
+        else:
+            return None
+        held.append(data)
+    out = np.empty(l.gt_json_rows_bound(cols, len(columns), n), np.uint8)
+    size = l.gt_json_rows(cols, len(columns), n, out.ctypes.data)
+    return memoryview(out)[:size]

@@ -6,12 +6,18 @@
 //   - CRC32 (zlib polynomial) for WAL record integrity
 //   - Snappy raw-format decompression (Prometheus remote write bodies)
 //   - WAL segment scanning: frame validation + torn-tail detection
+//   - the `rows` array of a /v1/sql reply, written from whole columns
 //
 // Build: make -C greptimedb_tpu/native      (produces libgreptime_native.so)
 // Bound via ctypes (greptimedb_tpu/native/__init__.py); every entry point
 // has a pure-python fallback so the library is an accelerator, not a
 // dependency.
 
+#if defined(__has_include)
+#if __has_include(<charconv>)
+#include <charconv>  // defines __cpp_lib_to_chars where doubles are covered
+#endif
+#endif
 #include <cstddef>
 #include <cstdint>
 #include <cstring>
@@ -212,5 +218,217 @@ int64_t gt_wal_find_boundary2(const uint8_t* buf, size_t len, size_t start) {
   }
   return -1;
 }
+
+// ---------------------------------------------------------------------------
+// JSON rows: the `rows` array of GreptimeDB's HTTP format, row-major
+// ([[v, v, ...], ...]), written from column pointers with the bytes that
+// Python's json.dumps gives for the same values as a list of lists:
+// float repr (shortest round-trip digits, ".0" on whole values, exponent
+// form below 1e-4 and from 1e16 with two exponent digits at least),
+// ensure_ascii string escapes, ", " between items.  Only where the
+// library has floating-point to_chars (libstdc++ 11 and later); without
+// it the symbol is absent and the caller keeps json.dumps.
+// ---------------------------------------------------------------------------
+
+#if defined(__cpp_lib_to_chars)
+
+enum GtJsonKind : int32_t {
+  GT_JSON_F64 = 0,   // data: double; NaN -> null
+  GT_JSON_I64 = 1,   // data: int64_t
+  GT_JSON_U64 = 2,   // data: uint64_t
+  GT_JSON_BOOL = 3,  // data: uint8_t
+  GT_JSON_STR = 4,   // data: UTF-8 bytes; offsets: int32_t[n + 1]
+};
+
+struct GtJsonCol {
+  int32_t kind;
+  const void* data;
+  const int32_t* offsets;  // strings only
+  const uint8_t* valid;    // bit i set = not null (Arrow bitmap) or NULL
+};
+
+static char* json_double(char* p, double v) {
+  if (v != v) return static_cast<char*>(memcpy(p, "null", 4)) + 4;
+  if (v - v != 0) {  // +-inf, as json.dumps(allow_nan=True) writes it
+    if (v < 0) *p++ = '-';
+    return static_cast<char*>(memcpy(p, "Infinity", 8)) + 8;
+  }
+  // shortest digits as d[.ddd]e[+-]XX, then laid out by repr()'s rule
+  char sci[32];
+  char* end = std::to_chars(sci, sci + sizeof sci, v,
+                            std::chars_format::scientific).ptr;
+  const char* s = sci;
+  if (*s == '-') *p++ = *s++;
+  char digits[20];
+  int nd = 0;
+  for (; *s != 'e'; s++)
+    if (*s != '.') digits[nd++] = *s;
+  int exp10 = 0;
+  bool neg = s[1] == '-';
+  for (s += 2; s < end; s++) exp10 = exp10 * 10 + (*s - '0');
+  int decpt = (neg ? -exp10 : exp10) + 1;
+  if (decpt <= -4 || decpt > 16) {
+    *p++ = digits[0];
+    if (nd > 1) {
+      *p++ = '.';
+      memcpy(p, digits + 1, nd - 1);
+      p += nd - 1;
+    }
+    *p++ = 'e';
+    int e = decpt - 1;
+    *p++ = e < 0 ? '-' : '+';
+    if (e < 0) e = -e;
+    if (e < 10) *p++ = '0';
+    return std::to_chars(p, p + 4, e).ptr;
+  }
+  if (decpt <= 0) {
+    *p++ = '0';
+    *p++ = '.';
+    for (int i = decpt; i < 0; i++) *p++ = '0';
+    memcpy(p, digits, nd);
+    return p + nd;
+  }
+  if (decpt >= nd) {
+    memcpy(p, digits, nd);
+    p += nd;
+    for (int i = nd; i < decpt; i++) *p++ = '0';
+    *p++ = '.';
+    *p++ = '0';
+    return p;
+  }
+  memcpy(p, digits, decpt);
+  p += decpt;
+  *p++ = '.';
+  memcpy(p, digits + decpt, nd - decpt);
+  return p + (nd - decpt);
+}
+
+static char* json_u_escape(char* p, uint32_t c) {
+  static const char hex[] = "0123456789abcdef";
+  *p++ = '\\';
+  *p++ = 'u';
+  *p++ = hex[(c >> 12) & 15];
+  *p++ = hex[(c >> 8) & 15];
+  *p++ = hex[(c >> 4) & 15];
+  *p++ = hex[c & 15];
+  return p;
+}
+
+// valid UTF-8 in (Arrow checked it), json.dumps(ensure_ascii=True) out
+static char* json_string(char* p, const uint8_t* s, const uint8_t* end) {
+  *p++ = '"';
+  while (s < end) {
+    uint32_t c = *s++;
+    if (c >= 0x20 && c < 0x7F && c != '"' && c != '\\') {
+      *p++ = static_cast<char>(c);
+      continue;
+    }
+    char short_esc = 0;
+    switch (c) {
+      case '"': short_esc = '"'; break;
+      case '\\': short_esc = '\\'; break;
+      case '\n': short_esc = 'n'; break;
+      case '\r': short_esc = 'r'; break;
+      case '\t': short_esc = 't'; break;
+      case '\b': short_esc = 'b'; break;
+      case '\f': short_esc = 'f'; break;
+    }
+    if (short_esc) {
+      *p++ = '\\';
+      *p++ = short_esc;
+      continue;
+    }
+    int more = c < 0x80 ? 0 : c < 0xE0 ? 1 : c < 0xF0 ? 2 : 3;
+    if (more) c &= 0x3F >> more;
+    for (; more && s < end; more--) c = (c << 6) | (*s++ & 0x3F);
+    if (c >= 0x10000) {
+      c -= 0x10000;
+      p = json_u_escape(p, 0xD800 | (c >> 10));
+      c = 0xDC00 | (c & 0x3FF);
+    }
+    p = json_u_escape(p, c);
+  }
+  *p++ = '"';
+  return p;
+}
+
+// An upper bound of what gt_json_rows writes for these columns.
+size_t gt_json_rows_bound(const GtJsonCol* cols, int32_t ncols,
+                          int64_t nrows) {
+  size_t row = 2;  // "[" "]"; each value below counts its ", "
+  size_t text = 0;
+  for (int32_t c = 0; c < ncols; c++) {
+    switch (cols[c].kind) {
+      case GT_JSON_F64: row += 26; break;   // -1.7976931348623157e+308
+      case GT_JSON_I64:
+      case GT_JSON_U64: row += 22; break;   // -9223372036854775808
+      case GT_JSON_BOOL: row += 7; break;   // false
+      default:                              // "..." or null
+        row += 6;
+        text += 6 * static_cast<size_t>(cols[c].offsets[nrows]
+                                        - cols[c].offsets[0]);
+    }
+  }
+  return 2 + static_cast<size_t>(nrows) * (row + 2) + text;
+}
+
+// Writes the array into out (at least gt_json_rows_bound bytes) and
+// returns its length.
+size_t gt_json_rows(const GtJsonCol* cols, int32_t ncols, int64_t nrows,
+                    char* out) {
+  char* p = out;
+  *p++ = '[';
+  for (int64_t r = 0; r < nrows; r++) {
+    if (r) {
+      *p++ = ',';
+      *p++ = ' ';
+    }
+    *p++ = '[';
+    for (int32_t c = 0; c < ncols; c++) {
+      const GtJsonCol& col = cols[c];
+      if (c) {
+        *p++ = ',';
+        *p++ = ' ';
+      }
+      if (col.valid && !((col.valid[r >> 3] >> (r & 7)) & 1)) {
+        memcpy(p, "null", 4);
+        p += 4;
+        continue;
+      }
+      switch (col.kind) {
+        case GT_JSON_F64:
+          p = json_double(p, static_cast<const double*>(col.data)[r]);
+          break;
+        case GT_JSON_I64:
+          p = std::to_chars(p, p + 20,
+                            static_cast<const int64_t*>(col.data)[r]).ptr;
+          break;
+        case GT_JSON_U64:
+          p = std::to_chars(p, p + 20,
+                            static_cast<const uint64_t*>(col.data)[r]).ptr;
+          break;
+        case GT_JSON_BOOL:
+          if (static_cast<const uint8_t*>(col.data)[r]) {
+            memcpy(p, "true", 4);
+            p += 4;
+          } else {
+            memcpy(p, "false", 5);
+            p += 5;
+          }
+          break;
+        default: {
+          const uint8_t* text = static_cast<const uint8_t*>(col.data);
+          p = json_string(p, text + col.offsets[r],
+                          text + col.offsets[r + 1]);
+        }
+      }
+    }
+    *p++ = ']';
+  }
+  *p++ = ']';
+  return static_cast<size_t>(p - out);
+}
+
+#endif  // __cpp_lib_to_chars
 
 }  // extern "C"

@@ -9,7 +9,7 @@ projection) mirrors the standard SQL operator order.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from collections.abc import Sequence
 
 import numpy as np
 
@@ -66,17 +66,76 @@ def _encode_replay(sel: Select, dbname: str) -> dict | None:
         return None
 
 
-@dataclass
+class ColumnRows(Sequence):
+    """A result's rows as whole columns: numpy arrays of one length, in
+    the order of the column names.  Reads as the ``list[list]`` that
+    ``to_rows`` builds, and writes nothing: the reply's encoder takes
+    ``columns`` as they are (servers/http.py ``_json_reply``)."""
+
+    __slots__ = ("columns",)
+
+    def __init__(self, columns: list[np.ndarray]):
+        self.columns = columns
+
+    def __len__(self) -> int:
+        return len(self.columns[0]) if self.columns else 0
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return ColumnRows([c[i] for c in self.columns])
+        i = range(len(self))[i]
+        return ColumnRows([c[i:i + 1] for c in self.columns]).to_rows()[0]
+
+    def __iter__(self):
+        return iter(self.to_rows())
+
+    def to_rows(self) -> list[list]:
+        """Column-wise: ndarray.tolist() converts to Python scalars in C
+        (no per-cell numpy scalar boxing), then one zip; NaN reads as
+        None."""
+        cols_py: list[list] = []
+        for col in self.columns:
+            lst = col.tolist()
+            if col.dtype.kind == "f" and bool(np.isnan(col).any()):
+                lst = [None if v != v else v for v in lst]
+            elif col.dtype.kind == "O":
+                lst = [_pyval(v) for v in lst]
+            cols_py.append(lst)
+        return [list(t) for t in zip(*cols_py)]
+
+
 class QueryResult:
-    column_names: list[str]
-    rows: list[list]
-    affected_rows: int = 0
-    # greptime type names per column (e.g. "Float64", "TimestampMillisecond")
-    column_types: list[str] | None = None
+    """One statement's answer.  ``QueryResult(names, rows)`` holds the
+    rows it was given; ``_shape`` gives ``columns`` and no rows, and
+    ``rows`` is then built on first read and kept.  From there on the
+    list is the result (its holder may change it), so the columns go."""
+
+    def __init__(self, column_names: list[str],
+                 rows: list[list] | None = None, affected_rows: int = 0,
+                 # greptime type names per column (e.g. "Float64")
+                 column_types: list[str] | None = None, *,
+                 columns: ColumnRows | None = None):
+        self.column_names = column_names
+        self.affected_rows = affected_rows
+        self.column_types = column_types
+        self.columns = columns
+        self._rows = [] if rows is None and columns is None else rows
+
+    @property
+    def rows(self) -> list[list]:
+        if self._rows is None:
+            self._rows = self.columns.to_rows()
+            self.columns = None
+        return self._rows
+
+    @rows.setter
+    def rows(self, rows: list[list]) -> None:
+        self._rows = rows
+        self.columns = None
 
     @property
     def num_rows(self) -> int:
-        return len(self.rows)
+        return len(self.columns if self._rows is None else self._rows)
 
     def to_pydict(self) -> dict[str, list]:
         return {
@@ -84,8 +143,16 @@ class QueryResult:
             for i, name in enumerate(self.column_names)
         }
 
+    def __eq__(self, other):
+        if other.__class__ is not self.__class__:
+            return NotImplemented
+        return (self.column_names, self.rows, self.affected_rows,
+                self.column_types) == (
+            other.column_names, other.rows, other.affected_rows,
+            other.column_types)
+
     def __repr__(self) -> str:
-        return f"QueryResult[{len(self.rows)} rows x {len(self.column_names)} cols]"
+        return f"QueryResult[{self.num_rows} rows x {len(self.column_names)} cols]"
 
 
 class TableProvider:
@@ -363,7 +430,7 @@ class QueryEngine:
             if res is not None:
                 mark("device_exec_ms", t)
                 if metrics is not None:
-                    metrics["output_rows"] = len(res.rows)
+                    metrics["output_rows"] = res.num_rows
                     metrics["expr_key_fold"] = True
                 return res
         if check is not None:
@@ -412,7 +479,7 @@ class QueryEngine:
                     mark("shape_ms", t)
                     if metrics is not None:
                         metrics["mesh_rows"] = True
-                        metrics["output_rows"] = len(result.rows)
+                        metrics["output_rows"] = result.num_rows
                     return result
         if pending is None:
             scan_seq0 = _scan_stats_seq()
@@ -434,7 +501,7 @@ class QueryEngine:
             result = self._shape(plan, env, n)
         mark("shape_ms", t)
         if metrics is not None:
-            metrics["output_rows"] = len(result.rows)
+            metrics["output_rows"] = result.num_rows
             metrics["scanned_rows_padded"] = scanned
         return result
 
@@ -908,22 +975,12 @@ class QueryEngine:
         if plan.limit is not None:
             idx = idx[: plan.limit]
 
-        # column-wise materialization: ndarray.tolist() converts to Python
-        # scalars in C (no per-cell numpy scalar boxing), then one zip —
-        # ~8x faster than per-cell indexing at 50k-row results
-        cols_py: list[list] = []
-        for name in names:
-            col = out_cols[name][idx]
-            lst = col.tolist()
-            if col.dtype.kind == "f" and bool(np.isnan(col).any()):
-                lst = [None if v != v else v for v in lst]
-            elif col.dtype.kind == "O":
-                lst = [_pyval(v) for v in lst]
-            cols_py.append(lst)
-        rows: list[list] = [list(t) for t in zip(*cols_py)] if names else []
-        return QueryResult(names, rows, column_types=[
-            _infer_type(item.expr, plan) for item in items
-        ])
+        # whole columns, no Python object a cell: the rows are built when
+        # somebody reads them (QueryResult.rows)
+        return QueryResult(
+            names, column_types=[_infer_type(item.expr, plan)
+                                 for item in items],
+            columns=ColumnRows([out_cols[name][idx] for name in names]))
 
 
 def _apply_sliding(plan: SelectPlan, env: dict, n: int) -> tuple[dict, int]:

@@ -428,7 +428,7 @@ def _make_frontend_http(frontend: DistFrontend, host: str, port: int):
             from aiohttp import web
 
             from greptimedb_tpu.servers.http import (
-                _error_json, _result_to_json,
+                _error_json, _json_reply, _result_to_json,
             )
 
             async def h_sql(request):
@@ -444,7 +444,7 @@ def _make_frontend_http(frontend: DistFrontend, host: str, port: int):
                 try:
                     res = await _asyncio.get_running_loop().run_in_executor(
                         None, self.frontend.sql, sql)
-                    return web.json_response(_result_to_json(res, t0))
+                    return _json_reply(_result_to_json(res, t0), "/v1/sql")
                 except Exception as e:  # noqa: BLE001
                     body, status = _error_json(e)
                     return web.json_response(body, status=status)

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import asyncio
 import json
+import os
 import re
 import threading
 import time
@@ -29,10 +30,11 @@ import time
 import numpy as np
 from aiohttp import web
 
+from greptimedb_tpu import native
 from greptimedb_tpu.errors import (
     GreptimeError, InvalidArguments, StatusCode, TableNotFound,
 )
-from greptimedb_tpu.query.engine import QueryResult
+from greptimedb_tpu.query.engine import ColumnRows, QueryResult
 from greptimedb_tpu.utils import telemetry
 from greptimedb_tpu.utils.snappy import decompress as snappy_decompress
 from greptimedb_tpu.utils.tracing import (
@@ -44,6 +46,12 @@ M_REQUESTS = telemetry.REGISTRY.counter(
 )
 M_LATENCY = telemetry.REGISTRY.histogram(
     "greptime_http_request_duration_seconds", "HTTP latency", ("path",)
+)
+# how a reply's body was built: "columns" is the native encoder over
+# whole columns, "rows" json.dumps over a list of rows (_json_reply)
+M_REPLY_ENCODED = telemetry.REGISTRY.counter(
+    "greptime_http_reply_encoded_total", "Reply bodies by encoder",
+    ("route", "encoder")
 )
 M_INGEST_ROWS = telemetry.REGISTRY.counter(
     "greptime_ingest_rows_total", "Rows ingested", ("protocol",)
@@ -89,8 +97,9 @@ def _result_to_json(res: QueryResult, t0: float) -> dict:
                     for n, t in zip(res.column_names, types)
                 ]
             },
-            "rows": res.rows,
-            "total_rows": len(res.rows),
+            # whole columns where the engine left them so (_json_reply)
+            "rows": res.rows if res.columns is None else res.columns,
+            "total_rows": res.num_rows,
         }
         output = [{"records": records}]
     else:
@@ -100,6 +109,38 @@ def _result_to_json(res: QueryResult, t0: float) -> dict:
         "output": output,
         "execution_time_ms": int((time.perf_counter() - t0) * 1000),
     }
+
+
+# stands in the envelope where the natively encoded rows go; a client
+# cannot guess it, and a reply that holds it twice takes json.dumps
+_ROWS_SLOT = f"rows:{os.urandom(12).hex()}"
+
+
+def _json_reply(body: dict, route: str, headers: dict | None = None
+                ) -> web.Response:
+    """The response for what ``_result_to_json`` returned.  Where its
+    ``rows`` are still whole columns (ColumnRows) of kinds the native
+    encoder knows, that writes them in one call and the envelope is
+    ``json.dumps`` around its bytes; anything else (no records, a list of
+    rows, an object column of something other than text, no library) is
+    ``json.dumps`` over the rows.  Both give the same bytes."""
+    records = body["output"][0].get("records")
+    rows = records["rows"] if records else None
+    if isinstance(rows, ColumnRows):
+        encoded = native.json_rows(rows.columns)
+        if encoded is not None:
+            records["rows"] = _ROWS_SLOT
+            parts = json.dumps(body).split(f'"{_ROWS_SLOT}"')
+            if len(parts) == 2:
+                M_REPLY_ENCODED.labels(route, "columns").inc()
+                return web.Response(
+                    body=b"".join((parts[0].encode(), encoded,
+                                   parts[1].encode())),
+                    content_type="application/json", charset="utf-8",
+                    headers=headers)
+        records["rows"] = rows.to_rows()
+    M_REPLY_ENCODED.labels(route, "rows").inc()
+    return web.json_response(body, headers=headers)
 
 
 def _error_json(e: Exception) -> tuple[dict, int]:
@@ -451,8 +492,8 @@ class HttpServer(ThreadedAiohttpApp):
                 # over the same span (record_held below), so sketch and
                 # histogram agree by construction.
                 with TRACER.stage_in(ctx, "serialize"):
-                    resp = web.json_response(_result_to_json(res, t0),
-                                             headers=_trace_headers(ctx))
+                    resp = _json_reply(_result_to_json(res, t0), "/v1/sql",
+                                       headers=_trace_headers(ctx))
                 if timed:
                     M_PROTOCOL_QUERY.labels("http").observe(
                         time.perf_counter() - t0)
@@ -1173,7 +1214,7 @@ class HttpServer(ThreadedAiohttpApp):
             return web.json_response({"error": f"bad json: {e}"}, status=400)
         try:
             res = await self._call(execute_log_query, self.db, query)
-            return web.json_response(_result_to_json(res, t0))
+            return _json_reply(_result_to_json(res, t0), "/v1/logs")
         except (AttributeError, TypeError, KeyError) as e:
             # malformed-but-parseable request shapes are client errors
             return web.json_response({"error": f"bad log query: {e}"},

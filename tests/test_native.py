@@ -1,5 +1,6 @@
 """Native C++ library tests: equivalence with the pure-python fallbacks."""
 
+import json
 import zlib
 
 import numpy as np
@@ -65,3 +66,22 @@ class TestNative:
         cut = data + b"\x99\x88\x77"
         spans, good_end = native.wal_scan(cut, 0)
         assert len(spans) == 1 and good_end == len(data)
+
+    def test_json_rows_matches_json_dumps(self):
+        if getattr(native.lib(), "_gt_no_json", False):
+            pytest.skip("libstdc++ without floating-point to_chars")
+        cols = [np.array(["a\"b", None, "é"], dtype=object),
+                np.array([1, -2, 3]),
+                np.array([0.5, np.nan, 1e16]),
+                np.array([True, False, True])]
+        assert bytes(native.json_rows(cols)) == json.dumps([
+            ["a\"b", 1, 0.5, True], [None, -2, None, False],
+            ["é", 3, 1e16, True]]).encode()
+
+
+def test_json_rows_without_the_symbol_is_none(monkeypatch):
+    """Like the WAL wrappers on an older .so: None, and the caller keeps
+    its pure-python road."""
+    if native.lib() is not None:
+        monkeypatch.setattr(native.lib(), "_gt_no_json", True, raising=False)
+    assert native.json_rows([np.array([1.0])]) is None
