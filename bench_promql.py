@@ -204,11 +204,11 @@ def main() -> None:
                 ev_total, ("promql", "any", "quota_reject")))
             _cache_stats["builds"] = sum(
                 int(REGISTRY.value(ev_total, ("promql", kind, "build")))
-                for kind in ("selection", "sort", "group", "bounds"))
+                for kind in ("selection", "sort", "group"))
             _cache_stats["evictions"] = sum(
                 int(REGISTRY.value(ev_total, ("promql", kind, "eviction")))
-                for kind in ("selection", "sort", "group", "bounds"))
-            for kind in ("selection", "sort", "group", "bounds"):
+                for kind in ("selection", "sort", "group"))
+            for kind in ("selection", "sort", "group"):
                 for event in ("hit", "miss"):
                     _cache_stats[f"{kind}_{event}es" if event == "miss"
                                  else f"{kind}_{event}s"] = int(

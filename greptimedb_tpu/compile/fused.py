@@ -4,10 +4,10 @@ The unfused evaluator runs `sum by (pod) (rate(m[5m]))` as one jitted
 window kernel plus a tail of EAGER device ops with host glue: the
 extrapolation epilogue (`_extrapolated`) and the cross-series segment
 reduction each dispatch separately.  This module lowers the whole chain
-— window stats over the presorted resident layout, the function
-epilogue, and the group reduction — into ONE jitted XLA program per
-shape class, so a warm aggregation is a single device dispatch (Data
-Path Fusion, arXiv 2605.10511).
+— window stats over the matched series' slab of the presorted resident
+layout, the function epilogue, and the group reduction — into ONE
+jitted XLA program per shape class, so a warm aggregation is a single
+device dispatch (Data Path Fusion, arXiv 2605.10511).
 
 Bit-exactness contract: the fused program COMPOSES the evaluator's own
 building blocks — ``_window_body`` (the exact function ``_window_kernel``
@@ -31,7 +31,7 @@ import jax.numpy as jnp
 
 from greptimedb_tpu.compile import named_jit
 from greptimedb_tpu.errors import TableNotFound
-from greptimedb_tpu.utils.tracing import TRACER
+from greptimedb_tpu.utils.tracing import M_WINDOW_ROWS, TRACER
 
 # diagnostics: fused dispatches this process (tests/bench read it)
 FUSED_DISPATCHES = {"count": 0}
@@ -179,14 +179,7 @@ def try_fused_aggregation(ev, e):
         return None  # pinned @: broadcast semantics stay unfused
     kind = _FUNC_KIND[func]
     try:
-        # allow_bounds=False: the per-series bounds matrix exists only
-        # when the PromQL cache is resident, so it would fork cached vs
-        # uncached evaluations into two DIFFERENT fused programs — whose
-        # XLA-level fusion/FMA choices can differ in the last ulp.  The
-        # eager path tolerated the fork (identical op-by-op rounding
-        # downstream); the fused program keeps ONE geometry so the PR-2
-        # cached-vs-uncached bit-exactness pin holds by construction.
-        prep = ev._prep_window(sel, kind, None, allow_bounds=False)
+        prep = ev._prep_window(sel, kind)
     except TableNotFound:
         return None  # unknown metric: unfused produces the empty vector
     args, p, tsids, labels, pinned, _start, rng = prep
@@ -234,6 +227,7 @@ def try_fused_aggregation(ev, e):
         fused_args = tuple(place(a) for a in fused_args)
     # AOT-store hits deserialize — first call is NOT an XLA compile
     compiling = jit_miss and not getattr(kern, "aot", False)
+    M_WINDOW_ROWS.inc(p.num_sel * p.slab_w)
     vals = ev._timed_kernel(
         "fused_kernel", lambda: kern(*fused_args), jit_miss, compiling,
         op=e.op, func=func or "instant")

@@ -26,6 +26,7 @@ either way (pinned by tests/test_fulltext.py)."""
 
 from __future__ import annotations
 
+import functools
 import json as _json
 import re
 
@@ -45,7 +46,6 @@ from greptimedb_tpu.utils.tracing import TRACER
 
 DEFAULT_TABLE = "loki_logs"
 DEFAULT_LIMIT = 100
-_I64_MAX = np.int64(np.iinfo(np.int64).max)
 
 
 # ---------------------------------------------------------------------------
@@ -98,18 +98,16 @@ def _filter_pred(f: LineFilter):
 # ---------------------------------------------------------------------------
 
 
-@jax.jit
-def _logs_layout(ts, tsid, mask):  # gl: warm-path
-    any_valid = mask.any()
-    ts_min = jnp.where(
-        any_valid, jnp.min(jnp.where(mask, ts, _I64_MAX)), jnp.int64(0))
-    ts_max = jnp.where(
-        any_valid,
-        jnp.max(jnp.where(mask, ts, jnp.int64(-(1 << 62)))), jnp.int64(0))
-    kp = ts_max - ts_min + 2
-    key = jnp.where(mask, tsid.astype(jnp.int64) * kp + (ts - ts_min),
-                    _I64_MAX)
-    return key, ts_min, kp
+@functools.partial(jax.jit, static_argnums=3)
+def _logs_layout(ts, tsid, mask, total_series):  # gl: warm-path
+    """What the PromQL window kernels read of a sort layout besides the
+    values: the timestamps' words, and the row pointer, densest spacing
+    and longest run of the resident log table, which is (tsid, ts)-sorted
+    with its padding at the end."""
+    from greptimedb_tpu.promql.engine import _row_pointer, _split_i64
+
+    return _split_i64(ts) + _row_pointer(
+        ts, tsid, mask.sum(dtype=jnp.int32), total_series)
 
 
 @jax.jit
@@ -226,7 +224,7 @@ class LokiEvaluator:
         """[S, T] window values + per-series labels + step timestamps.
         Windows are PromQL's left-exclusive (t - range, t]."""
         from greptimedb_tpu.promql.engine import (
-            _KERNEL_CACHE, WindowParams, _window_kernel,
+            _KERNEL_CACHE, WindowParams, _window_kernel, slab_width,
         )
 
         q = agg.query
@@ -253,7 +251,10 @@ class LokiEvaluator:
                 verified)
 
         with TRACER.stage("logql_window", fn=agg.fn):
-            key, ts_min, kp = _logs_layout(ts, tsid, mask)
+            total = max(self.view.num_series, 1)
+            ts_hi, ts_lo, row_ptr, spacing, max_run = _logs_layout(
+                ts, tsid, mask, total)
+            spacing, max_run = jax.device_get((spacing, max_run))  # gl: allow[GL-H001] -- the slab's width is a shape: one read a metric eval
             if agg.fn in ("bytes_over_time", "bytes_rate"):
                 vals = _byte_vals(codes, verified, self._byte_lengths(npad),
                                   mask)
@@ -263,20 +264,21 @@ class LokiEvaluator:
                 ind = vals
             p = WindowParams(
                 step_ms=step_u, num_steps=T, range_ms=range_u,
-                num_sel=int(sel_dev.shape[0]),
-                total_series=max(self.view.num_series, 1),
-                kind="gauge_window")
+                num_sel=int(sel_dev.shape[0]), total_series=total,
+                kind="gauge_window",
+                slab_w=slab_width(step_u, T, range_u, int(spacing),
+                                  int(max_run)))
             kern = _KERNEL_CACHE.get(p)
             if kern is None:
                 kern = _window_kernel(p)
                 _KERNEL_CACHE[p] = kern
-            out = kern(key, ts, vals, tsid, mask, ts_min, kp, sel_dev,
+            out = kern(ts_hi, ts_lo, vals, row_ptr, sel_dev,
                        np.int64(start_u))
             sums = np.asarray(out["sum"])[: len(sel_tsids)]  # gl: allow[GL-H001] -- THE one [S, T] result readback per metric eval
             if ind is vals:
                 counts = sums
             else:
-                out2 = kern(key, ts, ind, tsid, mask, ts_min, kp, sel_dev,
+                out2 = kern(ts_hi, ts_lo, ind, row_ptr, sel_dev,
                             np.int64(start_u))
                 counts = np.asarray(out2["sum"])[: len(sel_tsids)]
         values = self._finish_range_fn(agg, sums, range_u)

@@ -4,11 +4,13 @@ Pipeline per selector (SURVEY.md §3.3's hot loop, TPU-shaped):
 1. host: match series against label matchers over the region's series
    registry (dictionary codes, no string work on device);
 2. device: one jitted window kernel per (table shape-class, range, steps)
-   computes per-(series, step) window stats — boundaries by composite-key
-   searchsorted over the (tsid, ts)-sorted resident table, sums by
-   counter-reset-adjusted cumulative sums (exact Prometheus extrapolation,
-   reference src/promql/src/functions/extrapolate_rate.rs:56), min/max by
-   multi-bucket segment scatter;
+   gathers the matched series' samples that can fall in a window out of
+   the (tsid, ts)-sorted resident table into a dense [series, W] slab and
+   computes per-(series, step) window stats on it — boundaries by counting
+   slab timestamps, sums by counter-reset-adjusted prefix sums along W
+   (exact Prometheus extrapolation, reference
+   src/promql/src/functions/extrapolate_rate.rs:56), min/max under the
+   window mask;
 3. device: cross-series aggregation = segment reduction over the series
    axis; binary-op vector matching joins series on host, aligns rows on
    device.
@@ -23,6 +25,7 @@ import collections.abc
 import math
 import os
 import re
+import typing
 from dataclasses import dataclass, field
 
 import jax
@@ -36,7 +39,7 @@ from greptimedb_tpu.promql.parser import (
     StringLit, SubqueryExpr, UnaryExpr, VectorSelector, parse_promql,
 )
 from greptimedb_tpu.storage.memtable import TSID
-from greptimedb_tpu.utils.tracing import TRACER
+from greptimedb_tpu.utils.tracing import M_WINDOW_ROWS, TRACER
 
 DEFAULT_LOOKBACK_S = 300.0
 
@@ -232,29 +235,99 @@ class WindowParams:
     num_sel: int  # padded selected series count
     total_series: int
     kind: str  # which stats to compute
-    # padded max samples-per-series when the resident per-series bounds
-    # matrix serves window geometry (None = searchsorted over the full
-    # sorted key array); part of the key because the two geometries
-    # compile to different programs
-    bounds_l: int | None = None
+    # samples gathered a matched series (``slab_width``): a function of
+    # the query's span and the layout's spacing, never of a request's
+    # times, so every evaluation of one dashboard panel shares a program
+    slab_w: int
 
 
 _KERNEL_CACHE: dict[WindowParams, object] = {}
 
+# widest slab that is swept: up to it window edges, picks and min/max are
+# compare-select passes over [S, T, W] (4 ps a cell on a v5e), past it
+# searches and gathers (10 ns an element there, whatever W) — the same
+# integers and the same picked values either way (PERF.md, PR 31)
+_SWEEP_WIDTH = 1 << 13
 
-@named_jit("promql_sort_layout")
-def _build_sort_layout(ts, val, tsid, mask):
+
+def _pow2(n: int) -> int:
+    """The power of two at or above ``n`` (1 for n ≤ 1)."""
+    return 1 << (max(int(n), 1) - 1).bit_length()
+
+
+def _split_i64(x):
+    """An int64 array as (high int32, low uint32) words.  The resident
+    layout keeps timestamps this way: the TPU has no 64-bit integers, and
+    an int64 ARGUMENT is split into its words by every program that takes
+    it — two table-sized writes a request; words split once, when the
+    layout is built, are read in place."""
+    return (x >> 32).astype(jnp.int32), x.astype(jnp.uint32)
+
+
+def _join_i64(hi, lo):
+    return (hi.astype(jnp.int64) << 32) | lo.astype(jnp.int64)
+
+
+def slab_width(step_ms: int, num_steps: int, range_ms: int, spacing: int,
+               max_run: int) -> int:
+    """Slab width W for one shape class: every sample a series can have
+    in (start − range, end] fits when consecutive samples lie at least
+    ``spacing`` apart (the layout's densest spacing), rounded up to a
+    power of two and capped at the longest series' padded run.
+    Irregular data with a tiny smallest gap simply gets the cap: the
+    whole series row."""
+    span = (num_steps - 1) * step_ms + range_ms
+    return min(_pow2(span // max(int(spacing), 1) + 2), _pow2(max_run))
+
+
+def _count_le(probe, length, thr, bits: int):
+    """For each query, how many of the first ``length`` elements of its
+    ascending run are ≤ ``thr``; ``probe(i)`` reads element ``i`` of each
+    query's run.  A branchless binary search unrolled over ``bits``
+    rounds (2**bits must exceed every length), each round one gather of
+    a scalar a query — no loop the compiler carries an operand through."""
+    c = jnp.zeros_like(length)
+    for k in reversed(range(bits)):
+        cand = c + (1 << k)
+        c = jnp.where((cand <= length) & (probe(cand - 1) <= thr), cand, c)
+    return c
+
+
+def _row_pointer(ts_s, tsid_s, n_valid, total_series: int):
+    """Query-independent geometry of a (tsid, ts)-sorted layout whose
+    ``n_valid`` valid rows come first: the CSR row pointer
+    i32[total_series + 1] (series s owns rows [ptr[s], ptr[s+1])), the
+    densest spacing (smallest gap between two consecutive samples of one
+    series) and the longest run."""
+    n = ts_s.shape[0]
+    sids = jnp.arange(total_series + 1, dtype=jnp.int32)
+    row_ptr = _count_le(
+        lambda i: tsid_s[jnp.clip(i, 0, n - 1)],
+        jnp.broadcast_to(n_valid.astype(jnp.int32), sids.shape), sids - 1,
+        n.bit_length())
+    same = (tsid_s[1:] == tsid_s[:-1]) & (
+        jnp.arange(1, n, dtype=jnp.int32) < n_valid)
+    spacing = jnp.min(jnp.where(same, ts_s[1:] - ts_s[:-1], _I64_MAX),
+                      initial=_I64_MAX)
+    max_run = jnp.max(row_ptr[1:] - row_ptr[:-1])
+    return row_ptr, spacing, max_run
+
+
+@named_jit("promql_sort_layout", static_argnums=4)
+def _build_sort_layout(ts, val, tsid, mask, total_series: int):
     """Composite-key sort of a resident table, QUERY-INDEPENDENT: the key
     packs (tsid, ts − ts_min) with a stride covering the table's full time
-    span, so the permutation (and the gathered ts/val/tsid/valid arrays)
-    depends only on the data — it is built once per (region generation,
-    field column) and served resident by PromLayoutCache instead of being
+    span, so the permutation (and everything derived from it) depends
+    only on the data — it is built once per (region generation, field
+    column) and served resident by PromLayoutCache instead of being
     re-derived inside every window kernel call.  Invalid rows (padding,
-    NULL values) sort to the end via a +inf key.
+    NULL values) sort to the end via a +inf key, so each series' run
+    holds valid samples only.
 
-    Returns (key_s, ts_s, val_s, tsid_s, valid_s, ts_min, kp); ts_min/kp
-    are 0-d device scalars, traced through the kernels so one compiled
-    program serves every region of the same shape class.
+    Returns (ts_hi, ts_lo, val_s, row_ptr, spacing, max_run): the sorted
+    columns (timestamps as ``_split_i64`` words) and ``_row_pointer``'s
+    geometry (spacing/max_run are 0-d; the caller reads them once, as
+    host integers, when the layout is built).
     """
     valid = mask & ~jnp.isnan(val)
     any_valid = valid.any()
@@ -263,120 +336,152 @@ def _build_sort_layout(ts, val, tsid, mask):
     ts_max = jnp.where(
         any_valid,
         jnp.max(jnp.where(valid, ts, jnp.int64(-(1 << 62)))), jnp.int64(0))
-    # stride: rel = ts - ts_min ∈ [0, kp-2], so clip-to-(kp-1) bounds stay
-    # strictly above every data key (searchsorted side="right" correctness)
-    kp = ts_max - ts_min + 2
+    kp = ts_max - ts_min + 2  # stride: rel = ts - ts_min ∈ [0, kp-2]
     key = jnp.where(valid, tsid.astype(jnp.int64) * kp + (ts - ts_min),
                     _I64_MAX)
     order = jnp.argsort(key)
-    return (key[order], ts[order], val[order], tsid[order], valid[order],
-            ts_min, kp)
+    ts_s = ts[order]
+    return _split_i64(ts_s) + (val[order],) + _row_pointer(
+        ts_s, tsid[order], valid.sum(dtype=jnp.int32), total_series)
 
 
-def _sorted_window_bounds(p: WindowParams, key_s, ts_min, kp, sel_tsids,
-                          start_ms, bounds=None):
+class Slab(typing.NamedTuple):
+    """The matched series' samples that can fall in (start − range, end],
+    dense [S, W], and the window edges over it — see _slab_geometry."""
+
+    rel: jnp.ndarray  # [S, W] ts − start_ms; sentinels outside the run
+    val: jnp.ndarray  # [S, W] f32, 0 outside the run
+    ok: jnp.ndarray  # [S, W] column holds a sample of this series
+    lo: jnp.ndarray  # [S, T] first slab column of each window
+    hi: jnp.ndarray  # [S, T] one past its last
+    cnt: jnp.ndarray  # [S, T] i32 samples in the window
+    has: jnp.ndarray  # [S, T] window non-empty and series selected
+    sel_ok: jnp.ndarray  # [S]
+    sweep: bool  # W is within _SWEEP_WIDTH
+
+
+def _slab_edges(rel, thr, sweep: bool):
+    """[S, T] count of each slab row's columns with ``rel`` ≤ ``thr[t]``
+    (rows ascend: −inf sentinels, the samples, +inf sentinels): compares
+    over [S, T, W], or past _SWEEP_WIDTH a log-W search along the slab's
+    axis — the same integers either way."""
+    S, w = rel.shape
+    T = thr.shape[0]
+    if sweep:
+        return jnp.sum(rel[:, None, :] <= thr[None, :, None], axis=-1,
+                       dtype=jnp.int32)
+    return _count_le(
+        lambda i: jnp.take_along_axis(rel, jnp.clip(i, 0, w - 1), axis=1),
+        jnp.full((S, T), w, jnp.int32),
+        jnp.broadcast_to(thr[None, :], (S, T)), w.bit_length())
+
+
+def _slab_geometry(p: WindowParams, ts_hi, ts_lo, val_s, row_ptr, sel_tsids,
+                   start_ms) -> Slab:
     """Shared window geometry for all window kernels over a PRESORTED
-    resident layout (_build_sort_layout): per-(series, step) half-open
-    sample ranges [lo, hi) with LEFT-EXCLUSIVE window semantics
-    (t - range, t] — the ONE definition the stats kernel and the matrix
-    kernels build on.
+    resident layout (_build_sort_layout): the ONE definition the stats
+    kernel, the matrix kernels and the fused programs build on.
 
-    Two interchangeable geometries (identical integer bounds, so results
-    are bit-exact either way):
+    Gathers, for each selected series, ``p.slab_w`` consecutive rows of
+    its run from its first sample after ``start − range`` on (found in
+    the series' own run through ``row_ptr``), and places every window's
+    half-open column range [lo, hi) on that slab with LEFT-EXCLUSIVE
+    window semantics (t - range, t].  After the gather nothing has the
+    table's length: work is proportional to the matched series, not to
+    the table."""
+    T, S, w = p.num_steps, p.num_sel, p.slab_w
+    n = val_s.shape[0]
+    # padding slots (-1) and series newer than the layout own no rows
+    sel_ok = (sel_tsids >= 0) & (sel_tsids < row_ptr.shape[0] - 1)
+    sid = jnp.clip(sel_tsids, 0, row_ptr.shape[0] - 2)
+    r0 = row_ptr[sid]
+    run = jnp.where(sel_ok, row_ptr[sid + 1] - r0, 0)
 
-    - searchsorted (default): composite-key binary search over the full
-      sorted array — O(S·T·log N) RANDOM accesses, the right shape for
-      many steps;
-    - per-series bounds matrix (``bounds`` = (series_start [S], cnt_s [S],
-      ts_mat [S, L]), resident per selection): each window boundary is a
-      count of that series' timestamps ≤ threshold — O(S·T·L) SEQUENTIAL
-      compares, ~10× faster for instant-style queries where the
-      binary search is DRAM-latency-bound.
+    def ts_at(i):
+        at = jnp.clip(r0 + i, 0, n - 1)
+        return _join_i64(ts_hi[at], ts_lo[at])
 
-    Returns (lo, hi, cnt, has, sel_ok, n)."""
-    T = p.num_steps
-    S = p.num_sel
-    n = key_s.shape[0]
-    steps = start_ms + p.step_ms * jnp.arange(T, dtype=jnp.int64)  # [T]
-    sel_ok = sel_tsids >= 0
-    if bounds is not None:
-        series_start, cnt_s, ts_mat = bounds
-        # lo offset = #samples with ts ≤ t − range (left-exclusive window
-        # starts right after them); hi offset = #samples with ts ≤ t.
-        # Padding slots hold I64_MAX so they never count.
-        lo_off = jnp.sum(
-            ts_mat[:, None, :] <= (steps - p.range_ms)[None, :, None],
-            axis=-1, dtype=jnp.int32)
-        hi_off = jnp.sum(
-            ts_mat[:, None, :] <= steps[None, :, None],
-            axis=-1, dtype=jnp.int32)
-        lo = series_start[:, None] + lo_off
-        hi = series_start[:, None] + hi_off
-        cnt = hi_off - lo_off
-        has = (cnt > 0) & sel_ok[:, None]
-        return lo, hi, cnt, has, sel_ok, n
-    sel64 = sel_tsids.astype(jnp.int64)  # [S]
-    skey = jnp.where(sel_ok, sel64, 0) * kp  # [S]
-    # window (t - range, t]: left-exclusive.  rel_hi clips to -1 (a key
-    # strictly below this series' first sample) so windows entirely before
-    # the data come out empty; both clips cap at kp-1 > every data rel.
-    rel_lo = jnp.clip(steps[None, :] - p.range_ms + 1 - ts_min, 0, kp - 1)
-    rel_hi = jnp.clip(steps[None, :] - ts_min, -1, kp - 1)
-    lo = jnp.searchsorted(
-        key_s, (skey[:, None] + rel_lo).reshape(-1), side="left"
-    ).reshape(S, T)
-    hi = jnp.searchsorted(
-        key_s, (skey[:, None] + rel_hi).reshape(-1), side="right"
-    ).reshape(S, T)
-    cnt = jnp.maximum(hi - lo, 0).astype(jnp.int32)
+    base = r0 + _count_le(ts_at, run, start_ms - p.range_ms, n.bit_length())
+    # the table read as [n/128, 128] (a bitcast of the TPU's 1-D tiling),
+    # whole chunks gathered from the one that holds ``base``: ONE gather op
+    # a column (the TPU compiler turns a W-long slice-gather from a 1-D
+    # operand into a loop with an iteration a series).  Columns before
+    # ``base`` or past the run are masked.
+    c = math.gcd(n, 128)
+    k = -(-w // c) + 1
+    chunk = (base // c)[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
+    rows = (chunk[:, :, None] * c
+            + jnp.arange(c, dtype=jnp.int32)[None, None, :]).reshape(S, k * c)
+    before = rows < base[:, None]
+    ok = ~before & (rows < (r0 + run)[:, None])
+
+    def take(a):
+        return a.reshape(n // c, c)[
+            jnp.clip(chunk, 0, n // c - 1)].reshape(S, k * c)
+
+    val = jnp.where(ok, take(val_s), 0.0)
+    # timestamps rebased to start_ms; int32 where the query's span fits
+    # (the compare sweep is the slab's widest pass): integer compares stay
+    # exact, and a sample beyond the span saturates below the sentinel
+    steps = p.step_ms * np.arange(T, dtype=np.int64)
+    if int(steps[-1]) + p.range_ms < (1 << 31) - 2:
+        tdt, big = np.int32, (1 << 31) - 1
+    else:
+        tdt, big = np.int64, 1 << 62
+    rel = jnp.clip(_join_i64(take(ts_hi), take(ts_lo)) - start_ms,
+                   -big, big - 1).astype(tdt)
+    rel = jnp.where(ok, rel, jnp.where(before, -big - 1, big).astype(tdt))
+    sweep = w <= _SWEEP_WIDTH
+    lo = _slab_edges(rel, jnp.asarray((steps - p.range_ms).astype(tdt)),
+                     sweep)
+    hi = _slab_edges(rel, jnp.asarray(steps.astype(tdt)), sweep)
+    cnt = hi - lo
     has = (cnt > 0) & sel_ok[:, None]
-    return lo, hi, cnt, has, sel_ok, n
-
-
-@named_jit("promql_series_ranges")
-def _series_ranges(key_s, kp, sel_tsids):
-    """Query-independent row range of each selected series in the sorted
-    layout: [start, start+cnt).  skey+kp−1 exceeds every key of the series
-    (rel ≤ kp−2) and undercuts the next series' first key (skey+kp)."""
-    sel_ok = sel_tsids >= 0
-    skey = jnp.where(sel_ok, sel_tsids.astype(jnp.int64), 0) * kp
-    start = jnp.searchsorted(key_s, skey, side="left")
-    end = jnp.searchsorted(key_s, skey + (kp - 1), side="right")
-    return start, jnp.where(sel_ok, (end - start).astype(jnp.int32), 0)
-
-
-@named_jit("promql_gather_ts_mat", static_argnums=3)
-def _gather_ts_mat(ts_s, start, cnt_s, L: int):
-    """[S, L] per-series timestamp matrix (padding = I64_MAX so threshold
-    compares never count it); rows gathered from the sorted layout."""
-    n = ts_s.shape[0]
-    j = jnp.arange(L, dtype=jnp.int32)
-    idx = jnp.clip(start[:, None] + j[None, :], 0, n - 1)
-    mat = ts_s[idx]
-    return jnp.where(j[None, :] < cnt_s[:, None], mat, _I64_MAX)
+    return Slab(rel, val, ok, lo, hi, cnt, has, sel_ok, sweep)
 
 
 def _prefix_sum(x):
-    """Inclusive prefix sum of a 1-D array by doubling shifts: log2(n)
-    whole-array adds instead of ``jnp.cumsum``.  The table-wide prefixes
-    below have to be f64 (they reach 1e9 and windows difference them),
-    and the TPU compiler takes minutes over an f64 ``cumsum`` at any
-    length (ROADMAP A4 has the seconds), while it builds this form in
-    seconds.  Same dtype, same sums, pairwise instead of running
-    order."""
+    """Inclusive prefix sum along the last axis by doubling shifts:
+    log2(n) whole-array adds instead of ``jnp.cumsum``.  The prefixes
+    below have to be f64 (windows difference them), and the TPU compiler
+    takes minutes over an f64 ``cumsum`` at any length (ROADMAP A4 has the
+    seconds), while it builds this form in seconds.  Same dtype, same
+    sums, pairwise instead of running order."""
     k = 1
-    while k < x.shape[0]:
-        x = x + jnp.concatenate([jnp.zeros((k,), x.dtype), x[:-k]])
+    while k < x.shape[-1]:
+        x = x + jnp.concatenate(
+            [jnp.zeros(x.shape[:-1] + (k,), x.dtype), x[..., :-k]], axis=-1)
         k *= 2
     return x
+
+
+def _range_extreme(level, lo, hi, cnt, op):
+    """min/max of ``level`` [S, W] over each window's columns [lo, hi)
+    from a sparse table built level by level: a window of c samples is
+    two overlapping blocks of 2**floor(log2 c) — O(S·W·log W + S·T·log W)
+    where the masked sweep over [S, T, W] would not end."""
+    w = level.shape[-1]
+    fill = jnp.inf if op is jnp.minimum else -jnp.inf
+    res = jnp.full(lo.shape, fill, level.dtype)
+    k_of = 31 - jax.lax.clz(jnp.maximum(cnt, 1))
+    for k in range(w.bit_length()):
+        b = 1 << k
+        pick = op(
+            jnp.take_along_axis(level, jnp.clip(lo, 0, w - 1), axis=1),
+            jnp.take_along_axis(level, jnp.clip(hi - b, 0, w - 1), axis=1))
+        res = jnp.where((k_of == k) & (cnt > 0), pick, res)
+        level = op(level, jnp.concatenate(
+            [level[:, b:], jnp.full((level.shape[0], min(b, w)), fill,
+                                    level.dtype)], axis=1))
+    return res
 
 
 def _window_kernel(p: WindowParams):  # gl: warm-path
     """Build the jitted kernel computing window stats for selected series.
 
-    Inputs: the presorted resident layout (key_s [N] i64, ts_s [N] i64,
-            val_s [N] f32, tsid_s [N] i32, valid_s [N] bool, ts_min, kp —
-            see _build_sort_layout), sel_tsids [S] i32 (padded with -1),
+    Inputs: the presorted resident layout (ts_hi [N] i32, ts_lo [N] u32,
+            val_s [N] f32, row_ptr [total_series + 1] i32 — see
+            _build_sort_layout), sel_tsids [S] i32 (padded with -1),
             start_ms scalar i64.
     Output dict of [S, T] arrays depending on p.kind.
     """
@@ -391,46 +496,57 @@ def _window_body(p: WindowParams):  # gl: warm-path
     program source means fused and unfused window math can never
     diverge."""
 
-    T = p.num_steps
     S = p.num_sel
 
-    def kernel(key_s, ts_s, val_s, tsid_s, valid_s, ts_min, kp, *rest):
-        if p.bounds_l is not None:
-            series_start, cnt_s, ts_mat, sel_tsids, start_ms = rest
-            bounds = (series_start, cnt_s, ts_mat)
-        else:
-            sel_tsids, start_ms = rest
-            bounds = None
-        lo, hi, cnt, has, sel_ok, n = _sorted_window_bounds(
-            p, key_s, ts_min, kp, sel_tsids, start_ms, bounds)
+    def kernel(ts_hi, ts_lo, val_s, row_ptr, sel_tsids, start_ms):
+        slab = _slab_geometry(p, ts_hi, ts_lo, val_s, row_ptr, sel_tsids,
+                              start_ms)
+        rel, val, ok, lo, hi, cnt, has, sel_ok, sweep = slab
+        w = val.shape[1]
 
-        # per-series counter-reset adjustment (for counter kinds)
+        def pick(a, i):
+            """a[s, i[s, t]]: one compare-select pass where the slab is
+            swept (a single non-zero term, so the sum is the value), else
+            a gather."""
+            if not sweep:
+                return jnp.take_along_axis(a, i, axis=1)
+            cols = jnp.arange(a.shape[1], dtype=jnp.int32)[None, None, :]
+            return jnp.sum(
+                jnp.where(cols == i[:, :, None], a[:, None, :], 0), axis=-1)
+
+        def pick_ts(i):
+            return start_ms + pick(rel, i).astype(jnp.int64)
+
+        # per-series counter-reset adjustment (for counter kinds).  A
+        # window never reads a drop at or before its own first sample, so
+        # prefixes that start at the slab's first column give the same
+        # differences as table-wide ones
         prev_same = jnp.concatenate(
-            [jnp.array([False]), (tsid_s[1:] == tsid_s[:-1]) & valid_s[1:] & valid_s[:-1]]
-        )
-        prev_val = jnp.concatenate([val_s[:1] * 0, val_s[:-1]])
-        drop = jnp.where(prev_same & (prev_val > val_s), prev_val, 0.0)
+            [jnp.zeros((S, 1), bool), ok[:, 1:] & ok[:, :-1]], axis=1)
+        prev_val = jnp.concatenate(
+            [jnp.zeros((S, 1), val.dtype), val[:, :-1]], axis=1)
+        drop = jnp.where(prev_same & (prev_val > val), prev_val, 0.0)
         gdrop = _prefix_sum(drop.astype(jnp.float64))
-        # offset at series start: first valid index per selected series found
-        # via searchsorted of tsid*K
-        adj = val_s.astype(jnp.float64) + gdrop  # minus series-start gdrop via window diff
+        adj = val.astype(jnp.float64) + gdrop
 
-        # cumulative sums (leading zero) over sorted order
+        # cumulative sums (leading zero) along each series' slab row;
+        # ``val`` is already 0 outside the run
         def cs(x):
-            x64 = x.astype(jnp.float64)
-            return jnp.concatenate([jnp.zeros(1, jnp.float64), _prefix_sum(x64)])
+            return jnp.concatenate(
+                [jnp.zeros((S, 1), jnp.float64),
+                 _prefix_sum(x.astype(jnp.float64))], axis=1)
 
-        cs_v = cs(jnp.where(valid_s, val_s, 0.0))
-        cs_v2 = cs(jnp.where(valid_s, val_s.astype(jnp.float64) ** 2, 0.0))
-        tsec = (ts_s - start_ms).astype(jnp.float64) / 1000.0
-        cs_t = cs(jnp.where(valid_s, tsec, 0.0))
-        cs_tv = cs(jnp.where(valid_s, tsec * val_s.astype(jnp.float64), 0.0))
-        cs_t2 = cs(jnp.where(valid_s, tsec * tsec, 0.0))
+        cs_v = cs(val)
+        cs_v2 = cs(val.astype(jnp.float64) ** 2)
+        tsec = jnp.where(ok, rel, 0).astype(jnp.float64) / 1000.0
+        cs_t = cs(tsec)
+        cs_tv = cs(tsec * val.astype(jnp.float64))
+        cs_t2 = cs(tsec * tsec)
 
         has2 = (cnt >= 2) & sel_ok[:, None]
 
-        first_i = jnp.clip(lo, 0, n - 1)
-        last_i = jnp.clip(hi - 1, 0, n - 1)
+        first_i = jnp.clip(lo, 0, w - 1)
+        last_i = jnp.clip(hi - 1, 0, w - 1)
         out = {}
         fcnt = cnt.astype(jnp.float32)
         nan = jnp.float32(jnp.nan)
@@ -439,101 +555,81 @@ def _window_body(p: WindowParams):  # gl: warm-path
                       "instant"):
             out["count"] = jnp.where(has, fcnt, 0.0)
         if p.kind == "instant":
-            lastv = val_s[last_i]
-            out["last"] = jnp.where(has, lastv, nan)
-            out["last_ts"] = jnp.where(has, ts_s[last_i], 0)
+            out["last"] = jnp.where(has, pick(val, last_i), nan)
+            out["last_ts"] = jnp.where(has, pick_ts(last_i), 0)
         if p.kind == "counter":
-            ft = ts_s[first_i]
-            lt = ts_s[last_i]
-            fv = val_s[first_i]
-            d_adj = (adj[last_i] - adj[first_i]).astype(jnp.float32)
-            out["first_ts"] = jnp.where(has, ft, 0)
-            out["last_ts"] = jnp.where(has, lt, 0)
+            fv = pick(val, first_i)
+            lv = pick(val, last_i)
+            d_adj = (pick(adj, last_i) - pick(adj, first_i)).astype(
+                jnp.float32)
+            out["first_ts"] = jnp.where(has, pick_ts(first_i), 0)
+            out["last_ts"] = jnp.where(has, pick_ts(last_i), 0)
             out["first_val"] = jnp.where(has, fv, nan)
-            out["last_val"] = jnp.where(has, val_s[last_i], nan)
+            out["last_val"] = jnp.where(has, lv, nan)
             out["delta_adj"] = jnp.where(has2, d_adj, nan)
-            out["delta_raw"] = jnp.where(
-                has2, val_s[last_i] - val_s[first_i], nan
-            )
+            out["delta_raw"] = jnp.where(has2, lv - fv, nan)
         if p.kind == "counter_rc":
             # resets/changes counts via indicator cumsums — a SEPARATE
             # kind so the (much hotter) rate/increase/delta path doesn't
-            # pay two extra full-table cumsums it never reads
-            ind_reset = jnp.where(prev_same & (prev_val > val_s), 1.0, 0.0)
-            ind_change = jnp.where(prev_same & (prev_val != val_s), 1.0, 0.0)
+            # pay two extra prefixes it never reads
+            ind_reset = jnp.where(prev_same & (prev_val > val), 1.0, 0.0)
+            ind_change = jnp.where(prev_same & (prev_val != val), 1.0, 0.0)
             cs_r = cs(ind_reset)
             cs_c = cs(ind_change)
             # exclude the boundary pair crossing into the window: indicator at
             # index i compares i-1,i; window pairs are (lo+1..hi-1)
-            lo1 = jnp.clip(lo + 1, 0, n)
-            out["resets"] = jnp.where(has, (cs_r[hi] - cs_r[lo1]).astype(jnp.float32), nan)
-            out["changes"] = jnp.where(has, (cs_c[hi] - cs_c[lo1]).astype(jnp.float32), nan)
+            lo1 = jnp.clip(lo + 1, 0, w)
+            out["resets"] = jnp.where(
+                has, (pick(cs_r, hi) - pick(cs_r, lo1)).astype(jnp.float32),
+                nan)
+            out["changes"] = jnp.where(
+                has, (pick(cs_c, hi) - pick(cs_c, lo1)).astype(jnp.float32),
+                nan)
         if p.kind in ("gauge_window",):
-            s = (cs_v[hi] - cs_v[lo]).astype(jnp.float32)
-            s2 = (cs_v2[hi] - cs_v2[lo]).astype(jnp.float32)
+            sum64 = pick(cs_v, hi) - pick(cs_v, lo)
+            sum2_64 = pick(cs_v2, hi) - pick(cs_v2, lo)
+            s = sum64.astype(jnp.float32)
             out["sum"] = jnp.where(has, s, nan)
             out["avg"] = jnp.where(has, s / jnp.maximum(fcnt, 1), nan)
             mean = s.astype(jnp.float64) / jnp.maximum(cnt, 1)
-            var = (cs_v2[hi] - cs_v2[lo]) / jnp.maximum(cnt, 1) - mean * mean
+            var = sum2_64 / jnp.maximum(cnt, 1) - mean * mean
             out["var"] = jnp.where(has, jnp.maximum(var, 0.0).astype(jnp.float32), nan)
-            out["last"] = jnp.where(has, val_s[last_i], nan)
-            out["first"] = jnp.where(has, val_s[first_i], nan)
-            out["first_ts"] = jnp.where(has, ts_s[first_i], 0)
-            out["last_ts"] = jnp.where(has, ts_s[last_i], 0)
+            out["last"] = jnp.where(has, pick(val, last_i), nan)
+            out["first"] = jnp.where(has, pick(val, first_i), nan)
+            out["first_ts"] = jnp.where(has, pick_ts(first_i), 0)
+            out["last_ts"] = jnp.where(has, pick_ts(last_i), 0)
         if p.kind == "regression":
-            sw = (cs_v[hi] - cs_v[lo])
-            st = cs_t[hi] - cs_t[lo]
-            stv = cs_tv[hi] - cs_tv[lo]
-            st2 = cs_t2[hi] - cs_t2[lo]
+            sw = pick(cs_v, hi) - pick(cs_v, lo)
+            st = pick(cs_t, hi) - pick(cs_t, lo)
+            stv = pick(cs_tv, hi) - pick(cs_tv, lo)
+            st2 = pick(cs_t2, hi) - pick(cs_t2, lo)
             cn = cnt.astype(jnp.float64)
             denom = cn * st2 - st * st
             slope = jnp.where(denom != 0, (cn * stv - st * sw) / denom, jnp.nan)
             intercept = jnp.where(cn > 0, (sw - slope * st) / cn, jnp.nan)
             out["slope"] = jnp.where(has2, slope.astype(jnp.float32), nan)
             out["intercept"] = jnp.where(has2, intercept.astype(jnp.float32), nan)
-            out["last_ts"] = jnp.where(has, ts_s[last_i], 0)
+            out["last_ts"] = jnp.where(has, pick_ts(last_i), 0)
         if p.kind == "irate":
-            lastv = val_s[last_i]
-            prev_i = jnp.clip(hi - 2, 0, n - 1)
-            prevv = val_s[prev_i]
-            out["last_ts"] = jnp.where(has2, ts_s[last_i], 0)
-            out["prev_ts"] = jnp.where(has2, ts_s[prev_i], 0)
-            out["last_val"] = jnp.where(has2, lastv, nan)
-            out["prev_val"] = jnp.where(has2, prevv, nan)
+            prev_i = jnp.clip(hi - 2, 0, w - 1)
+            out["last_ts"] = jnp.where(has2, pick_ts(last_i), 0)
+            out["prev_ts"] = jnp.where(has2, pick_ts(prev_i), 0)
+            out["last_val"] = jnp.where(has2, pick(val, last_i), nan)
+            out["prev_val"] = jnp.where(has2, pick(val, prev_i), nan)
         if p.kind == "minmax":
-            # multi-bucket scatter: sample contributes to ceil(r/step)+1
-            # windows; fori_loop keeps compile size O(1) in range/step ratio
-            kmax = int(p.range_ms // p.step_ms + 1)  # gl: allow[GL-H001] -- static WindowParams config, folded at trace time
-            row_of = jnp.full((p.total_series + 1,), -1, dtype=jnp.int32)
-            row_of = row_of.at[jnp.where(sel_ok, sel_tsids, p.total_series)].set(
-                jnp.arange(S, dtype=jnp.int32)
-            )
-            rows = row_of[jnp.clip(tsid_s, 0, p.total_series)]
-            rows = jnp.where(valid_s & (tsid_s >= 0), rows, -1)
-            # first window index receiving this sample: smallest i with
-            # start + i*step >= ts  →  i = ceil((ts-start)/step)
-            i0 = -((start_ms - ts_s) // p.step_ms)  # ceil div
-
-            def body(k, carry):
-                mn, mx = carry
-                i_k = i0 + k
-                in_win = (
-                    (rows >= 0)
-                    & (i_k >= 0)
-                    & (i_k < T)
-                    & ((start_ms + i_k * p.step_ms) - ts_s < p.range_ms)
-                    & ((start_ms + i_k * p.step_ms) >= ts_s)
-                )
-                gid = jnp.where(in_win, rows.astype(jnp.int64) * T + i_k, S * T)
-                mn = mn.at[gid].min(jnp.where(in_win, val_s, jnp.inf))
-                mx = mx.at[gid].max(jnp.where(in_win, val_s, -jnp.inf))
-                return mn, mx
-
-            mn0 = jnp.full((S * T + 1,), jnp.inf, dtype=jnp.float32)
-            mx0 = jnp.full((S * T + 1,), -jnp.inf, dtype=jnp.float32)
-            mn, mx = jax.lax.fori_loop(0, kmax, body, (mn0, mx0))
-            mn = mn[:-1].reshape(S, T)
-            mx = mx[:-1].reshape(S, T)
+            if sweep:
+                # reduce over the slab under the edge mask
+                j = jnp.arange(w, dtype=jnp.int32)[None, None, :]
+                in_win = (j >= lo[:, :, None]) & (j < hi[:, :, None])
+                mn = jnp.min(jnp.where(in_win, val[:, None, :], jnp.inf),
+                             axis=-1)
+                mx = jnp.max(jnp.where(in_win, val[:, None, :], -jnp.inf),
+                             axis=-1)
+            else:
+                mn = _range_extreme(jnp.where(ok, val, jnp.inf), lo, hi, cnt,
+                                    jnp.minimum)
+                mx = _range_extreme(jnp.where(ok, val, -jnp.inf), lo, hi,
+                                    cnt, jnp.maximum)
             out["min"] = jnp.where(jnp.isfinite(mn), mn, nan)
             out["max"] = jnp.where(jnp.isfinite(mx), mx, nan)
         return out
@@ -546,19 +642,17 @@ def _count_max_kernel(p: WindowParams):  # gl: warm-path
     kernels' static padded width (one cheap pass, cached per shape)."""
 
     @named_jit("promql_window_cnt_max")
-    def kernel(key_s, ts_s, val_s, tsid_s, valid_s, ts_min, kp, sel_tsids,
-               start_ms):
-        _lo, _hi, cnt, _has, sel_ok, _n = _sorted_window_bounds(
-            p, key_s, ts_min, kp, sel_tsids, start_ms)
-        return jnp.max(jnp.where(sel_ok[:, None], cnt, 0))
+    def kernel(*layout_sel_start):
+        slab = _slab_geometry(p, *layout_sel_start)
+        return jnp.max(jnp.where(slab.sel_ok[:, None], slab.cnt, 0))
 
     return kernel
 
 
 def _matrix_kernel(p: WindowParams, lmax: int, kind: str):  # gl: warm-path
     """Window-matrix kernels: gather each (series, step) window's samples
-    (time-ordered, padded to the static width ``lmax``) into a
-    [S*T, lmax] matrix, then
+    (time-ordered, padded to the static width ``lmax``) out of the slab
+    into a [S*T, lmax] matrix, then
 
     - ``quantile``: per-row sort + Prometheus linear-interpolation
       quantile (reference src/promql/src/functions/quantile.rs semantics)
@@ -573,15 +667,17 @@ def _matrix_kernel(p: WindowParams, lmax: int, kind: str):  # gl: warm-path
     T, S = p.num_steps, p.num_sel
 
     @named_jit(f"promql_matrix_{kind}")
-    def kernel(key_s, ts_s, val_s, tsid_s, valid_s, ts_min, kp, sel_tsids,
-               start_ms, a1, a2):
-        lo, hi, cnt, has, sel_ok, n = _sorted_window_bounds(
-            p, key_s, ts_min, kp, sel_tsids, start_ms)
-        lof = lo.reshape(-1)  # [W] with W = S*T
-        cntf = cnt.reshape(-1)
+    def kernel(*args):
+        *layout_sel_start, a1, a2 = args
+        slab = _slab_geometry(p, *layout_sel_start)
+        has = slab.has
+        w = slab.val.shape[1]
+        cntf = slab.cnt.reshape(-1)  # [S*T]
         j = jnp.arange(lmax, dtype=jnp.int32)
-        idx = jnp.clip(lof[:, None] + j[None, :], 0, n - 1)
-        rows = val_s[idx]  # [W, L] time-ordered window samples
+        idx = jnp.clip(slab.lo[:, :, None] + j[None, None, :], 0, w - 1)
+        # [S*T, L] time-ordered window samples
+        rows = jnp.take_along_axis(
+            slab.val[:, None, :], idx, axis=2).reshape(S * T, lmax)
         ok = j[None, :] < cntf[:, None]
         nan = jnp.float32(jnp.nan)
         inf = jnp.float32(jnp.inf)
@@ -721,7 +817,7 @@ class SelectorData:
                 sel_tsids = np.intersect1d(sel_tsids, matched,
                                            assume_unique=True)
             sel_tsids = sel_tsids.astype(np.int32)
-            S = max(1, 1 << (max(len(sel_tsids), 1) - 1).bit_length())
+            S = _pow2(len(sel_tsids))
             padded = np.full(S, -1, dtype=np.int32)
             padded[: len(sel_tsids)] = sel_tsids
             sel_dev = jnp.asarray(padded)
@@ -748,11 +844,13 @@ class SelectorData:
 
     def sort_layout(self, fieldcol: str) -> tuple:
         """The resident composite-key sort of this table for ``fieldcol``
-        (see _build_sort_layout): served from PromLayoutCache per
-        (resident-table dicts_version, field column); a miss builds and —
-        if admission under the promql_cache workload quota succeeds —
-        stores it.  A rejected build serves this eval transiently from
-        the same arrays (reject-to-fallback, bit-exact either way)."""
+        (see _build_sort_layout) with its row pointer: (ts_hi, ts_lo,
+        val_s, row_ptr, spacing, max_run), the last two host integers.  Served
+        from PromLayoutCache per (resident-table dicts_version, field
+        column); a miss builds and — if admission under the promql_cache
+        workload quota succeeds — stores it.  A rejected build serves
+        this eval transiently from the same arrays (reject-to-fallback,
+        bit-exact either way)."""
         cache = self.promql_cache()
         rid = getattr(self.region, "region_id", None)
         version = self.table.dicts_version
@@ -763,67 +861,28 @@ class SelectorData:
                 return payload
             self.events["sort_miss"] += 1
         cols = self.table.columns
-        arrays = _build_sort_layout(
+        *arrays, spacing, max_run = _build_sort_layout(
             cols[self.ts_name], cols[fieldcol], cols[TSID],
-            self.table.row_mask)
+            self.table.row_mask, max(self.region.num_series, 1))
+        if cache is not None and cache.mesh is not None:
+            from greptimedb_tpu.parallel.dist import promql_row_shardings
+
+            sh = promql_row_shardings(cache.mesh, int(arrays[0].shape[0]))
+            if sh is not None:
+                # the sorted columns split by rows; the row pointer is
+                # small and read whole by every device
+                arrays[:3] = [jax.device_put(a, sh["rows"])
+                              for a in arrays[:3]]
+        spacing, max_run = jax.device_get((spacing, max_run))
+        layout = (*arrays, int(spacing), int(max_run))
         if cache is not None and rid is not None:
             nbytes = sum(int(a.nbytes) for a in arrays)
             if cache.admit(nbytes):
-                if cache.mesh is not None:
-                    from greptimedb_tpu.parallel.dist import (
-                        promql_row_shardings,
-                    )
-
-                    sh = promql_row_shardings(cache.mesh,
-                                              int(arrays[0].shape[0]))
-                    if sh is not None:
-                        arrays = tuple(
-                            jax.device_put(a, sh["rows"]) if a.ndim else a
-                            for a in arrays
-                        )
-                cache.store("sort", rid, (fieldcol,), version, arrays,
+                cache.store("sort", rid, (fieldcol,), version, layout,
                             nbytes)
             else:
                 self.events["sort_reject"] += 1
-        return arrays
-
-    def window_bounds(self, fieldcol: str, layout: tuple, sel_dev,
-                      matcher_key: tuple):
-        """Resident per-(selection, field) window-geometry state: each
-        selected series' row range in the sorted layout plus its [S, L]
-        timestamp matrix (L = padded max samples/series).  Window
-        boundaries then cost O(T·L) sequential compares per series
-        instead of an O(T·log N) DRAM-latency-bound binary search —
-        ~10× on instant queries at 1M series.  Returns
-        (series_start, cnt_s, ts_mat, L) or None (cache off / reject):
-        callers fall back to the searchsorted geometry, which produces
-        the same integer bounds bit-exactly."""
-        cache = self.promql_cache()
-        rid = getattr(self.region, "region_id", None)
-        if cache is None or rid is None:
-            return None  # resident-only accelerator; transient builds
-            # would cost more than the searchsorted they replace
-        version = self.table.dicts_version
-        ckey = (matcher_key, fieldcol)
-        payload = cache.lookup("bounds", rid, ckey, version)
-        if payload is not None:
-            self.events["bounds_hit"] += 1
-            return payload
-        self.events["bounds_miss"] += 1
-        key_s, ts_s = layout[0], layout[1]
-        kp = layout[6]
-        start, cnt_s = _series_ranges(key_s, kp, sel_dev)
-        lmax = int(jnp.max(cnt_s)) if cnt_s.size else 0
-        L = max(1, 1 << (max(lmax, 1) - 1).bit_length())
-        nbytes = int(start.nbytes) + int(cnt_s.nbytes) + \
-            int(sel_dev.shape[0]) * L * 8
-        if not cache.admit(nbytes):
-            self.events["bounds_reject"] += 1
-            return None
-        ts_mat = _gather_ts_mat(ts_s, start, cnt_s, L)
-        payload = (start, cnt_s, ts_mat, L)
-        cache.store("bounds", rid, ckey, version, payload, nbytes)
-        return payload
+        return layout
 
 
 class PromEvaluator:
@@ -918,12 +977,11 @@ class PromEvaluator:
     }
 
     def _prep_window(self, sel: VectorSelector, kind: str,
-                     range_ms: int | None = None,
-                     allow_bounds: bool = True):
+                     range_ms: int | None = None):
         """Shared selector→kernel-args prep for the stats and matrix
         kernels (ONE definition of pow2 series padding, range/offset/@
-        resolution, and the kernel argument tuple).  Returns
-        (args, p, tsids, labels, pinned, start, rng); raises
+        resolution, the slab width, and the kernel argument tuple).
+        Returns (args, p, tsids, labels, pinned, start, rng); raises
         TableNotFound for unknown metrics (callers map it to an empty
         vector, Prometheus semantics)."""
         d = self.data_for(sel.metric)
@@ -946,18 +1004,7 @@ class PromEvaluator:
             start = self.start_ms - offset_ms
             num_steps = self.num_steps
         with TRACER.stage("sort_layout") as st:
-            layout = d.sort_layout(fieldcol)
-            bounds_l = None
-            extra: tuple = ()
-            # per-series bounds matrix: resident-only accelerator for
-            # few-step windows (the S·T·L compare sweep must stay cheaper
-            # than the S·T·log N binary search it replaces)
-            if allow_bounds and num_steps <= 64:
-                b = d.window_bounds(fieldcol, layout, sel_dev,
-                                    labels.matcher_key)
-                if b is not None and S * num_steps * b[3] <= (1 << 27):
-                    bounds_l = b[3]
-                    extra = b[:3]
+            *layout, spacing, max_run = d.sort_layout(fieldcol)
         self._stage_mark("sort_layout", st)
         p = WindowParams(
             step_ms=self.step_ms,
@@ -966,9 +1013,10 @@ class PromEvaluator:
             num_sel=S,
             total_series=max(d.region.num_series, 1),
             kind=kind,
-            bounds_l=bounds_l,
+            slab_w=slab_width(self.step_ms, num_steps, int(rng), spacing,
+                              max_run),
         )
-        args = layout + extra + (sel_dev, np.int64(start))
+        args = (*layout, sel_dev, np.int64(start))
         return args, p, tsids, labels, pinned, start, int(rng)
 
     def _run_window(
@@ -995,6 +1043,7 @@ class PromEvaluator:
         # happened, so the first call must not be attributed as one
         # (the promql twin of physical.aot_kernel_call's discipline)
         compiling = jit_miss and not getattr(kern, "aot", False)
+        M_WINDOW_ROWS.inc(p.num_sel * p.slab_w)
         out = self._timed_kernel(
             "window_kernel", lambda: kern(*args), jit_miss, compiling,
             kind=kind)
@@ -1017,7 +1066,7 @@ class PromEvaluator:
         import dataclasses
 
         try:
-            prep = self._prep_window(sel, kind, allow_bounds=False)
+            prep = self._prep_window(sel, kind)
         except TableNotFound:
             return jnp.zeros((0, self.num_steps), jnp.float32), []
         args, p, tsids, labels, pinned, _start, _rng = prep
@@ -1029,8 +1078,9 @@ class PromEvaluator:
         if cnt_kern is None:
             cnt_kern = _count_max_kernel(ck)
             _KERNEL_CACHE[ck] = cnt_kern
+        M_WINDOW_ROWS.inc(2 * p.num_sel * p.slab_w)  # sizing pass + matrix
         cnt_max = int(cnt_kern(*args))
-        lmax = max(2, 1 << (max(cnt_max, 1) - 1).bit_length())
+        lmax = max(2, _pow2(cnt_max))
         mk = (p, "matrix", lmax)
         kern = _KERNEL_CACHE.get(mk)
         jit_miss = kern is None

@@ -1000,19 +1000,17 @@ class _ByteLRUCache:
 
 class PromLayoutCache(_ByteLRUCache):
     """Resident derived state for the PromQL evaluation hot path — the
-    PromQL twin of DerivedLayoutCache, holding four kinds of entries:
+    PromQL twin of DerivedLayoutCache, holding three kinds of entries:
 
     - ``selection``: per (region, matcher set) the matched tsid vector and
       its padded device copy, so repeated evaluations skip the inverted-
       index walk AND the O(series) label-dict materialization (labels are
       decoded lazily, only for output groups);
     - ``sort``: per (region, field column) the composite (tsid, ts)-key
-      sort of the resident table — key/ts/val/tsid/valid arrays presorted
-      once on device, reused by every window kernel instead of re-sorting
+      sort of the resident table — ts/val presorted once on device with
+      the per-series row pointer, its densest spacing and longest run,
+      reused by every window kernel instead of re-sorting (or searching)
       the full table inside each eval;
-    - ``bounds``: per (selection, field column) the series row ranges and
-      [S, L] timestamp matrix that turn few-step window boundaries into
-      sequential compares instead of full-array binary searches;
     - ``group``: per (selection, by/without grouping) the device group-id
       vector + segment layout computed from the region's dictionary-
       encoded tag codes, replacing the per-eval Python loop over label
@@ -1028,7 +1026,7 @@ class PromLayoutCache(_ByteLRUCache):
     bit-exact either way.
     """
 
-    KINDS = ("selection", "sort", "group", "bounds")
+    KINDS = ("selection", "sort", "group")
     metric_cache = "promql"
 
     def _kind_of(self, key: tuple) -> str:

@@ -48,6 +48,13 @@ M_GC_PAUSE = REGISTRY.histogram(
     "Pause of one full (generation 2) Python garbage collection",
     labels=("generation",),
 )
+# Says the slab engaged: S_padded x W of every window program dispatched
+# (promql/engine.py ``slab_width``), host arithmetic on static shapes.
+M_WINDOW_ROWS = REGISTRY.counter(
+    "greptime_promql_window_rows_total",
+    "Slab cells (padded matched series x slab width) gathered by "
+    "dispatched PromQL window programs",
+)
 
 _TRACE_ANNOTATION = None
 

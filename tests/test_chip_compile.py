@@ -28,6 +28,11 @@ NB = 18                               # hour buckets the padded grid spans
 ROWS = 18_874_368                     # pad_rows(4000 * 4320)
 HOSTS = 4000
 FIELD_NAMES = tuple(f"f{i}" for i in range(FIELDS))
+# node64.cpu_rate (benchmark/configs/prom-node-64.json): 4,096 counters x
+# 12 h at 15 s in a sort layout of 12.58M padded rows; one mode matches
+# 512 series of 64 instances, 1 h at 60 s over [5m] gathers 512 samples each
+PROM_ROWS, PROM_SERIES, PROM_SEL, PROM_GROUPS, PROM_W = (
+    12_582_912, 4096, 512, 64, 512)
 
 
 @pytest.fixture(scope="module")
@@ -102,19 +107,22 @@ def _grid_args(sh):
 
 
 def _promql_params():
-    from greptimedb_tpu.promql.engine import WindowParams
+    from greptimedb_tpu.promql.engine import WindowParams, slab_width
 
-    # sum by (hostname)(rate(cpu{__field__=..}[5m])), 1 h at 60 s
+    # sum by (instance)(rate(node_cpu_seconds_total{mode=..}[5m])), 1 h at 60 s
+    w = slab_width(60_000, 61, 300_000, 15_000, 2880)
+    assert w == PROM_W
     return WindowParams(step_ms=60_000, num_steps=61, range_ms=300_000,
-                        num_sel=SPAD, total_series=HOSTS, kind="counter")
+                        num_sel=PROM_SEL, total_series=PROM_SERIES,
+                        kind="counter", slab_w=w)
 
 
 def _layout_args(sh):
-    return (_shape((ROWS,), jnp.int64, sh), _shape((ROWS,), jnp.int64, sh),
-            _shape((ROWS,), jnp.float32, sh), _shape((ROWS,), jnp.int32, sh),
-            _shape((ROWS,), jnp.bool_, sh),
-            _shape((), jnp.int64, sh), _shape((), jnp.int64, sh),
-            _shape((SPAD,), jnp.int32, sh), _shape((), jnp.int64, sh))
+    return (_shape((PROM_ROWS,), jnp.int32, sh),
+            _shape((PROM_ROWS,), jnp.uint32, sh),
+            _shape((PROM_ROWS,), jnp.float32, sh),
+            _shape((PROM_SERIES + 1,), jnp.int32, sh),
+            _shape((PROM_SEL,), jnp.int32, sh), _shape((), jnp.int64, sh))
 
 
 def _promql_window():
@@ -126,7 +134,8 @@ def _promql_window():
 def _promql_fused():
     from greptimedb_tpu.compile.fused import _build_fused
 
-    return _build_fused(_promql_params(), "rate", "sum", HOSTS, HOSTS, 300)
+    return _build_fused(_promql_params(), "rate", "sum", PROM_GROUPS,
+                        PROM_SEL, 300)
 
 
 def _segment(form, op, rows, sh):
@@ -144,7 +153,7 @@ CASES = {
     "promql-window": lambda sh: (_promql_window(), _layout_args(sh)),
     "promql-fused": lambda sh: (
         _promql_fused(),
-        _layout_args(sh) + (_shape((HOSTS,), jnp.int32, sh),)),
+        _layout_args(sh) + (_shape((PROM_SEL,), jnp.int32, sh),)),
     # the form `auto` takes on every backend, at table size
     "segment-scatter-mean": lambda sh: _segment("scatter", "mean", ROWS, sh),
     # the form only `force` reaches: its scan is minutes slow past this

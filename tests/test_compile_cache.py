@@ -15,6 +15,7 @@ Covers the PR's acceptance surface:
   windows coalesce into one dispatch, bit-exact vs solo.
 """
 
+import dataclasses
 import glob
 import json
 import os
@@ -99,12 +100,14 @@ class TestShape:
         from greptimedb_tpu.promql.engine import WindowParams
 
         p = WindowParams(step_ms=60000, num_steps=11, range_ms=300000,
-                         num_sel=4, total_series=4, kind="counter")
+                         num_sel=4, total_series=4, kind="counter",
+                         slab_w=64)
         c = canon_key('promql', (p, "rate", "sum"))
-        assert c is not None and "counter" in c
-        p2 = WindowParams(step_ms=60000, num_steps=11, range_ms=300000,
-                          num_sel=4, total_series=4, kind="gauge_window")
+        assert c is not None and "counter" in c and "slab_w=i64" in c
+        p2 = dataclasses.replace(p, kind="gauge_window")
         assert canon_key('promql', (p2, "rate", "sum")) != c
+        p3 = dataclasses.replace(p, slab_w=128)
+        assert canon_key('promql', (p3, "rate", "sum")) != c
 
 
 # ---------------------------------------------------------------------------

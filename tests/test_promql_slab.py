@@ -1,0 +1,368 @@
+"""The PromQL window program on the matched series' slab.
+
+Every window kind is served through ``PromEvaluator`` and compared with
+a plain numpy reference that loops over series and windows (Prometheus
+semantics, written here from the definitions and sharing nothing with
+the engine), over data that stresses the slab's geometry: a regular
+scrape (a narrow slab inside long series), irregular timestamps (the
+slab falls to its cap, the whole series row), series shorter than the
+slab and empty ones, counters that reset on both sides of the slab's
+first column, windows wholly before the first and after the last
+sample, ``offset`` and ``@``.  Stored DOUBLEs compute in f32 on the
+device, so the reference rounds its inputs to f32 and the comparison is
+by a tolerance suited to f32.
+"""
+
+import math
+
+import numpy as np
+import pytest
+
+from greptimedb_tpu.promql import engine as pe
+from greptimedb_tpu.promql.parser import parse_promql
+from greptimedb_tpu.standalone import GreptimeDB
+from greptimedb_tpu.utils.telemetry import REGISTRY
+
+RANGE_S = 300
+LOOKBACK_MS = 300_000
+T0_S = 10_000  # first scrape of every data set, seconds
+
+
+@pytest.fixture
+def db():
+    d = GreptimeDB()
+    yield d
+    d.close()
+
+
+# ---------------------------------------------------------------------------
+# data
+# ---------------------------------------------------------------------------
+
+def _counter(rng, n, reset_every):
+    """A counter in f32-exact steps that resets every ``reset_every``
+    samples, so resets land on both sides of any slab's first column."""
+    inc = rng.integers(1, 40, n).astype(np.float64)
+    out = np.empty(n)
+    acc = 0.0
+    for i in range(n):
+        acc = inc[i] if reset_every and i and i % reset_every == 0 \
+            else acc + inc[i]
+        out[i] = acc
+    return out
+
+
+def _series(kind: str):
+    """{name: (ts_ms int64, values float64)} of one data set."""
+    rng = np.random.default_rng(31)
+    t0 = T0_S * 1000
+    if kind == "regular":
+        # 15 s scrape, phases staggered, 240 samples = 1 h
+        return {
+            f"s{i}": (t0 + 1000 * i + 15_000 * np.arange(240),
+                      _counter(rng, 240, (0, 7, 11, 0, 5, 13)[i]))
+            for i in range(6)}
+    if kind == "irregular":
+        # gaps from 1 ms to 40 s: the densest spacing is 1 ms
+        out = {}
+        for i in range(5):
+            gaps = rng.integers(2_000, 40_000, 150)
+            gaps[17 * (i + 1)] = 1
+            out[f"s{i}"] = (t0 + np.cumsum(gaps),
+                            _counter(rng, 150, (0, 9, 4, 0, 6)[i]))
+        return out
+    if kind == "lengths":
+        # longer than the slab, much shorter than it, and empty
+        out = {}
+        for i, n in enumerate((240, 3, 50, 1, 200)):
+            out[f"s{i}"] = (t0 + 15_000 * np.arange(n),
+                            _counter(rng, n, (8, 0, 5, 0, 0)[i]))
+        out["empty"] = (t0 + 15_000 * np.arange(4), np.full(4, np.nan))
+        return out
+    raise AssertionError(kind)
+
+
+def load(db, data, table="m"):
+    db.sql(f"CREATE TABLE {table} (name STRING, ts TIMESTAMP(3) TIME INDEX, "
+           f"val DOUBLE, PRIMARY KEY (name))")
+    r = db._region_of(table)
+    for name, (ts, vals) in data.items():
+        r.write({"name": [name] * len(ts), "ts": np.asarray(ts, np.int64),
+                 "val": np.asarray(vals, np.float64)})
+
+
+# scenario -> (data set, grid (start_s, end_s, step_s), selector suffix,
+#              offset_ms, pinned @ seconds or None)
+SCENARIOS = {
+    "regular": ("regular", (T0_S + 900, T0_S + 1500, 60), "", 0, None),
+    "irregular": ("irregular", (T0_S + 600, T0_S + 1500, 75), "", 0, None),
+    "lengths": ("lengths", (T0_S + 30, T0_S + 930, 60), "", 0, None),
+    # the grid starts two ranges before the first sample and ends two
+    # ranges after the last: windows wholly outside the data on both sides
+    "outside": ("regular", (T0_S - 700, T0_S + 3600 + 700, 100), "", 0,
+                None),
+    "offset": ("regular", (T0_S + 900, T0_S + 1500, 60), " offset 90s",
+               90_000, None),
+    "at": ("regular", (T0_S + 900, T0_S + 1140, 60), f" @ {T0_S + 2000}",
+           0, T0_S + 2000),
+}
+
+# kind -> [(function, takes a [range])]
+KIND_FUNCS = {
+    "instant": [("", False)],
+    "counter": [("rate", True), ("increase", True), ("delta", True)],
+    "counter_rc": [("resets", True), ("changes", True)],
+    "gauge_window": [("sum_over_time", True), ("avg_over_time", True),
+                     ("count_over_time", True), ("stddev_over_time", True),
+                     ("last_over_time", True), ("first_over_time", True)],
+    "regression": [("deriv", True)],
+    "irate": [("irate", True), ("idelta", True)],
+    "minmax": [("min_over_time", True), ("max_over_time", True)],
+}
+
+
+# ---------------------------------------------------------------------------
+# the plain reference: one series, one window at a time
+# ---------------------------------------------------------------------------
+
+def _window(ts, vals, t_ms, range_ms):
+    """Samples in (t − range, t], NULLs (NaN) left out."""
+    keep = (ts > t_ms - range_ms) & (ts <= t_ms) & ~np.isnan(vals)
+    return ts[keep], vals[keep].astype(np.float32).astype(np.float64)
+
+
+def _extrapolated(wt, wv, t_ms, range_ms, counter, is_rate):
+    if len(wt) < 2:
+        return math.nan
+    delta = wv[-1] - wv[0]
+    if counter:
+        delta += sum(wv[i - 1] for i in range(1, len(wv))
+                     if wv[i] < wv[i - 1])
+    sampled = (wt[-1] - wt[0]) / 1000.0
+    avg = sampled / (len(wt) - 1)
+    to_start = (wt[0] - (t_ms - range_ms)) / 1000.0
+    to_end = (t_ms - wt[-1]) / 1000.0
+    if to_start >= avg * 1.1:
+        to_start = avg / 2
+    if to_end >= avg * 1.1:
+        to_end = avg / 2
+    if counter and delta > 0:
+        to_start = min(to_start, sampled * (wv[0] / delta))
+    out = delta * (sampled + to_start + to_end) / sampled
+    return out / (range_ms / 1000.0) if is_rate else out
+
+
+def ref_point(func, ts, vals, t_ms, range_ms, origin_ms):
+    """One output point of ``func`` for one series at evaluation time
+    ``t_ms``; ``origin_ms`` is the grid's start (deriv's time origin)."""
+    if func == "":
+        wt, wv = _window(ts, vals, t_ms, LOOKBACK_MS)
+        return wv[-1] if len(wv) else math.nan
+    wt, wv = _window(ts, vals, t_ms, range_ms)
+    n = len(wv)
+    if func in ("rate", "increase", "delta"):
+        return _extrapolated(wt, wv, t_ms, range_ms, func != "delta",
+                             func == "rate")
+    if func in ("resets", "changes"):
+        if n == 0:
+            return math.nan
+        pairs = zip(wv[:-1], wv[1:])
+        return float(sum((a > b) if func == "resets" else (a != b)
+                         for a, b in pairs))
+    if func in ("irate", "idelta"):
+        if n < 2 or wt[-1] == wt[-2]:
+            return math.nan
+        dv = wv[-1] - wv[-2]
+        if func == "idelta":
+            return dv
+        if dv < 0:
+            dv = wv[-1]
+        return dv / ((wt[-1] - wt[-2]) / 1000.0)
+    if func == "deriv":
+        if n < 2:
+            return math.nan
+        x = (wt - origin_ms) / 1000.0
+        den = n * (x * x).sum() - x.sum() ** 2
+        if den == 0:
+            return math.nan
+        return (n * (x * wv).sum() - x.sum() * wv.sum()) / den
+    if n == 0:
+        return math.nan
+    return {
+        "sum_over_time": lambda: wv.sum(),
+        "avg_over_time": lambda: wv.mean(),
+        "count_over_time": lambda: float(n),
+        "stddev_over_time": lambda: math.sqrt(
+            max((wv * wv).mean() - wv.mean() ** 2, 0.0)),
+        "last_over_time": lambda: wv[-1],
+        "first_over_time": lambda: wv[0],
+        "min_over_time": lambda: wv.min(),
+        "max_over_time": lambda: wv.max(),
+    }[func]()
+
+
+def reference(func, data, grid, offset_ms, at_s):
+    start_s, end_s, step_s = grid
+    steps = np.arange(start_s * 1000, end_s * 1000 + 1, step_s * 1000)
+    out = {}
+    for name, (ts, vals) in data.items():
+        ts = np.asarray(ts, np.int64)
+        vals = np.asarray(vals, np.float64)
+        if at_s is not None:  # one evaluation, shown at every step
+            t_eval = at_s * 1000 - offset_ms
+            v = ref_point(func, ts, vals, t_eval, RANGE_S * 1000, t_eval)
+            out[name] = np.full(len(steps), v)
+        else:
+            out[name] = np.array([
+                ref_point(func, ts, vals, int(t) - offset_ms,
+                          RANGE_S * 1000, int(steps[0]) - offset_ms)
+                for t in steps])
+    return out
+
+
+def served(db, query, grid):
+    ev = pe.PromEvaluator(db, *grid)
+    res = ev.eval(parse_promql(query))
+    vals = np.asarray(res.values, np.float64)
+    return {lab["name"]: vals[i] for i, lab in enumerate(res.labels)}, ev
+
+
+def _query(func, ranged, suffix):
+    sel = f"m[{RANGE_S}s]{suffix}" if ranged else f"m{suffix}"
+    return f"{func}({sel})" if func else sel
+
+
+# ---------------------------------------------------------------------------
+# tests
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kind", sorted(pe.PromEvaluator._KIND_KEYS))
+@pytest.mark.parametrize("scenario", sorted(SCENARIOS))
+def test_kind_against_plain_reference(db, scenario, kind):
+    dataset, grid, suffix, offset_ms, at_s = SCENARIOS[scenario]
+    data = _series(dataset)
+    load(db, data)
+    for func, ranged in KIND_FUNCS[kind]:
+        got, ev = served(db, _query(func, ranged, suffix), grid)
+        want = reference(func, data, grid, offset_ms, at_s)
+        assert sorted(got) == sorted(want)
+        for name in want:
+            g, w = got[name], want[name]
+            assert np.array_equal(np.isnan(g), np.isnan(w)), (
+                func, name, g, w)
+            # f32 values, f64 prefixes: stddev and deriv difference sums
+            # of squares, so they get the scale of the values squared
+            scale = np.nanmax(np.abs(w), initial=1.0)
+            tol = 2e-5 * max(scale, 1.0)
+            if func == "stddev_over_time":
+                vmax = np.nanmax(np.abs(data[name][1]), initial=1.0)
+                tol = 1e-3 * max(vmax, 1.0) ** 0.5 + 2e-5 * scale
+            np.testing.assert_allclose(g, w, rtol=2e-5, atol=tol,
+                                       err_msg=f"{func} {name}")
+    # the slab's width follows the data: narrow on a regular scrape,
+    # the whole (padded) series row where the spacing says nothing
+    sel = parse_promql(_query("rate", True, suffix)).args[0]
+    _args, p, *_rest = ev._prep_window(sel, "counter")
+    longest = max(int((~np.isnan(v)).sum()) for _t, v in data.values())
+    cap = pe._pow2(longest)
+    if dataset == "irregular":
+        assert p.slab_w == cap
+    else:
+        assert p.slab_w == pe.slab_width(
+            ev.step_ms, p.num_steps, RANGE_S * 1000, 15_000, longest)
+        # only a grid longer than the data itself reaches the cap
+        assert (p.slab_w < cap) == (scenario != "outside")
+
+
+@pytest.mark.parametrize("kind", sorted(pe.PromEvaluator._KIND_KEYS))
+def test_edge_forms_agree_bit_for_bit(db, monkeypatch, kind):
+    """Window edges by the [S, T, W] compare sweep and by the log-W search
+    are the same integers, and picks by a compare-select pass and by a
+    gather (min/max: the masked sweep and the sparse table) the same
+    values, so every output is the same bits."""
+    data = _series("lengths")
+    load(db, data)
+    grid = (T0_S - 400, T0_S + 1100, 50)
+    func, ranged = KIND_FUNCS[kind][0]
+
+    def run():
+        pe._KERNEL_CACHE.clear()
+        ev = pe.PromEvaluator(db, *grid)
+        return np.asarray(ev.eval(parse_promql(_query(func, ranged, "")))
+                          .values)
+
+    swept = run()
+    monkeypatch.setattr(pe, "_SWEEP_WIDTH", 0)
+    searched = run()
+    pe._KERNEL_CACHE.clear()
+    assert np.array_equal(swept, searched, equal_nan=True)
+    assert not np.isnan(swept).all()
+
+
+def _node_fleet(db):
+    """Two modes x four CPUs at a 15 s scrape, 2 h: the cell's shape in
+    small (benchmark/configs/prom-node-64.json)."""
+    db.sql("CREATE TABLE cpu (mode STRING, cpu STRING, "
+           "ts TIMESTAMP(3) TIME INDEX, val DOUBLE, PRIMARY KEY (mode, cpu))")
+    r = db._region_of("cpu")
+    rng = np.random.default_rng(5)
+    n = 600
+    ts = T0_S * 1000 + 15_000 * np.arange(n)
+    for mode in ("user", "idle"):
+        for c in range(4):
+            r.write({"mode": [mode] * n, "cpu": [str(c)] * n, "ts": ts,
+                     "val": np.cumsum(rng.uniform(0, 15, n))})
+
+
+def test_one_program_for_every_hour_and_mode(db):
+    """start_ms and the matched series are data: two evaluations at
+    different times over different matchers of equal padded size run one
+    compiled program, and W depends on neither."""
+    _node_fleet(db)
+    q = 'sum by (cpu)(rate(cpu{mode="%s"}[5m]))'
+    builds = "greptime_compile_xla_builds_total"
+    widths = set()
+
+    def run(mode, start_s):
+        ev = pe.PromEvaluator(db, start_s, start_s + 3600, 60)
+        expr = parse_promql(q % mode)
+        out = np.asarray(ev.eval(expr).values)
+        sel = expr.expr.args[0]
+        widths.add(ev._prep_window(sel, "counter")[1].slab_w)
+        return out
+
+    first = run("user", T0_S + 4000)
+    n_kernels = len(pe._KERNEL_CACHE)
+    n_builds = REGISTRY.value(builds, ("promql",))
+    second = run("idle", T0_S + 4000 + 977)
+    run("user", T0_S + 5111)
+    assert len(pe._KERNEL_CACHE) == n_kernels
+    assert REGISTRY.value(builds, ("promql",)) == n_builds
+    # 1 h at 60 s over [5m] on a 15 s scrape: 3,900 s / 15 s + 2 -> 512
+    assert widths == {512}
+    assert first.shape == second.shape == (4, 61)
+    assert np.isfinite(first).all() and np.isfinite(second).all()
+
+
+def test_window_rows_counter_advances_by_slab_cells(db):
+    _node_fleet(db)
+    name = "greptime_promql_window_rows_total"
+
+    def cells(query, start_s=T0_S + 4000):
+        before = REGISTRY.value(name, ())
+        ev = pe.PromEvaluator(db, start_s, start_s + 3600, 60)
+        ev.eval(parse_promql(query))
+        return REGISTRY.value(name, ()) - before
+
+    # four matched series (padded to 4) x W = 512, a dispatch
+    assert cells('sum by (cpu)(rate(cpu{mode="user"}[5m]))') == 4 * 512
+    # every series, unfused
+    assert cells('rate(cpu[5m])') == 8 * 512
+    # a matrix kernel dispatches its sizing pass and itself
+    assert cells('quantile_over_time(0.5, cpu{mode="idle"}[5m])') \
+        == 2 * 4 * 512
+    # an instant vector at one step gathers the lookback only: 300 s / 15 s
+    ev = pe.PromEvaluator(db, T0_S + 4000, T0_S + 4000, 60)
+    before = REGISTRY.value(name, ())
+    ev.eval(parse_promql("cpu"))
+    assert REGISTRY.value(name, ()) - before == 8 * 32

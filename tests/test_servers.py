@@ -168,7 +168,12 @@ class TestHttpApi:
         code, raw = http(server, "/v1/sql")
         assert code == 400
 
-    def test_influx_write_and_query(self, server):
+    # Tests of this class read what an earlier one wrote to the module's
+    # server; under xdist the earlier one may have run on another worker,
+    # so each reader writes its rows itself (the same rows again change
+    # nothing).
+    @staticmethod
+    def _write_weather(server):
         lp = (
             "weather,city=sf temp=13.5 1700000000000\n"
             "weather,city=nyc temp=2.0 1700000000000\n"
@@ -176,6 +181,22 @@ class TestHttpApi:
         code, _ = http(server, "/v1/influxdb/api/v2/write?precision=ms",
                        method="POST", body=lp.encode())
         assert code == 204
+
+    @staticmethod
+    def _write_http_total(server):
+        ts0 = 1700000000000
+        pb = make_write_request([
+            ({"__name__": "http_total", "job": "api"},
+             [(float(5 * i), ts0 + i * 10_000) for i in range(60)]),
+        ])
+        code, _ = http(server, "/v1/prometheus/write", method="POST",
+                       body=snappy.compress(pb),
+                       headers={"Content-Encoding": "snappy"})
+        assert code == 204
+        return ts0
+
+    def test_influx_write_and_query(self, server):
+        self._write_weather(server)
         code, raw = http(server, "/v1/sql?" + urllib.parse.urlencode(
             {"sql": "SELECT city, temp FROM weather ORDER BY city"}))
         rows = json.loads(raw)["output"][0]["records"]["rows"]
@@ -211,6 +232,7 @@ class TestHttpApi:
         assert code == 400
 
     def test_influx_schema_extension(self, server):
+        self._write_weather(server)
         http(server, "/v1/influxdb/api/v2/write?precision=ms",
              method="POST", body=b"weather,city=sf humidity=80.0 1700000001000")
         code, raw = http(server, "/v1/sql?" + urllib.parse.urlencode(
@@ -219,15 +241,7 @@ class TestHttpApi:
         assert rows == [[None], [80.0]]
 
     def test_remote_write_and_prom_query(self, server):
-        ts0 = 1700000000000
-        pb = make_write_request([
-            ({"__name__": "http_total", "job": "api"},
-             [(float(5 * i), ts0 + i * 10_000) for i in range(60)]),
-        ])
-        code, _ = http(server, "/v1/prometheus/write", method="POST",
-                       body=snappy.compress(pb),
-                       headers={"Content-Encoding": "snappy"})
-        assert code == 204
+        ts0 = self._write_http_total(server)
         q = urllib.parse.urlencode({
             "query": "rate(http_total[5m])",
             "start": str(ts0 / 1000 + 300), "end": str(ts0 / 1000 + 500),
@@ -244,6 +258,7 @@ class TestHttpApi:
             assert float(v) == pytest.approx(0.5, rel=1e-5)
 
     def test_prom_instant_query(self, server):
+        self._write_http_total(server)
         q = urllib.parse.urlencode({
             "query": "http_total", "time": str(1700000000000 / 1000 + 590),
         })
@@ -253,6 +268,7 @@ class TestHttpApi:
         assert len(body["data"]["result"]) == 1
 
     def test_prom_metadata(self, server):
+        self._write_http_total(server)
         code, raw = http(server, "/v1/prometheus/api/v1/labels")
         data = json.loads(raw)["data"]
         assert "__name__" in data and "job" in data
@@ -266,6 +282,7 @@ class TestHttpApi:
         assert {"__name__": "http_total", "job": "api"} in data
 
     def test_promql_native_endpoint(self, server):
+        self._write_http_total(server)
         q = urllib.parse.urlencode({
             "query": "http_total", "start": str(1700000000000 / 1000 + 100),
             "end": str(1700000000000 / 1000 + 100), "step": "60",
@@ -1000,7 +1017,11 @@ def _decode_read_response(raw: bytes) -> list[list[tuple[dict, list]]]:
 
 
 class TestPromRemoteRead:
-    def test_write_then_remote_read(self, server):
+    @staticmethod
+    def _write_rr_metric(server):
+        """Both tests read these samples; each writes them itself (the
+        same samples again change nothing), so neither needs the other to
+        have run on its worker."""
         ts0 = 1700001000000
         pb = make_write_request([
             ({"__name__": "rr_metric", "job": "api", "inst": "a"},
@@ -1012,6 +1033,10 @@ class TestPromRemoteRead:
                        body=snappy.compress(pb),
                        headers={"Content-Encoding": "snappy"})
         assert code == 204
+        return ts0
+
+    def test_write_then_remote_read(self, server):
+        ts0 = self._write_rr_metric(server)
         # ReadRequest{queries=1:{start=1,end=2,matchers=3:{type=1,name=2,value=3}}}
         def matcher(mtype, name, value):
             m = b""
@@ -1046,7 +1071,7 @@ class TestPromRemoteRead:
             m += _pb_len(2, name.encode()) + _pb_len(3, value.encode())
             return _pb_len(3, m)
 
-        ts0 = 1700001000000
+        ts0 = self._write_rr_metric(server)
         q = (_pb_varint(1 << 3) + _pb_varint(0)
              + _pb_varint(2 << 3) + _pb_varint((ts0 + 60_000))
              + matcher(0, "__name__", "rr_metric")
