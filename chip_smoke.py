@@ -6,8 +6,8 @@ cpu-only through the standalone server, over HTTP, in ONE process.
     python chip_smoke.py --mesh           # only the 4-device mesh path
     python chip_smoke.py --rehearse --scale 16 --hours 1   # CPU rehearsal
 
-Deployment (source: BASELINE.md / upstream docs/benchmarks/tsbs/v0.12.0.md,
-shaped as bench.py shapes it): ``--scale`` hosts (TSBS's name for the
+Deployment (source: BASELINE.md / upstream docs/benchmarks/tsbs/v0.12.0.md;
+the benchmark of record is benchmark/run.py): ``--scale`` hosts (TSBS's name for the
 host count, default 4000), one ``hostname`` tag, 10 ``usage_*`` DOUBLE
 fields, one row per host every 10 s for ``--hours`` hours (default 12 —
 the window double-groupby-all reads: 17.28M rows), values from

@@ -111,19 +111,12 @@ KNOB_DOCS: dict[str, str] = {
     "GREPTIME_FULLTEXT_WORDS": (
         "uint32 words per fingerprint row (32 bloom bits each): more "
         "words = fewer prefilter false positives, more HBM."),
-    "GREPTIME_GRID": (
-        "`off` disables the dense resident time-grid path; queries fall "
-        "back to row-major device tables."),
     "GREPTIME_GRID_BUDGET_BYTES": (
         "HBM budget for resident dense grids; regions past it stay on "
         "the row path."),
     "GREPTIME_GRID_MIN_DENSITY": (
         "Minimum (rows / series x buckets) fill ratio for a region to "
         "qualify for the dense grid."),
-    "GREPTIME_INGEST_VECTOR": (
-        "`off` restores the legacy row-at-a-time wire decoders "
-        "(byte-for-byte) instead of the vectorized CSV/arrow parse "
-        "pipeline."),
     "GREPTIME_INGEST_WORKERS": (
         "Width of the parallel per-region ingest append pool."),
     "GREPTIME_JOIN_MAX_ROWS": (
@@ -131,9 +124,6 @@ KNOB_DOCS: dict[str, str] = {
         "exhausting memory."),
     "GREPTIME_JOIN_WARN_ROWS": (
         "Join output size above which a slow-join warning is logged."),
-    "GREPTIME_LAYOUT_CACHE": (
-        "`off` disables the bucket-major derived layout cache (aligned "
-        "range-window aggregation falls back to dynamic-slice)."),
     "GREPTIME_LAYOUT_CACHE_BYTES": (
         "Capacity of the bucket-major derived layout cache."),
     "GREPTIME_LAYOUT_CACHE_QUOTA_BYTES": (
@@ -148,16 +138,9 @@ KNOB_DOCS: dict[str, str] = {
     "GREPTIME_MESH_MIN_ROWS": (
         "Minimum region rows before mesh-sharded dispatch is worth the "
         "collective overhead."),
-    "GREPTIME_PLAN_FUSION": (
-        "`off` restores the multi-kernel PromQL chain (window kernel + "
-        "eager epilogue + eager group reduce) byte-for-byte instead of "
-        "the whole-plan fused single-dispatch programs."),
     "GREPTIME_PREFETCH_THREADS": (
         "S3 scan-readahead fetcher thread count (the read path joins "
         "in-flight prefetches)."),
-    "GREPTIME_PROMQL_CACHE": (
-        "`off` disables the resident PromQL evaluation cache (matcher "
-        "selections, sort layouts, group-id vectors)."),
     "GREPTIME_PROMQL_CACHE_BYTES": (
         "Capacity of the resident PromQL evaluation cache."),
     "GREPTIME_PROMQL_CACHE_QUOTA_BYTES": (
@@ -173,24 +156,12 @@ KNOB_DOCS: dict[str, str] = {
         "writes on shared object storage (conditional puts under the "
         "Metasrv-minted epoch; standalone regions never arm a fence "
         "either way)."),
-    "GREPTIME_SCAN_FORCE_LEXSORT": (
-        "`1` forces the legacy global lexsort instead of the sorted-run "
-        "merge (A/B bit-exactness harness)."),
     "GREPTIME_SCAN_QUOTA_BYTES": (
         "Memory-manager quota for the `scan` staging workload "
         "(reject-to-sequential fallback)."),
-    "GREPTIME_SCAN_TAG_CODES": (
-        "`off` disables dictionary-code tag transfer on cold scans "
-        "(per-row object arrays come back, for A/B)."),
     "GREPTIME_SCAN_THREADS": (
         "Cold-scan parallel SST decode pool width (default "
         "min(8, files, cores))."),
-    "GREPTIME_SCHEDULER": (
-        "`off` restores the inline per-protocol execution path "
-        "byte-for-byte (serving/ package never imported)."),
-    "GREPTIME_SCHEDULER_BATCH": (
-        "`off` disables cross-query stacked dispatch while keeping "
-        "admission/priorities."),
     "GREPTIME_SCHEDULER_LINGER_MS": (
         "Group-commit linger ceiling for coalescible query arrivals "
         "(adaptive: scaled by same-class pressure, 0 when idle)."),
@@ -221,18 +192,13 @@ KNOB_DOCS: dict[str, str] = {
         "exported into own tables); module never imported when unset."),
     "GREPTIME_SELF_MONITOR_INTERVAL_S": (
         "Flush interval of the self-monitoring export loop."),
-    "GREPTIME_SLO": (
-        "`off` disables the SLO observatory AND the budgeted idle "
-        "economy (serving/slo.py + serving/idle.py never imported; the "
-        "legacy chained idle hook and static deadlines serve "
-        "byte-for-byte); default on."),
     "GREPTIME_SLO_ALPHA": (
         "Relative-error bound of the DDSketch-style latency sketches "
         "(smaller = more buckets = tighter quantiles)."),
     "GREPTIME_SLO_SLOT_S": (
         "Burn-rate ring-buffer slot width in seconds; the 5m/30m/1h/6h "
         "windows are fixed slot COUNTS, so shrinking this compresses "
-        "every window proportionally (bench_soak uses that)."),
+        "every window proportionally."),
     "GREPTIME_SLO_THRESHOLD_MS": (
         "Default per-request latency objective for the interactive "
         "class; normal/background scale it by 4x/20x."),

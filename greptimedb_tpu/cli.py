@@ -341,16 +341,6 @@ def cmd_import(args) -> int:
     return 0
 
 
-def cmd_bench(args) -> int:
-    import os
-    import subprocess
-
-    bench = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "bench.py"
-    )
-    return subprocess.call([sys.executable, bench])
-
-
 def main(argv: list[str] | None = None) -> int:
     p = argparse.ArgumentParser(prog="greptime-tpu",
                                 description="TPU-native observability database")
@@ -434,9 +424,6 @@ def main(argv: list[str] | None = None) -> int:
     pi.add_argument("--data-home", required=True)
     pi.add_argument("--input-dir", required=True)
     pi.set_defaults(fn=cmd_import)
-
-    pb = sub.add_parser("bench", help="run the TSBS benchmark")
-    pb.set_defaults(fn=cmd_bench)
 
     args = p.parse_args(argv)
     return args.fn(args)

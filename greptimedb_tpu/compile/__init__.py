@@ -15,8 +15,8 @@ The three legs of ROADMAP open item 5 (the compile-latency attack):
   plus a tail of eager dispatches with host glue, now lowers to a single
   program (Data Path Fusion, arXiv 2605.10511: eliminating intermediate
   materialization between query stages is the next multiplier after
-  caching).  ``GREPTIME_PLAN_FUSION=off`` restores the multi-kernel path
-  byte-for-byte.
+  caching).  A chain outside the fused surface takes the multi-kernel
+  path (``try_fused_aggregation`` returns None).
 
 - **Persistent compilation cache** (``store.py`` + ``service.py``): AOT
   artifacts — ``jax.jit(...).lower(...).compile()`` executables
@@ -38,18 +38,7 @@ The three legs of ROADMAP open item 5 (the compile-latency attack):
 
 from __future__ import annotations
 
-import os
-
-__all__ = ["fusion_enabled", "named_jit", "PlanCompiler"]
-
-
-def fusion_enabled() -> bool:
-    """GREPTIME_PLAN_FUSION gate for the fused PromQL chain.  ``off``
-    restores the multi-kernel (window kernel + eager epilogue + eager
-    group reduce) path byte-for-byte — the A/B twin every fusion parity
-    test compares against."""
-    return os.environ.get("GREPTIME_PLAN_FUSION", "on").lower() not in (
-        "off", "0", "false")
+__all__ = ["named_jit", "PlanCompiler"]
 
 
 def named_jit(name: str, **jit_kwargs):

@@ -20,8 +20,7 @@ therefore identical to the unfused path (pinned by the fusion parity
 fuzz in tests/test_compile_cache.py).  Anything outside the fused
 surface — pinned ``@`` selectors, subqueries, quantile/topk, label-
 transformed inputs — returns None and the evaluator falls back to the
-multi-kernel path, which ``GREPTIME_PLAN_FUSION=off`` also restores
-wholesale.
+multi-kernel path.
 """
 
 from __future__ import annotations

@@ -1,7 +1,7 @@
 """Where JAX's own persistent compilation cache lives.
 
 One rule for every entry point that starts a serving or measuring
-process (cli standalone/datanode, chip_smoke.py, the bench scripts):
+process (cli standalone/datanode, chip_smoke.py, benchmark/run.py):
 ``JAX_COMPILATION_CACHE_DIR`` wins when the environment sets it — JAX
 reads it itself and nothing is set in code — otherwise the cache is
 ``<checkout>/.jax_cache``.  The directory is part of the cache key, so

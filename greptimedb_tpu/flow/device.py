@@ -1083,11 +1083,11 @@ class FlowDeviceRuntime:
             jnp.asarray(ts_p), tuple(jnp.asarray(v) for v in vals),
             tuple(jnp.asarray(m) for m in vvalids),
             jnp.asarray(aff_g), jnp.asarray(aff_w))
-        # with the SLO observatory on, folds SYNC so greptime_flow_tick
+        # under an SLO observatory (a standalone db; a Flownode's
+        # frontend handle has none) folds SYNC so greptime_flow_tick
         # and the idle economy's elapsed debit cover the real device
         # time (an async dispatch returns before the fold runs, and the
-        # economy would grant interactive-contending work for free);
-        # GREPTIME_SLO=off keeps the fully-async hot path byte-for-byte
+        # economy would grant interactive-contending work for free)
         sink = {} if getattr(self.db, "slo", None) is not None else None
         new_state, outs = timed_kernel_call(call, miss, sink)
         st.slots = list(new_state)

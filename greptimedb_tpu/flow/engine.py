@@ -790,8 +790,10 @@ class FlowEngine:
         re-arms right after (arm sees the cleared flag) — never neither."""
         if self.checkpoints is None or self._ckpt_interval_s <= 0:
             return
+        # a Flownode's db is a frontend handle (rpc DistFrontend), which
+        # has no scheduler: its flows checkpoint on the interval alone
         sched = getattr(self.db, "scheduler", None)
-        if sched is None or not hasattr(sched, "add_idle_hook"):
+        if sched is None:
             return
         with self._fold_lock:
             if self._idle_armed:

@@ -21,7 +21,7 @@ end to end:
 
 ``GREPTIME_FULLTEXT=off`` keeps the same composition but rebuilds the
 per-distinct-line truth with the host predicate loop on every
-evaluation — the A/B twin bench_logs.py measures; results are bit-exact
+evaluation; results are bit-exact
 either way (pinned by tests/test_fulltext.py)."""
 
 from __future__ import annotations

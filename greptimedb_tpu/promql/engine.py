@@ -23,7 +23,6 @@ from __future__ import annotations
 import collections
 import collections.abc
 import math
-import os
 import re
 import typing
 from dataclasses import dataclass, field
@@ -752,12 +751,10 @@ class SelectorData:
         self.events = events if events is not None else collections.Counter()
 
     def promql_cache(self):
-        """The db's resident PromLayoutCache, or None when caching is off
-        (GREPTIME_PROMQL_CACHE=off A/B knob) or the db has none.  Both
-        states serve evals from the identical transient-build code path,
-        so cached and uncached results are bit-exact by construction."""
-        if os.environ.get("GREPTIME_PROMQL_CACHE", "on") == "off":
-            return None
+        """The db's resident PromLayoutCache, or None when the db has
+        none.  Both states serve evals from the identical transient-build
+        code path, so cached and uncached results are bit-exact by
+        construction."""
         return getattr(self.db, "promql_cache", None)
 
     def field_column(self, matchers: list[LabelMatcher]) -> str:
@@ -908,7 +905,7 @@ class PromEvaluator:
         # statement and would strip the outer TQL's replay, leaving its
         # kernel classes permanently unwarmable.
         # resident-cache event counter for this evaluation (selection /
-        # sort / group × hit / miss / reject) — surfaced to bench_promql
+        # sort / group × hit / miss / reject)
         self.cache_events: collections.Counter = collections.Counter()
         # per-stage wall ms for this evaluation (selection → sort_layout →
         # window_kernel → group_agg → label_decode), taken from the
@@ -1605,19 +1602,15 @@ class PromEvaluator:
                 seg_start)
 
     def eval_aggregation(self, e: Aggregation) -> EvalResult:
-        from greptimedb_tpu.compile import fusion_enabled
+        # whole-plan fusion: selection→window→group as ONE device
+        # dispatch when the chain matches the fused surface
+        # (compile/fused.py); None falls through to the multi-kernel
+        # path below
+        from greptimedb_tpu.compile.fused import try_fused_aggregation
 
-        if fusion_enabled():
-            # whole-plan fusion: selection→window→group as ONE device
-            # dispatch when the chain matches the fused surface
-            # (compile/fused.py); None falls through to the multi-kernel
-            # path below, which GREPTIME_PLAN_FUSION=off also restores
-            # byte-for-byte
-            from greptimedb_tpu.compile.fused import try_fused_aggregation
-
-            fused = try_fused_aggregation(self, e)
-            if fused is not None:
-                return fused
+        fused = try_fused_aggregation(self, e)
+        if fused is not None:
+            return fused
         r = self.eval(e.expr)
         if r.num_series == 0:
             return r

@@ -442,8 +442,6 @@ class QueryEngine:
         import os as _os
 
         grid_fn = getattr(self.provider, "grid_table", None)
-        if _os.environ.get("GREPTIME_GRID", "auto") == "off":
-            grid_fn = None  # A/B escape hatch: force the row path
         if grid_fn is not None:
             from greptimedb_tpu.query.physical import grid_plan_candidate
 
@@ -517,9 +515,7 @@ class QueryEngine:
         falls outside the tight warm-grid eligibility — the scheduler
         then executes the group solo, so this path can only ever be a
         fast path, never a semantic fork."""
-        import os as _os
-
-        if len(sels) < 2 or _os.environ.get("GREPTIME_GRID", "auto") == "off":
+        if len(sels) < 2:
             return None
         # the worker thread may still carry the replay context of its
         # LAST solo statement — batch-built kernel classes (the vmapped

@@ -144,6 +144,24 @@ def aot_kernel_call(kernel, call, miss: bool, metrics: dict | None):
     return out
 
 
+def aligned_layout_eligible(specs, aligned, has_time, where_fn,
+                            where_series) -> bool:
+    """Eligibility for the resident bucket-major layout (see
+    Executor._aligned_layout): a bucket-aligned time window, a WHERE that
+    is absent or tag-only, and aggregates that reduce to plain per-bucket
+    sums/counts over finite stored columns."""
+    return bool(
+        aligned
+        and has_time
+        and (where_fn is None or where_series)
+        and all(
+            (op == "count" and (fn is None or nn))
+            or (op in ("sum", "mean") and nn and ci is not None)
+            for _name, op, fn, nn, ci in specs
+        )
+    )
+
+
 def grid_plan_candidate(plan) -> bool:
     """Cheap pre-build eligibility for the dense-grid executor: structure
     and referenced columns only (grid step/shape checks need the built
@@ -1053,18 +1071,8 @@ class Executor:
         semantically."""
         if metrics is not None:
             metrics["layout"] = "dynamic_slice"
-        eligible = (
-            aligned
-            and has_time
-            and os.environ.get("GREPTIME_LAYOUT_CACHE", "auto") != "off"
-            and (where_fn is None or where_series)
-            and all(
-                (op == "count" and (fn is None or nn))
-                or (op in ("sum", "mean") and nn and ci is not None)
-                for _name, op, fn, nn, ci in specs
-            )
-        )
-        if not eligible:
+        if not aligned_layout_eligible(
+                specs, aligned, has_time, where_fn, where_series):
             return None
         step_class = (r, pad_left, nb)
         arrays = self.layout_cache.lookup(

@@ -247,13 +247,12 @@ class HttpServer(ThreadedAiohttpApp):
         self._db_executor = ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="greptime-db"
         )
-        # with the serving scheduler enabled, query requests block in
-        # scheduler.submit instead of executing here — a wider pool lets
-        # concurrent clients queue into the scheduler (where priorities,
-        # quotas and batching decide order) rather than serialize in
-        # front of it.  Created lazily: scheduler-off servers never
-        # allocate it.
-        self._submit_pool: ThreadPoolExecutor | None = None
+        # query requests block in scheduler.submit instead of executing
+        # here — a wider pool lets concurrent clients queue into the
+        # scheduler (where priorities, quotas and batching decide order)
+        # rather than serialize in front of it
+        self._submit_pool = ThreadPoolExecutor(
+            max_workers=32, thread_name_prefix="greptime-submit")
         # metric-ingest handlers get their own small pool: region writes
         # serialize per REGION (Region._write_lock), so concurrent
         # batches for different tables/regions decode+append in parallel
@@ -365,10 +364,7 @@ class HttpServer(ThreadedAiohttpApp):
         memory/concurrency quotas.  Returns a release callable (pair it
         in a finally); raises RateLimited (429) / ResourcesExhausted
         (503) — the same error surface queries get."""
-        sched = self.db.scheduler
-        if sched is None:
-            return lambda: None
-        adm = sched.admission
+        adm = self.db.scheduler.admission
         if tenant is None:
             tenant = self._tenant(request)
         # decoded columnar batches run ~4x the wire bytes (numbers widen
@@ -378,19 +374,10 @@ class HttpServer(ThreadedAiohttpApp):
         return lambda: adm.release(tenant, est)
 
     async def _call_query(self, fn, *args):
-        """Query-path executor hop: the scheduler-submit pool when the
-        serving scheduler is on (submit blocks until the worker finishes
-        the entry), the single db worker otherwise."""
-        ex = self._db_executor
-        if self.db.scheduler is not None:
-            if self._submit_pool is None:
-                from concurrent.futures import ThreadPoolExecutor
-
-                self._submit_pool = ThreadPoolExecutor(
-                    max_workers=32, thread_name_prefix="greptime-submit")
-            ex = self._submit_pool
+        """Query-path executor hop: the scheduler-submit pool (submit
+        blocks until the worker finishes the entry)."""
         return await asyncio.get_running_loop().run_in_executor(
-            ex, fn, *args)
+            self._submit_pool, fn, *args)
 
     def _tenant(self, request: web.Request) -> str:
         """Tenant identity for admission: the authenticated basic-auth
@@ -441,14 +428,6 @@ class HttpServer(ThreadedAiohttpApp):
         return default
 
     # ---- handlers ------------------------------------------------------
-    def _traced_sql(self, sql: str, ctx: tuple[str, str] | None):
-        """Executor-thread entry for /v1/sql: installs the request's
-        trace context on the DB thread (thread-locals do not cross the
-        run_in_executor boundary) so the statement's span tree is rooted
-        under the client's traceparent."""
-        with TRACER.trace_context(ctx):
-            return self.db.sql(sql)
-
     async def h_sql(self, request: web.Request) -> web.Response:
         ctx = _request_trace_context(request)
         with TRACER.stage_in(ctx, "http_request", path="/v1/sql"):
@@ -471,19 +450,14 @@ class HttpServer(ThreadedAiohttpApp):
                 res = self.db.try_fast_sql(sql)
                 timed = res is None
                 if res is None:
-                    sched = self.db.scheduler
-                    if sched is not None:
-                        tenant = self._tenant(request)
-                        prio = self._priority(request)
-                        client = request.remote or ""
-                        res = await self._call_query(
-                            lambda: sched.submit(
-                                sql, tenant=tenant, priority=prio,
-                                client=client, trace_ctx=ctx,
-                                protocol="http", slo_hold=hold))
-                    else:
-                        res = await self._call(
-                            self._traced_sql, sql, ctx)
+                    tenant = self._tenant(request)
+                    prio = self._priority(request)
+                    client = request.remote or ""
+                    res = await self._call_query(
+                        lambda: self.db.scheduler.submit(
+                            sql, tenant=tenant, priority=prio,
+                            client=client, trace_ctx=ctx,
+                            protocol="http", slo_hold=hold))
                 # serialize BEFORE observing (ISSUE 18 fix): the JSON
                 # envelope and its text are part of what the client
                 # waits for, and the histogram previously closed at
@@ -497,17 +471,13 @@ class HttpServer(ThreadedAiohttpApp):
                 if timed:
                     M_PROTOCOL_QUERY.labels("http").observe(
                         time.perf_counter() - t0)
-                    sched = self.db.scheduler
-                    if sched is not None and hold:
-                        sched.record_held(hold)
+                    self.db.scheduler.record_held(hold)
                 M_REQUESTS.labels("/v1/sql", "200").inc()
                 return resp
             except Exception as e:  # noqa: BLE001
-                sched = self.db.scheduler
-                if sched is not None and hold:
-                    # serialization failed after a clean execution: the
-                    # held sample still records (exactly-one invariant)
-                    sched.record_held(hold)
+                # serialization failed after a clean execution: the
+                # held sample still records (exactly-one invariant)
+                self.db.scheduler.record_held(hold)
                 body, status = _error_json(e)
                 M_REQUESTS.labels("/v1/sql", str(status)).inc()
                 return web.json_response(body, status=status,
@@ -540,18 +510,14 @@ class HttpServer(ThreadedAiohttpApp):
                             res, values=np.asarray(res.values))
             return res, ev.steps_ms()
 
-        sched = self.db.scheduler
-        if sched is not None:
-            # PromQL evaluations submit like SQL queries: per-tenant
-            # admission, interactive priority, deadline shedding (no
-            # cross-query batching — the PromQL layout caches already
-            # dedupe the heavy state)
-            return await self._call_query(
-                lambda: sched.submit_fn(run, tenant=tenant,
-                                        trace_ctx=trace_ctx,
-                                        label=query[:256],
-                                        protocol="prometheus"))
-        return await self._call(run)
+        # PromQL evaluations submit like SQL queries: per-tenant
+        # admission, interactive priority, deadline shedding (no
+        # cross-query batching — the PromQL layout caches already
+        # dedupe the heavy state)
+        return await self._call_query(
+            lambda: self.db.scheduler.submit_fn(
+                run, tenant=tenant, trace_ctx=trace_ctx,
+                label=query[:256], protocol="prometheus"))
 
     async def _h_prom(self, request: web.Request, route: str, params,
                       payload_name: str) -> web.Response:
@@ -1145,16 +1111,12 @@ class HttpServer(ThreadedAiohttpApp):
                         return fn(params)
 
             with M_LATENCY.labels(path).time():
-                sched = self.db.scheduler
-                if sched is not None:
-                    tenant = self._loki_tenant(request)
-                    payload = await self._call_query(
-                        lambda: sched.submit_fn(
-                            run, tenant=tenant,
-                            label=f"logql: {params.get('query', path)}"
-                            [:256], protocol="loki"))
-                else:
-                    payload = await self._call(run)
+                tenant = self._loki_tenant(request)
+                payload = await self._call_query(
+                    lambda: self.db.scheduler.submit_fn(
+                        run, tenant=tenant,
+                        label=f"logql: {params.get('query', path)}"
+                        [:256], protocol="loki"))
             M_REQUESTS.labels(path, "200").inc()
             return web.json_response(payload, headers=_trace_headers(ctx))
         except Exception as e:  # noqa: BLE001
@@ -1641,18 +1603,12 @@ class HttpServer(ThreadedAiohttpApp):
         protocol) sketch status, firing burn-rate alerts, and the idle
         economy's consumer ledgers — the same rows as
         ``information_schema.slo_status``."""
-        slo = getattr(self.db, "slo", None)
-        if slo is None:
-            return web.json_response(
-                {"enabled": False,
-                 "hint": "set GREPTIME_SLO=on (default) with the "
-                         "scheduler enabled"})
-        eco = getattr(self.db, "idle_economy", None)
+        slo = self.db.slo
         payload = {
             "enabled": True,
             "status": slo.status_rows(),
             "alerts": slo.alerts(),
-            "idle": eco.consumers() if eco is not None else [],
+            "idle": self.db.idle_economy.consumers(),
         }
         return web.json_response(payload)
 

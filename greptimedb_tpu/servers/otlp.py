@@ -151,29 +151,22 @@ def parse_otlp_metrics(body: bytes) -> dict[str, dict[str, list]]:
     """ExportMetricsServiceRequest → per-table columnar dicts (same shape
     the line-protocol/remote-write parsers emit).
 
-    Default path is the vectorized assembly (``_assemble_vec``): data
+    The assembly is vectorized (``_assemble_vec``): data
     points carry self-describing attribute sets (the protobuf forces a
     per-POINT decode), but attribute sets repeat heavily across points,
     so they memoize into a per-table vocabulary and the per-row output is
     int32 indexes — tag columns come out as ``DictColumn`` with one
-    ``np.take`` per tag instead of the legacy per-row × per-tag Python
-    loop.  ``GREPTIME_INGEST_VECTOR=off`` restores the legacy assembly."""
+    ``np.take`` per tag instead of a per-row × per-tag Python loop
+    (``_assemble_legacy``, the parity oracle of
+    tests/test_ingest_pipeline.py)."""
     from greptimedb_tpu.servers.protocols import (
-        M_INGEST_BATCHES, M_OBJECT_DECODE_ROWS, M_PARSE_SECONDS, TRACER,
-        vector_enabled,
+        M_INGEST_BATCHES, M_PARSE_SECONDS, TRACER,
     )
 
     with M_PARSE_SECONDS.labels("otlp_metrics").time(), \
             TRACER.stage("ingest_parse", protocol="otlp_metrics"):
-        rows = _walk_otlp_metrics(body)
-        if vector_enabled():
-            out = _assemble_vec(rows)
-            M_INGEST_BATCHES.labels("otlp_metrics", "vectorized").inc()
-            return out
-        out = _assemble_legacy(rows)
-        M_INGEST_BATCHES.labels("otlp_metrics", "legacy").inc()
-        M_OBJECT_DECODE_ROWS.labels("otlp_metrics").inc(
-            sum(len(t["ts"]) for t in out.values()))
+        out = _assemble_vec(_walk_otlp_metrics(body))
+        M_INGEST_BATCHES.labels("otlp_metrics", "vectorized").inc()
         return out
 
 

@@ -16,18 +16,17 @@ Each metric-ingest format has TWO decoders:
   (multithreaded number parsing); remote write keeps the per-TIMESERIES
   protobuf walk but assembles columns by ``np.repeat`` over per-series
   label sets instead of a per-row Python loop.
-- the original **row-at-a-time** decoder (``*_legacy``), selected by
-  ``GREPTIME_INGEST_VECTOR=off`` (byte-for-byte the old path, for A/B) and
-  as the fallback for wire shapes the vectorized parser does not cover
-  (escapes, quoted string fields, ragged per-line schemas).  Rows decoded
-  through it count into ``greptime_ingest_object_decode_rows_total`` —
-  the vectorized hot path pins that counter at 0.
+- the original **row-at-a-time** decoder (``*_legacy``): the fallback
+  for wire shapes the vectorized line-protocol parser does not cover
+  (escapes, quoted string fields, ragged per-line schemas) and the
+  parity oracle of tests/test_ingest_pipeline.py.  Rows decoded through
+  it count into ``greptime_ingest_object_decode_rows_total`` — the
+  vectorized hot path pins that counter at 0.
 """
 
 from __future__ import annotations
 
 import math
-import os
 import time
 from collections import defaultdict
 
@@ -47,13 +46,6 @@ M_PARSE_SECONDS = telemetry.REGISTRY.histogram(
 M_INGEST_BATCHES = telemetry.REGISTRY.counter(
     "greptime_ingest_batches_total",
     "Wire ingest batches decoded", labels=("protocol", "path"))
-
-
-def vector_enabled() -> bool:
-    """``GREPTIME_INGEST_VECTOR=off`` restores the legacy row-at-a-time
-    decoders byte-for-byte (read per call: benches A/B within a process)."""
-    return os.environ.get("GREPTIME_INGEST_VECTOR", "on").lower() not in (
-        "off", "0", "false")
 
 
 _PA_TUNED = False
@@ -162,9 +154,9 @@ def parse_line_protocol(
 
     Returns {measurement: {tag/field/ts column -> values}}; missing
     tags/fields across lines are None-filled (schema union per table).
-    Timestamps normalize to epoch ms.  With the vectorized path enabled
-    (default) columns come back as NumPy arrays / ``DictColumn`` tag
-    codes; the legacy path returns Python lists — both feed
+    Timestamps normalize to epoch ms.  From the vectorized path columns
+    come back as NumPy arrays / ``DictColumn`` tag codes; the legacy
+    fallback returns Python lists — both feed
     ``Region.write`` to identical table contents (pinned in
     tests/test_ingest_pipeline.py).
     """
@@ -173,14 +165,13 @@ def parse_line_protocol(
         raise InvalidArguments(f"bad precision {precision}")
     with M_PARSE_SECONDS.labels("influxdb").time(), \
             TRACER.stage("ingest_parse", protocol="influxdb"):
-        if vector_enabled():
-            raw = body.encode("utf-8") if isinstance(body, str) else body
-            try:
-                out = _parse_line_protocol_vec(raw, div)
-                M_INGEST_BATCHES.labels("influxdb", "vectorized").inc()
-                return out
-            except _Unvectorizable:
-                pass  # row-at-a-time fallback below
+        raw = body.encode("utf-8") if isinstance(body, str) else body
+        try:
+            out = _parse_line_protocol_vec(raw, div)
+            M_INGEST_BATCHES.labels("influxdb", "vectorized").inc()
+            return out
+        except _Unvectorizable:
+            pass  # row-at-a-time fallback below
         text = body.decode("utf-8") if isinstance(body, bytes) else body
         out = parse_line_protocol_legacy(text, precision)
         M_INGEST_BATCHES.labels("influxdb", "legacy").inc()
@@ -420,9 +411,8 @@ def parse_line_protocol_legacy(
     body: str, precision: str = "ns"
 ) -> dict[str, dict[str, list]]:
     """Row-at-a-time reference decoder (the seed path): per-line splits,
-    per-row dict/tuple assembly.  Kept byte-for-byte as the
-    ``GREPTIME_INGEST_VECTOR=off`` A/B baseline, the parity oracle, and
-    the fallback for wire shapes outside the vectorized surface."""
+    per-row dict/tuple assembly.  Kept as the parity oracle and the
+    fallback for wire shapes outside the vectorized surface."""
     div = _PRECISION_DIV.get(precision)
     if div is None:
         raise InvalidArguments(f"bad precision {precision}")
@@ -558,14 +548,8 @@ def parse_remote_write(body: bytes) -> dict[str, dict[str, list]]:
     """
     with M_PARSE_SECONDS.labels("prom_remote_write").time(), \
             TRACER.stage("ingest_parse", protocol="prom_remote_write"):
-        if vector_enabled():
-            out = _parse_remote_write_vec(body)
-            M_INGEST_BATCHES.labels("prom_remote_write", "vectorized").inc()
-            return out
-        out = parse_remote_write_legacy(body)
-        M_INGEST_BATCHES.labels("prom_remote_write", "legacy").inc()
-        M_OBJECT_DECODE_ROWS.labels("prom_remote_write").inc(
-            sum(len(t["ts"]) for t in out.values()))
+        out = _parse_remote_write_vec(body)
+        M_INGEST_BATCHES.labels("prom_remote_write", "vectorized").inc()
         return out
 
 
@@ -692,9 +676,7 @@ def parse_arrow_bulk(body: bytes) -> dict:  # gl: warm-path(host)
     Null-free columns never materialize a per-row Python object; a
     column WITH nulls drops to the object path (None must survive to the
     region's NULL semantics) and is counted in
-    ``greptime_ingest_object_decode_rows_total{protocol="arrow"}``.
-    ``GREPTIME_INGEST_VECTOR=off`` decodes every column through the
-    object path — the A/B twin of the seed's row-wise do_put."""
+    ``greptime_ingest_object_decode_rows_total{protocol="arrow"}``."""
     import numpy as np
     import pyarrow as pa
 
@@ -709,7 +691,6 @@ def parse_arrow_bulk(body: bytes) -> dict:  # gl: warm-path(host)
         if "ts" not in table.column_names:
             raise InvalidArguments("arrow bulk batch needs a 'ts' column")
         n = table.num_rows
-        vec = vector_enabled()
         objdec = False
         ts_int = False
         tag_names: list[str] = []
@@ -728,13 +709,12 @@ def parse_arrow_bulk(body: bytes) -> dict:  # gl: warm-path(host)
                     # surface the NOT NULL violation here — downstream
                     # astype would turn None into an opaque 500
                     raise InvalidArguments("arrow bulk 'ts' contains nulls")
-                # ts converts structurally on both paths — a
-                # timestamp-typed column would otherwise decode to
-                # datetime objects the region cannot take
+                # ts converts structurally — a timestamp-typed column
+                # would otherwise decode to datetime objects the region
+                # cannot take
                 ts_int = pa.types.is_integer(col.type)
-                ts = _arrow_ts_ms(col)
-                cols[name] = ts if vec else ts.tolist()
-            elif not vec or col.null_count:
+                cols[name] = _arrow_ts_ms(col)
+            elif col.null_count:
                 # object path: per-row PyObjects (None survives to the
                 # region's NULL semantics, including the NOT NULL error
                 # for a null ts)
@@ -757,11 +737,10 @@ def parse_arrow_bulk(body: bytes) -> dict:  # gl: warm-path(host)
                 cols[name] = col.to_numpy(zero_copy_only=False)
         if objdec:
             M_OBJECT_DECODE_ROWS.labels("arrow").inc(n)
-        M_INGEST_BATCHES.labels("arrow", "vectorized" if vec else "legacy"
-                                ).inc()
+        M_INGEST_BATCHES.labels("arrow", "vectorized").inc()
         cols["__tags__"] = sorted(tag_names)
         cols["__fields__"] = sorted(field_names)
-        if vec and not objdec and ts_int and n:
+        if not objdec and ts_int and n:
             # every column decoded structurally and ts is already int64
             # epoch ms on the wire: the body IS a valid slim WAL payload
             # (replay_wal re-derives codes/tsids from exactly these

@@ -12,7 +12,7 @@ import threading
 from concurrent.futures import ThreadPoolExecutor
 
 from greptimedb_tpu.utils.telemetry import REGISTRY
-from greptimedb_tpu.utils.tracing import TRACER, extract_sql_trace_context
+from greptimedb_tpu.utils.tracing import extract_sql_trace_context
 
 # same histogram object as servers/http.py's M_PROTOCOL_QUERY (the
 # registry dedupes by name): the wire servers label it mysql/postgres
@@ -38,20 +38,14 @@ class ThreadedTcpServer:
         # that are unsynchronized by design (mito2-style single worker per
         # region) and rely on this pool for serialization. Registry-only
         # statements (KILL, SHOW PROCESSLIST) bypass the pool entirely —
-        # see db.try_fast_sql at the protocol call sites.  With the
-        # serving scheduler enabled the pool carries only BLOCKING submit
-        # calls (the scheduler owns execution order and the db lock owns
-        # correctness), so it widens to let concurrent connections queue
-        # into the scheduler instead of serializing in front of it.
+        # see db.try_fast_sql at the protocol call sites.  The pool
+        # carries only BLOCKING submit calls (the scheduler owns execution
+        # order and the db lock owns correctness), so it is wide enough
+        # to let concurrent connections queue into the scheduler instead
+        # of serializing in front of it.
         self._db_executor = ThreadPoolExecutor(
-            max_workers=(16 if getattr(db, "scheduler", None) is not None
-                         else 1),
-            thread_name_prefix=f"{self.name}-db"
+            max_workers=16, thread_name_prefix=f"{self.name}-db"
         )
-
-    @property
-    def scheduler(self):
-        return getattr(self.db, "scheduler", None)
 
     async def _handle(self, reader, writer) -> None:  # pragma: no cover
         raise NotImplementedError
@@ -63,21 +57,16 @@ class ThreadedTcpServer:
         leading SQL comment (sqlcommenter convention,
         ``/* traceparent='00-…-…-01' */ SELECT …``) and seeds the span
         tree exactly like the HTTP ``traceparent`` header; this runs ON
-        the db-executor thread, where the Tracer's thread-local lives.
-        With the serving scheduler enabled, the statement submits there
-        instead — the connection's authenticated ``user`` is its tenant
-        identity for admission, and the scheduler's worker installs the
-        trace context."""
+        a db-executor thread.  The statement submits to the scheduler:
+        the connection's authenticated ``user`` is its tenant identity
+        for admission, and the scheduler's worker installs the trace
+        context."""
         ctx = extract_sql_trace_context(query)
         with M_PROTOCOL_QUERY.labels(self.protocol).time():
-            sched = self.scheduler
-            if sched is not None:
-                return sched.submit_session(
-                    query, dbname, timezone,
-                    tenant=user or "default", client=self.protocol,
-                    trace_ctx=ctx, protocol=self.protocol)
-            with TRACER.trace_context(ctx):
-                return self.db.sql_in_db(query, dbname, timezone)
+            return self.db.scheduler.submit_session(
+                query, dbname, timezone,
+                tenant=user or "default", client=self.protocol,
+                trace_ctx=ctx, protocol=self.protocol)
 
     def start(self) -> None:
         def run_loop():

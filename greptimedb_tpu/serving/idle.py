@@ -1,13 +1,12 @@
 """Budgeted idle economy: deficit-round-robin over background consumers.
 
 The arbitration half of ROADMAP item 5 (serving/slo.py is the
-observation half).  Before this, the scheduler's single ``idle_hook``
-slot was shared first-come by four ad-hoc consumers (AOT warmup, flow
-checkpoint drains, the integrity scrubber, journal/cache drains)
-through a chained dispatcher that ran EVERY member each tick — no
-weights, no fairness, no notion of how much idle time each consumed.
+observation half) and the one registry of the scheduler's idle
+consumers (AOT warmup, flow checkpoint drains, the integrity scrubber,
+journal/cache drains): ``QueryScheduler.add_idle_hook`` registers here
+and the scheduler's ``idle_hook`` is this economy's ``tick``.
 
-Here each consumer registers with a weight and the economy grants one
+Each consumer registers with a weight and the economy grants one
 consumer per idle tick by **deficit round-robin**: every eligible
 consumer accrues credit proportional to its weight each tick, the
 richest runs, and its measured elapsed time is debited in quantum
@@ -25,10 +24,6 @@ remains, False unhooks.  When the SLO engine reports a **fast-burn
 alert**, every consumer is throttled — the tick grants nothing until
 the alert clears, because idle-capacity work shares the device with
 the queries currently blowing the budget.
-
-``GREPTIME_SLO=off`` keeps this module unimported; the legacy chained
-dispatcher in ``add_idle_hook`` is untouched and serves exactly as
-before.
 """
 
 from __future__ import annotations
@@ -147,6 +142,11 @@ class IdleEconomy:
             self._consumers.append(c)
             return n
 
+    def pending(self) -> bool:
+        """Any consumer not yet drained."""
+        with self._lock:
+            return any(not c.drained for c in self._consumers)
+
     def consumers(self) -> list[dict]:
         with self._lock:
             return [{"name": c.name, "weight": c.weight,
@@ -167,8 +167,7 @@ class IdleEconomy:
             # (0.05 s) is the retry cadence, not a busy spin.
             self.throttled += 1
             M_IDLE_THROTTLED.inc()
-            with self._lock:
-                return any(not c.drained for c in self._consumers)
+            return self.pending()
         with self._lock:
             live = [c for c in self._consumers if not c.drained]
             if not live:

@@ -1,8 +1,7 @@
 """Async query scheduler: priority queues, deadline shedding, batching.
 
-Every protocol server submits queries here instead of executing inline
-(``GREPTIME_SCHEDULER=off`` restores the inline path; the package is not
-imported then).  Submit threads parse + admit (per-tenant quotas,
+Every protocol server submits queries here; there is no inline road
+beside it.  Submit threads parse + admit (per-tenant quotas,
 serving/admission.py) and block on a per-entry event; a small worker pool
 drains three priority classes — interactive > normal > background — so
 interactive queries always jump cold scans/compaction, sheds entries
@@ -31,6 +30,8 @@ from greptimedb_tpu.errors import (
     Cancelled, DeadlineExceeded, GreptimeError, ResourcesExhausted,
 )
 from greptimedb_tpu.serving.admission import TenantAdmission, TenantQuota
+from greptimedb_tpu.serving.idle import IdleEconomy
+from greptimedb_tpu.serving.slo import SloEngine
 from greptimedb_tpu.utils.telemetry import REGISTRY
 from greptimedb_tpu.utils.tracing import TRACER
 
@@ -180,7 +181,7 @@ class QueryScheduler:
         max_queue: int | None = None,
         max_batch: int | None = None,
         default_timeout_s: float | None = None,
-        batching: bool | None = None,
+        batching: bool = True,
     ):
         self.db = db
         env = os.environ.get
@@ -198,8 +199,6 @@ class QueryScheduler:
             t = env("GREPTIME_SCHEDULER_TIMEOUT_S")
             default_timeout_s = float(t) if t else None
         self.default_timeout_s = default_timeout_s
-        if batching is None:
-            batching = env("GREPTIME_SCHEDULER_BATCH", "on") != "off"
         self.batching = batching
         # group-commit linger CEILING: under saturation (more clients in
         # flight than claimed) a worker waits for coalescible arrivals
@@ -227,20 +226,19 @@ class QueryScheduler:
         self._threads: list[threading.Thread] = []
         self._started = False
         self._stopping = False
-        # optional idle-capacity hook (AOT warmup, compile/warmup.py):
-        # when set, an idle worker calls it OUTSIDE the condition lock,
-        # one unit of background work per tick; a False return (or any
-        # exception) unhooks it.  None (default) keeps the worker's
-        # indefinite wait exactly as before.
+        # idle-capacity hook: None until add_idle_hook registers a
+        # consumer, then the idle economy's tick.  An idle worker calls
+        # it OUTSIDE the condition lock, one grant per tick; a False
+        # return (every consumer drained) unhooks it and the worker
+        # goes back to its indefinite wait.
         self.idle_hook = None
-        # closed-loop observability (ISSUE 18), armed by standalone when
-        # GREPTIME_SLO is on: ``slo`` (serving/slo.py) receives exactly
-        # one sample per completed entry and feeds adaptive deadlines,
-        # adaptive linger and background admission; ``idle_economy``
-        # (serving/idle.py) takes over add_idle_hook registrations.
-        # Both None (=off) keeps every code path byte-for-byte legacy.
-        self.slo = None
-        self.idle_economy = None
+        # closed-loop observability (ISSUE 18): ``slo`` (serving/slo.py)
+        # receives exactly one sample per completed entry and feeds
+        # adaptive deadlines, adaptive linger and background admission;
+        # ``idle_economy`` (serving/idle.py) is the one registry of
+        # idle consumers.
+        self.slo = SloEngine()
+        self.idle_economy = IdleEconomy(slo=self.slo)
         # local mirrors so /status, EXPLAIN ANALYZE and the bench read
         # pressure without a registry scrape (memory.py discipline)
         self.executed = 0
@@ -279,55 +277,17 @@ class QueryScheduler:
     def add_idle_hook(self, fn, kick: bool = True, *,
                       name: str | None = None,
                       weight: float | None = None) -> None:
-        """Compose ``fn`` into the idle-capacity hook.  With the idle
-        economy armed (GREPTIME_SLO on), registrations become weighted
-        consumers and the economy's deficit-round-robin tick IS the
+        """Register ``fn`` as a weighted consumer of the idle economy
+        (AOT warmup, flow checkpoint drain, the integrity scrubber,
+        journal drains); the economy's deficit-round-robin tick IS the
         hook — one grant per tick, fairness and throttling applied
-        (serving/idle.py).  Otherwise multiple background consumers
-        (AOT warmup, flow checkpoint drain, the integrity scrubber)
-        share the single ``idle_hook`` slot through a dispatcher that
-        calls each member per tick, drops drained/failing members, and
-        reports drained (False) only when none remain — preserving the
-        worker loop's unhook-on-False contract for a lone hook.
-        ``kick=False`` registers without starting/waking the worker
-        pool: the hook begins ticking when the instance actually serves
-        traffic (embedded/test instances that never submit never spin
-        workers for it)."""
-        eco = self.idle_economy
-        if eco is not None:
-            eco.register(fn, name=name, weight=weight)
-            with self._cond:
-                self.idle_hook = eco.tick
-            if kick:
-                self.kick_idle()
-            return
+        (serving/idle.py).  ``kick=False`` registers without
+        starting/waking the worker pool: the hook begins ticking when
+        the instance actually serves traffic (embedded/test instances
+        that never submit never spin workers for it)."""
+        self.idle_economy.register(fn, name=name, weight=weight)
         with self._cond:
-            cur = self.idle_hook
-            if cur is None:
-                self.idle_hook = fn
-            elif getattr(cur, "_gl_hooks", None) is not None:
-                cur._gl_hooks.append(fn)
-            else:
-                hooks = [cur, fn]
-
-                def _multi():
-                    alive = False
-                    for h in list(_multi._gl_hooks):
-                        try:
-                            keep = bool(h())
-                        except Exception:  # noqa: BLE001 — a failing
-                            keep = False  # member must not kill the rest
-                        if keep:
-                            alive = True
-                        else:
-                            try:
-                                _multi._gl_hooks.remove(h)
-                            except ValueError:
-                                pass
-                    return alive
-
-                _multi._gl_hooks = hooks
-                self.idle_hook = _multi
+            self.idle_hook = self.idle_economy.tick
         if kick:
             self.kick_idle()
 
@@ -422,7 +382,7 @@ class QueryScheduler:
 
     def _set_deadline(self, e: _Entry, timeout_s: float | None) -> None:
         t = timeout_s if timeout_s is not None else self.default_timeout_s
-        if t is None and self.slo is not None:
+        if t is None:
             # no configured timeout: derive one from the class's OBSERVED
             # p99 (x factor, generously floored) instead of running
             # unbounded — None again below the sample floor, so a fresh
@@ -431,22 +391,21 @@ class QueryScheduler:
         if t is not None and t > 0:
             e.deadline = time.monotonic() + t
 
-    # ---- closed-loop accounting (ISSUE 18; no-ops with slo unarmed) ----
+    # ---- closed-loop accounting (ISSUE 18) ----------------------------
     def _finish(self, e: _Entry) -> None:
         """Deliver ``e`` to its waiter, recording EXACTLY one SLO sample
         per entry: shed/cancelled work records as a breach (budget was
         consumed without an answer), ordinary errors record their true
         latency, and a clean finish with a caller-held sample defers to
         the submitter (response serialization still ahead)."""
-        slo = self.slo
-        if slo is not None and not e._slo_done:
+        if not e._slo_done:
             e._slo_done = True
             try:
                 if e.error is None and e.slo_hold is not None:
                     e.slo_hold.append(
                         (e.tenant, e.priority, e.protocol, e.enqueued))
                 else:
-                    slo.record(
+                    self.slo.record(
                         e.tenant, e.priority, e.protocol,
                         time.monotonic() - e.enqueued,
                         bad=isinstance(e.error,
@@ -459,11 +418,9 @@ class QueryScheduler:
         """Record caller-held samples (servers/http.py calls this after
         serializing the response, so the sketch covers the full
         submit→bytes-ready span)."""
-        slo = self.slo
-        if slo is not None:
-            now = time.monotonic()
-            for tenant, priority, protocol, enqueued in hold:
-                slo.record(tenant, priority, protocol, now - enqueued)
+        now = time.monotonic()
+        for tenant, priority, protocol, enqueued in hold:
+            self.slo.record(tenant, priority, protocol, now - enqueued)
         hold.clear()
 
     def _estimate_cost_ms(self, e: _Entry) -> float:
@@ -485,8 +442,6 @@ class QueryScheduler:
     def _note_cost(self, sqls, dt_s: float) -> None:
         """Feed measured execution time back into the journal's
         per-class cost EWMA — the estimate the admission check reads."""
-        if self.slo is None:
-            return
         pc = getattr(self.db, "plan_compiler", None)
         j = getattr(pc, "journal", None) if pc is not None else None
         if j is None:
@@ -503,7 +458,7 @@ class QueryScheduler:
         if e.priority not in PRIORITIES:
             raise ValueError(f"unknown priority {e.priority!r}")
         self._ensure_started()
-        if e.priority == "background" and self.slo is not None:
+        if e.priority == "background":
             est = self._estimate_cost_ms(e)
             ok, allowance = self.slo.admit_background(est)
             if not ok:
@@ -561,7 +516,7 @@ class QueryScheduler:
                             pass
                 # abandoned-before-claim is a breach the workers never
                 # see: record it here (claimed entries reach _finish)
-                if removed and self.slo is not None and not e._slo_done:
+                if removed and not e._slo_done:
                     e._slo_done = True
                     self.slo.record(e.tenant, e.priority, e.protocol,
                                     time.monotonic() - e.enqueued,
@@ -639,16 +594,15 @@ class QueryScheduler:
         if pending <= 0:
             return 0.0
         ceil_ms = self.linger_ms
-        if self.slo is not None:
-            # linger adapts to the MEASURED queue-wait sketch: when this
-            # class already waits w at p95, fishing for batch mates up to
-            # ~2w is latency noise (stacking pays for itself); when waits
-            # are near zero, a lightly loaded server must not pay the
-            # full configured ceiling for a mate that may never come
-            w = self.slo.wait_quantile(priority, 0.95)
-            if w is not None:
-                ceil_ms = min(self.linger_ms,
-                              max(self.linger_ms * 0.25, w * 2000.0))
+        # linger adapts to the MEASURED queue-wait sketch: when this
+        # class already waits w at p95, fishing for batch mates up to
+        # ~2w is latency noise (stacking pays for itself); when waits
+        # are near zero, a lightly loaded server must not pay the
+        # full configured ceiling for a mate that may never come
+        w = self.slo.wait_quantile(priority, 0.95)
+        if w is not None:
+            ceil_ms = min(self.linger_ms,
+                          max(self.linger_ms * 0.25, w * 2000.0))
         return (ceil_ms / 1000.0) * min(
             1.0, pending / max(1, self.max_batch))
 
@@ -664,7 +618,7 @@ class QueryScheduler:
                     if hook is None:
                         self._cond.wait()
                         continue
-                    # background warmup pending: bounded wait, then (still
+                    # idle consumers pending: bounded wait, then (still
                     # idle) run one tick outside the lock — live queries
                     # always win the claim
                     self._cond.wait(timeout=0.05)
@@ -683,15 +637,11 @@ class QueryScheduler:
                 except Exception:  # noqa: BLE001 — warmup must not kill
                     drained = True  # the worker
                 if drained:
-                    # unhook under the lock, and only while the hook is
-                    # still the one we ran AND gained no new members —
-                    # add_idle_hook may have extended the dispatcher (or
-                    # replaced a lone hook) concurrently with this tick,
-                    # and clearing blindly would discard that registration
+                    # unhook under the lock; a registration that raced
+                    # this tick re-armed the hook after the economy saw
+                    # no live consumer, so look again before clearing
                     with self._cond:
-                        cur = self.idle_hook
-                        if cur is idle_work and not getattr(
-                                cur, "_gl_hooks", None):
+                        if not self.idle_economy.pending():
                             self.idle_hook = None
                 continue
             with self._cond:
@@ -725,8 +675,7 @@ class QueryScheduler:
             for e in group:
                 e.wait_ms = (now - e.enqueued) * 1000.0
                 M_WAIT.labels(e.priority).observe(e.wait_ms / 1000.0)
-                if self.slo is not None:
-                    self.slo.record_wait(e.priority, e.wait_ms / 1000.0)
+                self.slo.record_wait(e.priority, e.wait_ms / 1000.0)
                 if e.deadline is not None and now > e.deadline:
                     self.shed += 1
                     M_SHED.labels(e.priority).inc()

@@ -23,8 +23,7 @@ is O(slots) at scrape time and O(1) at record time.
 Everything here is registry-exported (``greptime_slo_*`` pull gauges),
 so the PR-4 self-monitor loop ingests it and the DB can PromQL-query
 its own burn rates; ``information_schema.slo_status`` and ``/v1/slo``
-render the same rows.  ``GREPTIME_SLO=off`` keeps this module entirely
-unimported (standalone.py gate) — today's behavior byte-for-byte.
+render the same rows.  Every ``QueryScheduler`` owns one engine.
 """
 
 from __future__ import annotations
@@ -261,8 +260,8 @@ class SloEngine:
 
     def set_objective(self, tenant: str, threshold_ms: float,
                       objective: float | None = None) -> None:
-        """Runtime override (bench_soak's induced latency storm flips
-        the objective under live traffic and back)."""
+        """Runtime override: flips a tenant's objective under live
+        traffic (and back)."""
         with self._lock:
             self._overrides[tenant] = (
                 threshold_ms / 1000.0,

@@ -476,38 +476,19 @@ class GreptimeDB(TableProvider):
         # concurrent serving layer (serving/): protocol servers submit
         # queries through the scheduler — per-tenant admission, priority
         # classes, deadline shedding, cross-query stacked dispatch.
-        # GREPTIME_SCHEDULER=off restores the inline path byte-for-byte:
-        # the package is never imported, servers call db.sql directly,
-        # and the warm path carries zero new allocations (pinned in
-        # tests/test_scheduler.py).  Worker threads start lazily on the
-        # first submit, so non-serving embedders pay only this attribute.
-        self.scheduler = None
-        if os.environ.get("GREPTIME_SCHEDULER", "on").lower() not in (
-                "off", "0", "false"):
-            from greptimedb_tpu.serving import QueryScheduler
+        # Worker threads start lazily on the first submit, so an
+        # embedded db.sql() pays only this attribute.
+        from greptimedb_tpu.serving import QueryScheduler
 
-            self.scheduler = QueryScheduler(self)
+        self.scheduler = QueryScheduler(self)
         # closed-loop SLO observatory (ISSUE 18, serving/slo.py +
-        # serving/idle.py): per-(tenant, class, protocol) latency
-        # sketches, error budgets and burn-rate alerts, plus the
-        # budgeted idle economy that arbitrates the scheduler's idle
-        # capacity between warmup / flow checkpoints / scrubbing /
-        # journal drains.  GREPTIME_SLO=off restores today's behavior
-        # byte-for-byte — neither module is imported, the scheduler's
-        # slo/idle_economy stay None, and every consumer below falls
-        # back to the legacy chained idle hook.
-        self.slo = None
-        self.idle_economy = None
-        if (self.scheduler is not None
-                and os.environ.get("GREPTIME_SLO", "on").lower() not in (
-                    "off", "0", "false")):
-            from greptimedb_tpu.serving.idle import IdleEconomy
-            from greptimedb_tpu.serving.slo import SloEngine
-
-            self.slo = SloEngine()
-            self.idle_economy = IdleEconomy(slo=self.slo)
-            self.scheduler.slo = self.slo
-            self.scheduler.idle_economy = self.idle_economy
+        # serving/idle.py), owned by the scheduler: per-(tenant, class,
+        # protocol) latency sketches, error budgets and burn-rate
+        # alerts, plus the budgeted idle economy that arbitrates the
+        # scheduler's idle capacity between warmup / flow checkpoints /
+        # scrubbing / journal drains.
+        self.slo = self.scheduler.slo
+        self.idle_economy = self.scheduler.idle_economy
         # persistent procedure manager (repartition etc.): one instance so
         # table locks are process-wide; RUNNING journals from a crashed
         # process resume here at startup
@@ -571,13 +552,11 @@ class GreptimeDB(TableProvider):
                 self, self.plan_compiler,
                 top_k=int(os.environ.get("GREPTIME_AOT_WARMUP_TOP_K", "8")))
             self.warmup.warm_on_open()
-            if self.scheduler is not None and self.warmup.pending():
-                # add_idle_hook (not direct assignment): the flow
-                # checkpoint drain shares the idle slot
+            if self.warmup.pending():
+                # kick (the default) wakes/starts the workers: an idle
+                # standby node must drain its warmup queue without
+                # waiting for traffic
                 self.scheduler.add_idle_hook(self.warmup.idle_tick)
-                # wake/start the workers: an idle standby node must
-                # drain its warmup queue without waiting for traffic
-                self.scheduler.kick_idle()
         # online integrity scrubber (storage/scrubber.py, ISSUE 15): a
         # low-priority verified sweep over cold SSTs / manifest files /
         # WAL segments / grid snapshots / the S3 read cache on the
@@ -587,8 +566,7 @@ class GreptimeDB(TableProvider):
         # `on` starts sweeping immediately (a standby node scrubs too).
         self.scrubber = None
         _sc = os.environ.get("GREPTIME_SCRUB", "auto").lower()
-        if (_sc not in ("off", "0", "false")
-                and self.scheduler is not None and not self.memory_mode):
+        if _sc not in ("off", "0", "false") and not self.memory_mode:
             from greptimedb_tpu.storage.scrubber import Scrubber
 
             self.scrubber = Scrubber(
@@ -596,14 +574,11 @@ class GreptimeDB(TableProvider):
                 snapshot_dirs=[os.path.join(data_home, "grid_snap")])
             self.scheduler.add_idle_hook(
                 self.scrubber.tick, kick=_sc in ("on", "1", "true"))
-        # journal/cache drain as a WEIGHTED idle consumer: with the idle
-        # economy armed, usage-journal persistence stops riding the
-        # note() call's save-every-8 hiccup exclusively and instead
-        # drains on granted idle ticks like every other background
-        # consumer (cheap, so low weight)
-        if (self.idle_economy is not None
-                and getattr(self.plan_compiler, "journal", None)
-                is not None):
+        # journal/cache drain as a WEIGHTED idle consumer: usage-journal
+        # persistence stops riding the note() call's save-every-8
+        # hiccup exclusively and instead drains on granted idle ticks
+        # like every other background consumer (cheap, so low weight)
+        if getattr(self.plan_compiler, "journal", None) is not None:
             self.scheduler.add_idle_hook(
                 self._journal_drain_tick, kick=False,
                 name="journal_drain", weight=0.5)
@@ -641,11 +616,10 @@ class GreptimeDB(TableProvider):
         self-monitor, close region WAL handles, close the kv store.
         ``flush=True`` (the graceful SIGTERM server path) also flushes
         dirty regions so a clean restart replays O(hot-tail)."""
-        if self.scheduler is not None:
-            # unhook idle warmup first: a tick claimed after this point
-            # would replay statements against a closing instance
-            self.scheduler.idle_hook = None
-            self.scheduler.stop()
+        # unhook idle warmup first: a tick claimed after this point
+        # would replay statements against a closing instance
+        self.scheduler.idle_hook = None
+        self.scheduler.stop()
         if self.flow_checkpoints is not None:
             # final checkpoints: a clean restart resumes every flow from
             # its exact watermark with zero tail to replay
@@ -2090,7 +2064,7 @@ class GreptimeDB(TableProvider):
                 for k in metrics
             ]
             rows.append(["analyze (cold vs warm ms)", "\n".join(lines)])
-            if sched and self.scheduler is not None:
+            if sched:
                 st = self.scheduler.stats()
                 rows.append([
                     "analyze (scheduler)",

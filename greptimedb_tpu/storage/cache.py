@@ -37,7 +37,7 @@ from greptimedb_tpu.utils.telemetry import REGISTRY
 # metrics.rs).  The instance attributes (hits/misses/...) stay the
 # per-cache source of truth for tests and /status; these registry
 # counters make the same events SQL-queryable via runtime_metrics and
-# scrapeable at /metrics, which is what bench.py/bench_promql.py read.
+# scrapeable at /metrics, which is what benchmark/run.py reads.
 M_CACHE_EVENTS = REGISTRY.counter(
     "greptime_cache_events_total",
     "Resident-cache events (hit/miss/build/eviction/invalidation/"
@@ -217,12 +217,8 @@ def build_device_table(
     ``__tagcode_<name>__`` int32 companions already in region code space
     (storage/sst.py maps each file's dictionary once), so canonicalization
     is a rename — no per-row object array, no re-hash.  Duck-typed views
-    (combined/metric/file engines) keep the raw scan + re-encode;
-    ``GREPTIME_SCAN_TAG_CODES=off`` forces the raw path for A/B."""
-    import os
-
-    if (getattr(region, "scan_supports_codes", False)
-            and os.environ.get("GREPTIME_SCAN_TAG_CODES", "on") != "off"):
+    (combined/metric/file engines) keep the raw scan + re-encode."""
+    if getattr(region, "scan_supports_codes", False):
         host = region.scan_host(ts_range, columns, with_tag_codes=True)
     else:
         host = region.scan_host(ts_range, columns)

@@ -24,8 +24,8 @@ Theseus (arXiv:2508.05029) applied to the scan path:
   time-disjoint runs merge with one narrow tsid-key radix argsort; the
   general case takes one packed-key radix argsort — numpy's stable
   integer sort — instead of a 3-key comparison lexsort.  Output is
-  bit-exact with the lexsort path (``GREPTIME_SCAN_FORCE_LEXSORT=1``
-  forces the old path for A/B and parity tests).
+  bit-exact with the lexsort path, which stays as the fallback for keys
+  too wide to pack.
 - ``stream_to_device``: chunked host→device upload with DOUBLE BUFFERING —
   the next chunk's ``device_put`` dispatches while the previous one is
   still in flight (bounded at 2 outstanding chunks, so in-flight bytes
@@ -110,12 +110,13 @@ _UPLOAD_CHUNK_BYTES = 64 << 20
 _UPLOAD_DEPTH = 2
 
 
-# Scan-pool preemption hook (serving/scheduler.py installs it when the
-# scheduler is enabled): returns True when the CALLING thread is running
-# background-priority work while interactive queries wait — the decode
-# pool then narrows to one thread so a cold scan/compaction pass stops
-# monopolizing cores under interactive load.  None (scheduler off) costs
-# the warm path nothing.
+# Scan-pool preemption hook (serving/scheduler.py installs it on import;
+# the storage layer never imports serving/): returns True when the
+# CALLING thread is running background-priority work while interactive
+# queries wait — the decode pool then narrows to one thread so a cold
+# scan/compaction pass stops monopolizing cores under interactive load.
+# None (a bare RegionEngine, no serving layer in the process) costs the
+# warm path nothing.
 background_yield_hook = None
 
 
@@ -284,8 +285,7 @@ def merge_parts(parts, ts_name: str, tsid_name: str, seq_name: str):
       run order, so the result is exact;
     - ``packed_sort``: interleaving/unsorted sources — one stable radix
       argsort over the packed 1-D keys (still ~4x under lexsort);
-    - ``lexsort``: key space too wide to pack, or forced via
-      ``GREPTIME_SCAN_FORCE_LEXSORT=1`` (the A/B reference path).
+    - ``lexsort``: key space too wide to pack (or poison tsids).
     """
     global LAST_MERGE_PATH
     t0 = time.perf_counter()
@@ -318,8 +318,6 @@ def _merge_parts(parts, ts_name, tsid_name, seq_name):
             (merged[seq_name], merged[ts_name], merged[tsid_name]))
         return {k: v[order] for k, v in merged.items()}, "lexsort"
 
-    if os.environ.get("GREPTIME_SCAN_FORCE_LEXSORT") == "1":
-        return lexsorted()
     keys = _pack_keys(live, ts_name, tsid_name, seq_name)
     if keys is None:
         return lexsorted()

@@ -311,10 +311,8 @@ class Scrubber:
     def _yielding(self) -> bool:
         if self._should_yield is not None:
             return bool(self._should_yield())
-        try:
-            from greptimedb_tpu.serving.scheduler import interactive_waiting
-        except ImportError:  # scheduler off: nothing to preempt for
-            return False
+        from greptimedb_tpu.serving.scheduler import interactive_waiting
+
         return interactive_waiting() > 0
 
     def tick(self) -> bool:

@@ -6,6 +6,7 @@ we fake an 8-chip TPU slice with XLA's host-platform device count so all
 mesh/sharding/collective paths run in CI without TPU hardware.
 """
 
+import contextlib
 import os
 
 flags = os.environ.get("XLA_FLAGS", "")
@@ -31,6 +32,41 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.default_rng(42)
+
+
+# The fast roads a parity test compares with the road beside them.  The
+# program picks each from one predicate over its input, so a test reaches
+# the other road by making every input ineligible there.
+_FAST_ROADS = {
+    # dense time-grid executor -> row-major DeviceTable path
+    "grid": ("greptimedb_tpu.query.physical.grid_plan_candidate",
+             lambda plan: False),
+    # resident bucket-major layout -> dynamic-slice grid kernel
+    "layout": ("greptimedb_tpu.query.physical.aligned_layout_eligible",
+               lambda *a: False),
+    # whole-plan fused PromQL chain -> multi-kernel path
+    "fusion": ("greptimedb_tpu.compile.fused.try_fused_aggregation",
+               lambda ev, e: None),
+    # packed-key radix merge of scan parts -> global lexsort
+    "packed_merge": ("greptimedb_tpu.storage.scan._pack_keys",
+                     lambda *a: None),
+}
+
+
+@pytest.fixture
+def ineligible(monkeypatch):
+    """``with ineligible("grid"): ...`` — inside the block no input is
+    eligible for the named fast road, so the program takes the road it
+    falls back to (the reference of the parity tests)."""
+
+    @contextlib.contextmanager
+    def road(name):
+        target, never = _FAST_ROADS[name]
+        with monkeypatch.context() as mp:
+            mp.setattr(target, never)
+            yield
+
+    return road
 
 
 @pytest.fixture

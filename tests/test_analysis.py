@@ -523,6 +523,30 @@ class TestConfigMd:
         undocumented = {k for k, _d, _f, _l in reads} - set(KNOB_DOCS)
         assert not undocumented, undocumented
 
+    @pytest.fixture(scope="class")
+    def knobs_read(self):
+        from greptimedb_tpu.analysis.passes.hygiene import (
+            collect_knob_reads,
+        )
+
+        return {k for k, _d, _f, _l in
+                collect_knob_reads(core.load_package())}
+
+    # PR 32: each of these selected a second road beside the one every
+    # served request takes; the road is now chosen from what the code
+    # observes, and the name must not come back as a read or a document
+    @pytest.mark.parametrize("knob", [
+        "GREPTIME_SCHEDULER", "GREPTIME_SLO", "GREPTIME_PLAN_FUSION",
+        "GREPTIME_PROMQL_CACHE", "GREPTIME_GRID", "GREPTIME_LAYOUT_CACHE",
+        "GREPTIME_INGEST_VECTOR", "GREPTIME_SCAN_TAG_CODES",
+        "GREPTIME_SCAN_FORCE_LEXSORT", "GREPTIME_SCHEDULER_BATCH",
+    ])
+    def test_removed_switch_stays_removed(self, knobs_read, knob):
+        from greptimedb_tpu.analysis.passes.hygiene import KNOB_DOCS
+
+        assert knob not in knobs_read
+        assert knob not in KNOB_DOCS
+
 
 # ---------------------------------------------------------------------------
 # CLI

@@ -2,8 +2,8 @@
 
 Covers the PR's acceptance surface:
 
-- whole-plan fusion parity: fused PromQL chains bit-exact vs
-  ``GREPTIME_PLAN_FUSION=off`` across a (function × aggregation op)
+- whole-plan fusion parity: fused PromQL chains bit-exact vs the
+  multi-kernel path (conftest's ``ineligible("fusion")``) across a (function × aggregation op)
   fuzz, and warm SQL grid classes pinned at ONE device dispatch via the
   ``device_dispatches`` counter EXPLAIN ANALYZE surfaces;
 - persistent compile cache integrity: corrupt/truncated artifacts
@@ -380,15 +380,17 @@ _FUZZ_CASES = [
 class TestFusionParity:
     @pytest.mark.parametrize('func,agg', _FUZZ_CASES,
                              ids=[f"{a}_{f[:12]}" for f, a in _FUZZ_CASES])
-    def test_fused_vs_off_bit_exact(self, db, func, agg, monkeypatch):
+    def test_fused_vs_unfused_bit_exact(self, db, func, agg, ineligible):
         from greptimedb_tpu.compile.fused import FUSED_DISPATCHES
 
         q = _tql(f"{agg} ({func})")
         before = FUSED_DISPATCHES["count"]
         fused = db.sql(q)
         assert FUSED_DISPATCHES["count"] > before, "fused path not taken"
-        monkeypatch.setenv('GREPTIME_PLAN_FUSION', "off")
-        plain = db.sql(q)
+        before = FUSED_DISPATCHES["count"]
+        with ineligible("fusion"):
+            plain = db.sql(q)
+        assert FUSED_DISPATCHES["count"] == before, "reference ran fused"
         assert fused.column_names == plain.column_names
         # BIT-exact: float cells compare with ==, not approx
         assert fused.rows == plain.rows

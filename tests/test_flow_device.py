@@ -638,8 +638,6 @@ class TestIdleCheckpointDrain:
 
     def test_add_idle_hook_composes(self, db):
         calls = []
-        if db.scheduler is None:
-            pytest.skip("scheduler off")
         db.scheduler.add_idle_hook(lambda: calls.append("a") and False)
         db.scheduler.add_idle_hook(lambda: calls.append("b") and False)
         import time as _t

@@ -1,11 +1,10 @@
 """Dense time-grid executor: equivalence vs the row-oriented path.
 
-Every query here runs twice — grid path (default) and GREPTIME_GRID=off
-(row DeviceTable path) — on the same data; results must agree.  The row
-path is itself golden-tested, so agreement pins the grid kernels.
+Every query here runs twice — the grid path, and the row DeviceTable path
+(conftest's ``ineligible("grid")``) — on the same data; results must
+agree.  The row path is itself golden-tested, so agreement pins the grid
+kernels.
 """
-
-import os
 
 import numpy as np
 import pytest
@@ -34,21 +33,24 @@ def _assert_rows_close(a, b, sql):
                 assert va == vb, f"{va} vs {vb}: {sql}"
 
 
-def run_both(db, sql, expect_grid=True):
-    before = DISPATCH_STATS["grid"]
-    r_grid = db.sql(sql)
-    used = DISPATCH_STATS["grid"] > before
-    assert used == expect_grid, (
-        f"grid used={used}, expected {expect_grid}: {sql}"
-    )
-    os.environ["GREPTIME_GRID"] = "off"
-    try:
-        r_row = db.sql(sql)
-    finally:
-        os.environ.pop("GREPTIME_GRID", None)
-    assert r_grid.column_names == r_row.column_names, sql
-    _assert_rows_close(_rows(r_grid), _rows(r_row), sql)
-    return r_grid
+@pytest.fixture
+def run_both(ineligible):
+    def run_both(db, sql, expect_grid=True):
+        before = DISPATCH_STATS["grid"]
+        r_grid = db.sql(sql)
+        used = DISPATCH_STATS["grid"] > before
+        assert used == expect_grid, (
+            f"grid used={used}, expected {expect_grid}: {sql}"
+        )
+        before = DISPATCH_STATS["grid"]
+        with ineligible("grid"):
+            r_row = db.sql(sql)
+        assert DISPATCH_STATS["grid"] == before, "reference ran the grid"
+        assert r_grid.column_names == r_row.column_names, sql
+        _assert_rows_close(_rows(r_grid), _rows(r_row), sql)
+        return r_grid
+
+    return run_both
 
 
 @pytest.fixture
@@ -76,62 +78,62 @@ def db(tmp_path):
     d.close()
 
 
-def test_double_groupby(db):
+def test_double_groupby(db, run_both):
     r = run_both(db, "SELECT host, date_trunc('minute', ts) AS m, "
                      "avg(usage), avg(mem) FROM cpu GROUP BY host, m")
     # 240 steps @5s = 1200s spanning 21 partial minutes (t0 not aligned)
     assert r.num_rows == 6 * 21
 
 
-def test_key_order_time_first(db):
+def test_key_order_time_first(db, run_both):
     run_both(db, "SELECT date_trunc('minute', ts) AS m, host, avg(usage) "
                  "FROM cpu GROUP BY m, host")
 
 
-def test_all_ops(db):
+def test_all_ops(db, run_both):
     run_both(db, "SELECT dc, count(*), count(mem), sum(usage), min(mem), "
                  "max(usage), avg(mem) FROM cpu GROUP BY dc")
 
 
-def test_global_agg(db):
+def test_global_agg(db, run_both):
     r = run_both(db, "SELECT count(*), avg(usage) FROM cpu")
     assert r.num_rows == 1
 
 
-def test_global_agg_empty_window(db):
+def test_global_agg_empty_window(db, run_both):
     r = run_both(db, "SELECT count(*), max(usage) FROM cpu WHERE ts < 5")
     assert r.rows[0][0] == 0 and r.rows[0][1] is None
 
 
-def test_time_window_and_tag_filter(db):
+def test_time_window_and_tag_filter(db, run_both):
     run_both(db, "SELECT host, date_trunc('minute', ts) AS m, avg(usage) "
                  "FROM cpu WHERE ts >= 1700000300000 AND ts < 1700000900000 "
                  "AND dc = 'dc0' GROUP BY host, m")
 
 
-def test_field_predicate(db):
+def test_field_predicate(db, run_both):
     run_both(db, "SELECT host, count(*) FROM cpu WHERE usage > 50 "
                  "GROUP BY host")
 
 
-def test_expression_agg(db):
+def test_expression_agg(db, run_both):
     run_both(db, "SELECT host, avg(usage + mem), sum(usage * 2) "
                  "FROM cpu GROUP BY host")
 
 
-def test_unaligned_window_start(db):
+def test_unaligned_window_start(db, run_both):
     # window start not aligned to the minute buckets nor the 5s grid
     run_both(db, "SELECT date_trunc('minute', ts) AS m, sum(usage) "
                  "FROM cpu WHERE ts >= 1700000302000 GROUP BY m")
 
 
-def test_delete_excluded(db):
+def test_delete_excluded(db, run_both):
     db.sql("DELETE FROM cpu WHERE host = 'h1' AND dc = 'dc1' "
            "AND ts = 1700000000000")
     run_both(db, "SELECT host, count(*) FROM cpu GROUP BY host")
 
 
-def test_append_extension(db):
+def test_append_extension(db, run_both):
     # first query builds the grid; appends then extend it device-side
     run_both(db, "SELECT host, count(*) FROM cpu GROUP BY host")
     t = 1700000000000 + 240 * 5000
@@ -142,7 +144,7 @@ def test_append_extension(db):
     assert counts["h6"] == 1 and counts["h0"] == 241
 
 
-def test_irregular_falls_back(tmp_path):
+def test_irregular_falls_back(tmp_path, run_both):
     db = GreptimeDB(str(tmp_path / "i"))
     db.sql("CREATE TABLE ev (h STRING, ts TIMESTAMP(3) TIME INDEX, "
            "v DOUBLE, PRIMARY KEY (h))")
@@ -159,14 +161,14 @@ def test_irregular_falls_back(tmp_path):
     db.close()
 
 
-def test_unsupported_aggs_fall_back(db):
+def test_unsupported_aggs_fall_back(db, run_both):
     run_both(db, "SELECT host, count(DISTINCT dc) FROM cpu GROUP BY host",
              expect_grid=False)
     run_both(db, "SELECT host, stddev(usage) FROM cpu GROUP BY host",
              expect_grid=False)
 
 
-def test_grid_vs_row_after_flush_cycles(db):
+def test_grid_vs_row_after_flush_cycles(db, run_both):
     # second flush (structure change) → grid rebuild on next query
     t = 1700000000000 + 300 * 5000
     db.sql(f"INSERT INTO cpu VALUES ('h2','dc0',{t},10.0,1.0)")
@@ -174,7 +176,7 @@ def test_grid_vs_row_after_flush_cycles(db):
     run_both(db, "SELECT host, max(usage) FROM cpu GROUP BY host")
 
 
-def test_delete_with_default_fill_excluded_from_sums(tmp_path):
+def test_delete_with_default_fill_excluded_from_sums(tmp_path, run_both):
     # tombstone rows carry schema DEFAULT fills in their field payload;
     # the mask-free sum fast path must not count them (review r4 finding)
     db = GreptimeDB(str(tmp_path / "d"))
@@ -190,7 +192,7 @@ def test_delete_with_default_fill_excluded_from_sums(tmp_path):
     db.close()
 
 
-def test_inf_values_take_masked_path(tmp_path):
+def test_inf_values_take_masked_path(tmp_path, run_both):
     # written ±inf must not meet the 0/1 weight multiply (inf*0 = NaN)
     db = GreptimeDB(str(tmp_path / "inf"))
     db.sql("CREATE TABLE m (h STRING, ts TIMESTAMP(3) TIME INDEX, "
@@ -213,7 +215,7 @@ def test_inf_values_take_masked_path(tmp_path):
     db.close()
 
 
-def test_grid_snapshot_roundtrip(db, tmp_path):
+def test_grid_snapshot_roundtrip(db, tmp_path, run_both):
     # snapshot persist/restore: same tensors, installed as the live entry
     from greptimedb_tpu.storage.grid import (
         load_grid_snapshot, save_grid_snapshot,

@@ -8,8 +8,8 @@ Covers the PR's acceptance surface:
   row-at-a-time fallback must produce the same columns the legacy path
   yields, and the clean shapes must pin the object-decode counter at 0)
 - end-to-end table-content parity: the same wire body ingested through
-  the vectorized and the ``GREPTIME_INGEST_VECTOR=off`` path produces
-  identical SQL results
+  the vectorized decoders and the row-at-a-time ``*_legacy`` oracles
+  produces identical SQL results
 - WAL group commit: concurrent appenders share one fsync, acked records
   survive a kill (no close/flush) and replay losslessly, torn tails
   still repair
@@ -30,7 +30,8 @@ from greptimedb_tpu.datatypes.schema import ColumnSchema, Schema
 from greptimedb_tpu.datatypes.types import ConcreteDataType as T
 from greptimedb_tpu.datatypes.types import SemanticType as S
 from greptimedb_tpu.servers.protocols import (
-    parse_line_protocol, parse_remote_write,
+    parse_line_protocol, parse_line_protocol_legacy, parse_remote_write,
+    parse_remote_write_legacy,
 )
 from greptimedb_tpu.standalone import GreptimeDB
 from greptimedb_tpu.utils.proto import pb_len as _pb_len
@@ -73,13 +74,10 @@ def _assert_tables_equal(a, b):
                 assert x == y, f"{t}.{k}[{i}]: {x!r} != {y!r}"
 
 
-def _parse_lp_both(monkeypatch, body, precision="ns"):
-    monkeypatch.delenv("GREPTIME_INGEST_VECTOR", raising=False)
+def _parse_lp_both(body, precision="ns"):
     vec = _norm(parse_line_protocol(body, precision))
-    monkeypatch.setenv("GREPTIME_INGEST_VECTOR", "off")
     txt = body.decode("utf-8") if isinstance(body, bytes) else body
-    legacy = _norm(parse_line_protocol(txt, precision))
-    monkeypatch.delenv("GREPTIME_INGEST_VECTOR", raising=False)
+    legacy = _norm(parse_line_protocol_legacy(txt, precision))
     return vec, legacy
 
 
@@ -130,13 +128,12 @@ def _otlp_gauge_request(points):
 # ---------------------------------------------------------------------------
 
 class TestLineProtocolParity:
-    def test_clean_batch_and_object_decode_pin(self, monkeypatch):
+    def test_clean_batch_and_object_decode_pin(self):
         body = (
             b"cpu,host=a,dc=east usage=1.5,load=0.25 1000000\n"
             b"cpu,host=b,dc=west usage=2.5,load=0.5 2000000\n"
             b"cpu,host=a,dc=east usage=3.5,load=0.75 3000000\n"
         )
-        monkeypatch.delenv("GREPTIME_INGEST_VECTOR", raising=False)
         before = REGISTRY.value(
             "greptime_ingest_object_decode_rows_total", ("influxdb",))
         vec = parse_line_protocol(body, "ns")
@@ -149,12 +146,10 @@ class TestLineProtocolParity:
         assert hasattr(vec["cpu"]["host"], "codes")
         assert list(vec["cpu"]["host"].values) in (
             ["a", "b"], ["b", "a"])
-        monkeypatch.setenv("GREPTIME_INGEST_VECTOR", "off")
-        legacy = parse_line_protocol(body.decode(), "ns")
+        legacy = parse_line_protocol_legacy(body.decode(), "ns")
         _assert_tables_equal(_norm(vec), _norm(legacy))
 
-    def test_fallback_counts_object_rows(self, monkeypatch):
-        monkeypatch.delenv("GREPTIME_INGEST_VECTOR", raising=False)
+    def test_fallback_counts_object_rows(self):
         before = REGISTRY.value(
             "greptime_ingest_object_decode_rows_total", ("influxdb",))
         parse_line_protocol(b'cpu value="quoted string" 1000000\n', "ns")
@@ -172,8 +167,8 @@ class TestLineProtocolParity:
         # comment + blank lines
         b"# a comment\n\ncpu,host=a usage=1 1000000\n",
     ])
-    def test_fallback_shapes_parity(self, monkeypatch, body):
-        vec, legacy = _parse_lp_both(monkeypatch, body)
+    def test_fallback_shapes_parity(self, body):
+        vec, legacy = _parse_lp_both(body)
         _assert_tables_equal(vec, legacy)
 
     @pytest.mark.parametrize("body", [
@@ -194,20 +189,19 @@ class TestLineProtocolParity:
         # no-tag lines
         b"m v=1 1000000\nm v=2 2000000\n",
     ])
-    def test_value_shapes_parity(self, monkeypatch, body):
-        vec, legacy = _parse_lp_both(monkeypatch, body)
+    def test_value_shapes_parity(self, body):
+        vec, legacy = _parse_lp_both(body)
         _assert_tables_equal(vec, legacy)
 
     @pytest.mark.parametrize("precision", ["ns", "us", "ms", "s"])
-    def test_precision_parity(self, monkeypatch, precision):
+    def test_precision_parity(self, precision):
         body = b"m,host=a v=1 1234567891\nm,host=b v=2 -987654321\n"
-        vec, legacy = _parse_lp_both(monkeypatch, body, precision)
+        vec, legacy = _parse_lp_both(body, precision)
         _assert_tables_equal(vec, legacy)
 
-    def test_errors_match_legacy(self, monkeypatch):
+    def test_errors_match_legacy(self):
         from greptimedb_tpu.errors import InvalidArguments
 
-        monkeypatch.delenv("GREPTIME_INGEST_VECTOR", raising=False)
         for bad in (b"cpu_no_fields 1000\n", b"cpu,tag v=1 1000\n"):
             with pytest.raises(InvalidArguments):
                 parse_line_protocol(bad, "ns")
@@ -218,7 +212,7 @@ class TestLineProtocolParity:
 # ---------------------------------------------------------------------------
 
 class TestRemoteWriteParity:
-    def test_parity_with_ragged_labels(self, monkeypatch):
+    def test_parity_with_ragged_labels(self):
         pb = _write_request([
             ({"__name__": "up", "job": "api", "pod": "pé1"},
              [(1.0, 1000), (0.0, 2000)]),
@@ -226,16 +220,13 @@ class TestRemoteWriteParity:
             ({"__name__": "lat", "job": "api"},
              [(0.25, 3000), (0.5, -500)]),
         ])
-        monkeypatch.delenv("GREPTIME_INGEST_VECTOR", raising=False)
         vec = _norm(parse_remote_write(pb))
-        monkeypatch.setenv("GREPTIME_INGEST_VECTOR", "off")
-        legacy = _norm(parse_remote_write(pb))
+        legacy = _norm(parse_remote_write_legacy(pb))
         _assert_tables_equal(vec, legacy)
         # ragged label sets fill with "" on both paths
         assert vec["up"]["pod"] == ["pé1", "pé1", ""]
 
-    def test_tag_columns_are_dictionary_coded(self, monkeypatch):
-        monkeypatch.delenv("GREPTIME_INGEST_VECTOR", raising=False)
+    def test_tag_columns_are_dictionary_coded(self):
         out = parse_remote_write(_write_request([
             ({"__name__": "up", "job": "api"}, [(1.0, i) for i in range(50)]),
             ({"__name__": "up", "job": "web"}, [(1.0, i) for i in range(50)]),
@@ -246,8 +237,10 @@ class TestRemoteWriteParity:
 
 
 class TestOtlpParity:
-    def test_parity(self, monkeypatch):
-        from greptimedb_tpu.servers.otlp import parse_otlp_metrics
+    def test_parity(self):
+        from greptimedb_tpu.servers.otlp import (
+            _assemble_legacy, _walk_otlp_metrics, parse_otlp_metrics,
+        )
 
         ts = 1700000000 * 10 ** 9
         pb = _otlp_gauge_request([
@@ -258,10 +251,8 @@ class TestOtlpParity:
              float("inf")),
             ("mem_usage", {"pod": "p1"}, ts, 1.5),
         ])
-        monkeypatch.delenv("GREPTIME_INGEST_VECTOR", raising=False)
         vec = _norm(parse_otlp_metrics(pb))
-        monkeypatch.setenv("GREPTIME_INGEST_VECTOR", "off")
-        legacy = _norm(parse_otlp_metrics(pb))
+        legacy = _norm(_assemble_legacy(_walk_otlp_metrics(pb)))
         _assert_tables_equal(vec, legacy)
         assert len(vec["cpu_usage"]["ts"]) == 3
 
@@ -278,17 +269,14 @@ class TestEndToEndParity:
         b"mem,host=a free=0.25 1000000000\n"
     )
 
-    def _ingest_and_dump(self, monkeypatch, off: bool):
+    def _ingest_and_dump(self, legacy: bool):
         from greptimedb_tpu.servers.http import _ingest_columns
 
-        if off:
-            monkeypatch.setenv("GREPTIME_INGEST_VECTOR", "off")
-        else:
-            monkeypatch.delenv("GREPTIME_INGEST_VECTOR", raising=False)
         db = GreptimeDB()
         try:
-            body = self.LP_BODY if not off else self.LP_BODY.decode()
-            for table, cols in parse_line_protocol(body, "ns").items():
+            tables = (parse_line_protocol_legacy(self.LP_BODY.decode(), "ns")
+                      if legacy else parse_line_protocol(self.LP_BODY, "ns"))
+            for table, cols in tables.items():
                 _ingest_columns(db, table, cols)
             dump = {}
             for t in ("cpu", "mem"):
@@ -298,9 +286,9 @@ class TestEndToEndParity:
         finally:
             db.close()
 
-    def test_sql_contents_identical(self, monkeypatch):
-        vec = self._ingest_and_dump(monkeypatch, off=False)
-        legacy = self._ingest_and_dump(monkeypatch, off=True)
+    def test_sql_contents_identical(self):
+        vec = self._ingest_and_dump(legacy=False)
+        legacy = self._ingest_and_dump(legacy=True)
         assert set(vec) == set(legacy)
         for t in vec:
             assert vec[t][0] == legacy[t][0]
@@ -572,22 +560,41 @@ class TestArrowBulkParity:
             "ok": np.array([True, False, True]),
         })
 
-    def _dump(self, monkeypatch, body, off: bool, table="m"):
+    @staticmethod
+    def _object_columns(body):
+        """The plain reference decode: every column a list of Python
+        objects (None for null), ts as epoch ms, tags by arrow type."""
+        import pyarrow as pa
+
+        table = pa.ipc.open_stream(body).read_all()
+        cols, tags, fields = {}, [], []
+        for name in table.column_names:
+            col = table.column(name)
+            if name == "ts":
+                if pa.types.is_timestamp(col.type):
+                    col = col.cast(pa.timestamp("ms")).cast(pa.int64())
+                cols[name] = col.to_pylist()
+                continue
+            stringish = (pa.types.is_dictionary(col.type)
+                         or pa.types.is_string(col.type))
+            (tags if stringish else fields).append(name)
+            cols[name] = col.to_pylist()
+        cols["__tags__"] = sorted(tags)
+        cols["__fields__"] = sorted(fields)
+        return cols
+
+    def _dump(self, body, reference: bool, table="m"):
         from greptimedb_tpu.servers.http import _ingest_columns
         from greptimedb_tpu.servers.protocols import parse_arrow_bulk
 
-        if off:
-            monkeypatch.setenv("GREPTIME_INGEST_VECTOR", "off")
-        else:
-            monkeypatch.delenv("GREPTIME_INGEST_VECTOR", raising=False)
         db = GreptimeDB()
         try:
-            _ingest_columns(db, table, parse_arrow_bulk(body))
+            _ingest_columns(db, table, self._object_columns(body)
+                            if reference else parse_arrow_bulk(body))
             res = db.sql(f"SELECT * FROM {table} ORDER BY ts")
             return res.column_names, res.rows
         finally:
             db.close()
-            monkeypatch.delenv("GREPTIME_INGEST_VECTOR", raising=False)
 
     def _assert_rows_equal(self, vec, legacy):
         assert vec[0] == legacy[0]
@@ -599,26 +606,24 @@ class TestArrowBulkParity:
                     continue
                 assert x == y, (vec, legacy)
 
-    def test_sql_contents_identical_and_decode_pin(self, monkeypatch):
+    def test_sql_contents_identical_and_decode_pin(self):
         from greptimedb_tpu.servers.protocols import parse_arrow_bulk
 
         body = self._mixed_body()
         d0 = REGISTRY.value("greptime_ingest_object_decode_rows_total",
                             ("arrow",))
-        vec = self._dump(monkeypatch, body, off=False)
+        vec = self._dump(body, reference=False)
         # the null-free mixed-type body never touches the object path
         assert REGISTRY.value("greptime_ingest_object_decode_rows_total",
                               ("arrow",)) == d0
-        legacy = self._dump(monkeypatch, body, off=True)
-        assert REGISTRY.value("greptime_ingest_object_decode_rows_total",
-                              ("arrow",)) == d0 + 3
+        legacy = self._dump(body, reference=True)
         self._assert_rows_equal(vec, legacy)
-        # tags classified from arrow types, identically on both paths
+        # tags classified from arrow types
         cols = parse_arrow_bulk(body)
         assert cols["__tags__"] == ["dc", "hostname"]
         assert cols["__fields__"] == ["count", "ok", "usage"]
 
-    def test_null_columns_take_object_path_with_parity(self, monkeypatch):
+    def test_null_columns_take_object_path_with_parity(self):
         import pyarrow as pa
 
         body = _ipc({
@@ -629,17 +634,17 @@ class TestArrowBulkParity:
         })
         d0 = REGISTRY.value("greptime_ingest_object_decode_rows_total",
                             ("arrow",))
-        vec = self._dump(monkeypatch, body, off=False)
+        vec = self._dump(body, reference=False)
         assert REGISTRY.value("greptime_ingest_object_decode_rows_total",
                               ("arrow",)) == d0 + 3
-        legacy = self._dump(monkeypatch, body, off=True)
+        legacy = self._dump(body, reference=True)
         self._assert_rows_equal(vec, legacy)
         # None survived to NULL (floats NaN→NULL; null tags render '')
         names, rows = vec
         assert rows[1][names.index("v")] is None
         assert rows[1][names.index("host")] == ""
 
-    def test_null_dictionary_vocab_entry(self, monkeypatch):
+    def test_null_dictionary_vocab_entry(self):
         import pyarrow as pa
 
         dic = pa.DictionaryArray.from_arrays(
@@ -647,13 +652,13 @@ class TestArrowBulkParity:
             pa.array(["x", None]))
         body = _ipc({"tag": dic, "ts": np.array([1, 2, 3], dtype=np.int64),
                      "v": np.array([1.0, 2.0, 3.0])})
-        vec = self._dump(monkeypatch, body, off=False)
-        legacy = self._dump(monkeypatch, body, off=True)
+        vec = self._dump(body, reference=False)
+        legacy = self._dump(body, reference=True)
         self._assert_rows_equal(vec, legacy)
         # row 2's vocab entry is null → NULL tag renders '' on both paths
         assert vec[1][1][vec[0].index("tag")] == ""
 
-    def test_timestamp_typed_ts(self, monkeypatch):
+    def test_timestamp_typed_ts(self):
         import pyarrow as pa
 
         body = _ipc({
@@ -661,8 +666,8 @@ class TestArrowBulkParity:
             "ts": pa.array([1_000_000, 2_000_000], type=pa.timestamp("us")),
             "v": np.array([1.0, 2.0]),
         })
-        vec = self._dump(monkeypatch, body, off=False)
-        legacy = self._dump(monkeypatch, body, off=True)
+        vec = self._dump(body, reference=False)
+        legacy = self._dump(body, reference=True)
         self._assert_rows_equal(vec, legacy)
         assert [r[1] for r in vec[1]] == [1000, 2000]  # us → ms
 

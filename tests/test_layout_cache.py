@@ -3,14 +3,12 @@
 The derived-layout path (query/physical.py _aligned_layout +
 storage/cache.py DerivedLayoutCache) must be invisible except for speed:
 every test here pins its results against BOTH the dynamic-slice grid
-kernel (GREPTIME_LAYOUT_CACHE=off) and the row-oriented DeviceTable path
-(GREPTIME_GRID=off).  Layout-vs-dynamic-slice parity is asserted EXACTLY
-(the cached partials are the same f32 ``reshape @ ones[r]`` contraction
+kernel (conftest's ``ineligible("layout")``) and the row-oriented
+DeviceTable path (``ineligible("grid")``).  Layout-vs-dynamic-slice
+parity is asserted EXACTLY (the cached partials are the same f32 ``reshape @ ones[r]`` contraction
 over identical r-element blocks); grid-vs-row parity keeps the usual f32
 accumulation tolerance.
 """
-
-import os
 
 import numpy as np
 import pytest
@@ -35,19 +33,6 @@ def _rows(res):
     )
 
 
-def _run_env(db, sql, **env):
-    old = {k: os.environ.get(k) for k in env}
-    os.environ.update(env)
-    try:
-        return db.sql(sql)
-    finally:
-        for k, v in old.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-
-
 def _assert_exact(a, b, ctx):
     assert len(a) == len(b) and len(a) > 0, (len(a), len(b), ctx)
     for ra, rb in zip(a, b):
@@ -65,20 +50,29 @@ def _assert_close(a, b, ctx):
                 assert va == vb, f"{va} vs {vb}: {ctx}"
 
 
-def run_layout_query(db, sql, expect_layout=True):
-    """Run ``sql`` through the layout path and pin it against the
-    dynamic-slice and row paths.  Returns the layout-path result."""
-    before = DISPATCH_STATS["grid_bm"]
-    r_bm = db.sql(sql)
-    used = DISPATCH_STATS["grid_bm"] > before
-    assert used == expect_layout, (
-        f"bucket_major used={used}, expected {expect_layout}: {sql}")
-    r_ds = _run_env(db, sql, GREPTIME_LAYOUT_CACHE="off")
-    r_row = _run_env(db, sql, GREPTIME_GRID="off")
-    assert r_bm.column_names == r_ds.column_names == r_row.column_names
-    _assert_exact(_rows(r_bm), _rows(r_ds), f"bm vs dynamic_slice: {sql}")
-    _assert_close(_rows(r_bm), _rows(r_row), f"bm vs row: {sql}")
-    return r_bm
+@pytest.fixture
+def run_layout_query(ineligible):
+    def run_layout_query(db, sql, expect_layout=True):
+        """Run ``sql`` through the layout path and pin it against the
+        dynamic-slice and row paths.  Returns the layout-path result."""
+        before = DISPATCH_STATS["grid_bm"]
+        r_bm = db.sql(sql)
+        used = DISPATCH_STATS["grid_bm"] > before
+        assert used == expect_layout, (
+            f"bucket_major used={used}, expected {expect_layout}: {sql}")
+        before = DISPATCH_STATS["grid_bm"]
+        with ineligible("layout"):
+            r_ds = db.sql(sql)
+        with ineligible("grid"):
+            r_row = db.sql(sql)
+        assert DISPATCH_STATS["grid_bm"] == before, "a reference ran bm"
+        assert r_bm.column_names == r_ds.column_names == r_row.column_names
+        _assert_exact(_rows(r_bm), _rows(r_ds),
+                      f"bm vs dynamic_slice: {sql}")
+        _assert_close(_rows(r_bm), _rows(r_row), f"bm vs row: {sql}")
+        return r_bm
+
+    return run_layout_query
 
 
 @pytest.fixture
@@ -102,7 +96,7 @@ def db(tmp_path):
     d.close()
 
 
-def test_warm_queries_hit_the_layout(db):
+def test_warm_queries_hit_the_layout(db, run_layout_query):
     lc = db.engine.executor.layout_cache
     run_layout_query(db, ALIGNED_SQL)
     assert lc.builds == 1 and len(lc) == 1
@@ -113,7 +107,7 @@ def test_warm_queries_hit_the_layout(db):
     assert r.num_rows == 6 * 10
 
 
-def test_rolling_window_reuses_the_layout(db):
+def test_rolling_window_reuses_the_layout(db, run_layout_query):
     lc = db.engine.executor.layout_cache
     run_layout_query(db, ALIGNED_SQL)
     builds0 = lc.builds
@@ -125,13 +119,13 @@ def test_rolling_window_reuses_the_layout(db):
     assert lc.builds == builds0 and lc.hits > 0
 
 
-def test_tag_only_where_rides_the_layout(db):
+def test_tag_only_where_rides_the_layout(db, run_layout_query):
     sql = ALIGNED_SQL.replace("GROUP BY", "AND dc = 'dc0' GROUP BY")
     r = run_layout_query(db, sql)
     assert r.num_rows == 3 * 10  # dc0 = h0, h2, h4
 
 
-def test_unaligned_window_falls_back_identical(db):
+def test_unaligned_window_falls_back_identical(db, run_layout_query):
     # window start off the minute boundary: dynamic-slice path serves it
     sql = ALIGNED_SQL.replace(str(ALIGNED_LO), str(ALIGNED_LO + 7000))
     before = DISPATCH_STATS["grid"]
@@ -139,12 +133,12 @@ def test_unaligned_window_falls_back_identical(db):
     assert DISPATCH_STATS["grid"] > before  # still the grid executor
 
 
-def test_minmax_falls_back(db):
+def test_minmax_falls_back(db, run_layout_query):
     sql = ALIGNED_SQL.replace("avg(usage)", "max(usage)")
     run_layout_query(db, sql, expect_layout=False)
 
 
-def test_ingest_invalidates_the_stale_layout(db):
+def test_ingest_invalidates_the_stale_layout(db, run_layout_query):
     lc = db.engine.executor.layout_cache
     # wide aligned window whose last bucket still has grid headroom
     wide = (
@@ -168,7 +162,7 @@ def test_ingest_invalidates_the_stale_layout(db):
     assert changed[0][0] == "h0"
 
 
-def test_budget_reject_falls_back_identical(db):
+def test_budget_reject_falls_back_identical(db, run_layout_query):
     lc = db.engine.executor.layout_cache
     run_layout_query(db, ALIGNED_SQL)
     # tightened budget: admission pressure reclaims the resident layout
@@ -186,7 +180,7 @@ def test_budget_reject_falls_back_identical(db):
         lc.capacity = old_cap
 
 
-def test_workload_quota_reject_falls_back(db):
+def test_workload_quota_reject_falls_back(db, run_layout_query):
     # the utils/memory.py integration: a 1-byte workload quota rejects
     # the build through the memory probe; results stay correct
     run_layout_query(db, ALIGNED_SQL)
@@ -205,7 +199,7 @@ def test_workload_quota_reject_falls_back(db):
     assert lc.builds == builds0 + 1
 
 
-def test_overquota_build_does_not_thrash_warm_entries(db):
+def test_overquota_build_does_not_thrash_warm_entries(db, run_layout_query):
     # a build that can NEVER fit the workload quota must reject without
     # draining the warm entries (reclaim would evict everything and
     # still reject — pure thrash)
@@ -226,7 +220,7 @@ def test_overquota_build_does_not_thrash_warm_entries(db):
         db.memory.set_quota("layout_cache", None)
 
 
-def test_lru_eviction_across_step_classes(db):
+def test_lru_eviction_across_step_classes(db, run_layout_query):
     lc = db.engine.executor.layout_cache
     run_layout_query(db, ALIGNED_SQL)
     entry_bytes = lc.bytes
@@ -246,7 +240,7 @@ def test_lru_eviction_across_step_classes(db):
     assert len(lc) == 1
 
 
-def test_grid_lru_eviction_drops_layouts(db):
+def test_grid_lru_eviction_drops_layouts(db, run_layout_query):
     # a grid evicted under RegionCacheManager capacity pressure strands
     # its derived layouts (next build = new dicts_version, so they can
     # never hit) — eviction must drop them too
@@ -260,7 +254,7 @@ def test_grid_lru_eviction_drops_layouts(db):
     run_layout_query(db, ALIGNED_SQL)
 
 
-def test_drop_table_frees_the_layout(db):
+def test_drop_table_frees_the_layout(db, run_layout_query):
     lc = db.engine.executor.layout_cache
     run_layout_query(db, ALIGNED_SQL)
     assert lc.bytes > 0

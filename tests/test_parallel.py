@@ -151,9 +151,7 @@ class TestMeshSql:
     (round-2/3 verdict: the mesh must be reachable from GreptimeDB.sql,
     reference src/query/src/dist_plan/merge_scan.rs:210,335)."""
 
-    def test_north_star_sql_on_mesh(self, tmp_path):
-        import os
-
+    def test_north_star_sql_on_mesh(self, tmp_path, ineligible):
         from greptimedb_tpu.standalone import GreptimeDB
 
         db = GreptimeDB(str(tmp_path / "m"))
@@ -171,11 +169,8 @@ class TestMeshSql:
         r_mesh = db.sql(sql)
         gt, _ = db.grid_table("cpu", None)
         assert gt is not None and "shard" in str(gt.values.sharding)
-        os.environ["GREPTIME_GRID"] = "off"
-        try:
+        with ineligible("grid"):
             r_row = db.sql(sql)
-        finally:
-            os.environ.pop("GREPTIME_GRID", None)
         key = lambda r: (r[0], r[1])
         a, b = sorted(r_mesh.rows, key=key), sorted(r_row.rows, key=key)
         assert len(a) == len(b) == 48

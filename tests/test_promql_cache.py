@@ -2,7 +2,7 @@
 
 The cache must be invisible except for speed: every parity test pins the
 cached (warm, resident selection/sort/group state) evaluation BIT-EXACT
-against GREPTIME_PROMQL_CACHE=off — both serve from the identical
+against an evaluator on a db without ``promql_cache`` — both serve from the identical
 transient-build code path, so equality is structural, not tolerance-based.
 Invalidation tests prove the generation discipline: data appends rebuild
 the resident sort layout (dicts_version), registry growth rebuilds the
@@ -11,7 +11,6 @@ label materialization to O(output groups) so the round-5 O(series) host
 loop cannot silently regress.
 """
 
-import os
 
 import numpy as np
 import pytest
@@ -58,16 +57,21 @@ def eval_q(db, query, start=300, end=300, step=60):
     return np.asarray(res.values), list(res.labels), ev
 
 
+class _NoPromCache:
+    """The db as a frontend handle shows it: everything but the resident
+    ``promql_cache``, so the evaluator builds its state transiently."""
+
+    def __init__(self, db):
+        self._db = db
+
+    def __getattr__(self, name):
+        if name == "promql_cache":
+            raise AttributeError(name)
+        return getattr(self._db, name)
+
+
 def eval_uncached(db, query, **kw):
-    old = os.environ.get("GREPTIME_PROMQL_CACHE")
-    os.environ["GREPTIME_PROMQL_CACHE"] = "off"
-    try:
-        return eval_q(db, query, **kw)
-    finally:
-        if old is None:
-            os.environ.pop("GREPTIME_PROMQL_CACHE", None)
-        else:
-            os.environ["GREPTIME_PROMQL_CACHE"] = old
+    return eval_q(_NoPromCache(db), query, **kw)
 
 
 PARITY_QUERIES = [

@@ -1,7 +1,7 @@
 """Streaming cold-scan pipeline tests (storage/scan.py).
 
 Pins the round-10 invariants: bit-exact parity of the parallel decode +
-sorted-run merge against the sequential forced-lexsort reference
+sorted-run merge against the sequential lexsort reference
 (tombstones, ALTER-added columns, overlapping sequences across SSTs),
 the single-source / disjoint-run fast paths, quota reject-to-sequential
 fallback, the thread-count knob, the grid catch-up build, the S3
@@ -14,6 +14,7 @@ import threading
 import time
 
 import numpy as np
+import pytest
 
 from greptimedb_tpu.datatypes import (
     ColumnSchema,
@@ -74,20 +75,24 @@ def assert_same_columns(a, b):
             assert np.array_equal(va, vb), k
 
 
-def scan_ab(monkeypatch, region, **kw):
-    """(sequential forced-lexsort, pipelined) scan outputs."""
-    monkeypatch.setenv("GREPTIME_SCAN_THREADS", "1")
-    monkeypatch.setenv("GREPTIME_SCAN_FORCE_LEXSORT", "1")
-    seq = region.scan_host(**kw)
-    monkeypatch.delenv("GREPTIME_SCAN_THREADS")
-    monkeypatch.delenv("GREPTIME_SCAN_FORCE_LEXSORT")
-    par = region.scan_host(**kw)
-    return seq, par
+@pytest.fixture
+def scan_ab(monkeypatch, ineligible):
+    def scan_ab(region, **kw):
+        """(sequential lexsort, pipelined) scan outputs."""
+        monkeypatch.setenv("GREPTIME_SCAN_THREADS", "1")
+        with ineligible("packed_merge"):
+            seq = region.scan_host(**kw)
+            assert scanmod.LAST_MERGE_PATH in ("lexsort", "empty")
+        monkeypatch.delenv("GREPTIME_SCAN_THREADS")
+        par = region.scan_host(**kw)
+        return seq, par
+
+    return scan_ab
 
 
 class TestParity:
     def test_multi_sst_overlapping_seqs_tombstones_alter(
-        self, tmp_path, monkeypatch
+        self, tmp_path, scan_ab
     ):
         """The kitchen-sink parity case: upserts across SSTs (overlapping
         (series, ts) keys with different sequences), delete tombstones in
@@ -114,11 +119,11 @@ class TestParity:
         write_batch(r, ["h1"], t0=120_000, n=5)  # live memtable rows
         assert len(r.sst_files) == 5
 
-        seq, par = scan_ab(monkeypatch, r)
+        seq, par = scan_ab(r)
         assert_same_columns(seq, par)
         assert len(par["ts"]) > 0
         # restricted ranges + column projection parity too
-        seq, par = scan_ab(monkeypatch, r, ts_range=(1000, 60_000),
+        seq, par = scan_ab(r, ts_range=(1000, 60_000),
                            columns=["hostname", "usage"])
         assert_same_columns(seq, par)
         eng.close()
@@ -162,7 +167,7 @@ class TestMergePaths:
         assert scanmod.LAST_MERGE_PATH == "concat"
         eng.close()
 
-    def test_disjoint_runs_merge_not_lexsort(self, tmp_path, monkeypatch):
+    def test_disjoint_runs_merge_not_lexsort(self, tmp_path, scan_ab):
         """Multi-series TWCS-style time-disjoint SSTs take the sorted-run
         merge, and its output is bit-exact with forced lexsort."""
         eng, r = make_region(tmp_path)
@@ -170,21 +175,27 @@ class TestMergePaths:
             write_batch(r, ["h0", "h1", "h2", "h3"], t0=i * 1_000_000, n=40)
             r.flush()
         c0 = REGISTRY.value("greptime_scan_merge_total", ("merge",))
-        seq, par = scan_ab(monkeypatch, r)
+        seq, par = scan_ab(r)
         assert scanmod.LAST_MERGE_PATH == "merge"
         assert REGISTRY.value("greptime_scan_merge_total", ("merge",)) > c0
         assert_same_columns(seq, par)
         eng.close()
 
-    def test_forced_lexsort_knob(self, tmp_path, monkeypatch):
-        eng, r = make_region(tmp_path)
-        for i in range(3):
-            write_batch(r, ["h0", "h1"], t0=i * 1_000_000, n=10)
-            r.flush()
-        monkeypatch.setenv("GREPTIME_SCAN_FORCE_LEXSORT", "1")
-        r.scan_host()
-        assert scanmod.LAST_MERGE_PATH == "lexsort"
-        eng.close()
+    def test_unpackable_keys_take_lexsort(self):
+        """Keys too wide for 62 bits fall to the global lexsort, which
+        gives the same order as the stable reference."""
+        parts = [
+            {"ts": np.array([1 << 61, 0], dtype=np.int64),
+             "tsid": np.array([3, 3], dtype=np.int64),
+             "seq": np.array([0, 1], dtype=np.int64)},
+            {"ts": np.array([5], dtype=np.int64),
+             "tsid": np.array([1], dtype=np.int64),
+             "seq": np.array([2], dtype=np.int64)},
+        ]
+        got, path = scanmod.merge_parts(parts, "ts", "tsid", "seq")
+        assert path == "lexsort"
+        assert got["tsid"].tolist() == [1, 3, 3]
+        assert got["ts"].tolist() == [5, 0, 1 << 61]
 
     def test_merge_parts_fuzz_vs_lexsort(self):
         """Random sorted/unsorted parts: every strategy must reproduce
@@ -304,7 +315,8 @@ class TestObjectDecodeGuard:
 
 
 class TestCompaction:
-    def test_compact_parallel_parity(self, tmp_path, monkeypatch):
+    def test_compact_parallel_parity(self, tmp_path, monkeypatch,
+                                     ineligible):
         """Compaction through the parallel reader + sorted-run merge
         produces the same merged table as the sequential lexsort path."""
         def build(name):
@@ -321,10 +333,9 @@ class TestCompaction:
 
         eng_a, ra = build("a")
         monkeypatch.setenv("GREPTIME_SCAN_THREADS", "1")
-        monkeypatch.setenv("GREPTIME_SCAN_FORCE_LEXSORT", "1")
-        ra.compact()
+        with ineligible("packed_merge"):
+            ra.compact()
         monkeypatch.delenv("GREPTIME_SCAN_THREADS")
-        monkeypatch.delenv("GREPTIME_SCAN_FORCE_LEXSORT")
         eng_b, rb = build("b")
         rb.compact()
         assert len(ra.sst_files) == 1 and len(rb.sst_files) == 1

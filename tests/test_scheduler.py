@@ -2,9 +2,7 @@
 
 Covers the PR's acceptance surface: bit-exact batched-vs-solo parity,
 per-tenant quota rejection + fallback, priority ordering under a
-saturated (background-occupied) pool, deadline shedding, and the
-zero-overhead-disabled pin (GREPTIME_SCHEDULER=off ⇒ no serving
-allocations on the warm path).
+saturated (background-occupied) pool, and deadline shedding.
 
 Reference counterpart: the frontend's admission/flow-control surface
 (GreptimeDB limits concurrent queries per frontend and rejects with
@@ -578,75 +576,6 @@ class TestObservability:
         t.join(5)
         s.stop()
         assert seen, "queued entry never appeared in SHOW PROCESSLIST"
-
-
-# ---------------------------------------------------------------------------
-# Zero-overhead disabled
-# ---------------------------------------------------------------------------
-
-class TestDisabled:
-    def test_scheduler_off_restores_inline_path(self, monkeypatch):
-        monkeypatch.setenv("GREPTIME_SCHEDULER", "off")
-        d = GreptimeDB()
-        try:
-            assert d.scheduler is None
-            d.sql("CREATE TABLE t (h STRING, ts TIMESTAMP(3) TIME INDEX, "
-                  "v DOUBLE, PRIMARY KEY (h))")
-            d.sql("INSERT INTO t VALUES ('a', 1000, 1.0)")
-            warm_sql = "SELECT h, avg(v) FROM t GROUP BY h"
-            d.sql(warm_sql)  # warm
-            # no new allocations from serving/ on the warm path: trace
-            # allocations of a warm query and assert none originate in
-            # the serving package
-            import tracemalloc
-
-            tracemalloc.start()
-            d.sql(warm_sql)
-            snap = tracemalloc.take_snapshot()
-            tracemalloc.stop()
-            serving_allocs = [
-                st for st in snap.statistics("filename")
-                if "/serving/" in st.traceback[0].filename
-            ]
-            assert serving_allocs == [], serving_allocs
-        finally:
-            d.close()
-
-    def test_scheduler_off_server_calls_inline(self, monkeypatch):
-        """HTTP server with scheduler off keeps the single-worker inline
-        executor path (no submit pool is ever created)."""
-        monkeypatch.setenv("GREPTIME_SCHEDULER", "off")
-        from greptimedb_tpu.servers import HttpServer
-
-        d = GreptimeDB()
-        srv = HttpServer(d, port=0)
-        try:
-            srv.start()
-            import json
-            import urllib.request
-
-            with urllib.request.urlopen(
-                f"http://127.0.0.1:{srv.port}/v1/sql?sql=SELECT+1",
-                timeout=10,
-            ) as resp:
-                body = json.load(resp)
-            assert body["output"][0]["records"]["rows"] == [[1]]
-            assert srv._submit_pool is None
-        finally:
-            srv.stop()
-            d.close()
-
-    def test_off_knob_keeps_metrics_silent(self, monkeypatch):
-        monkeypatch.setenv("GREPTIME_SCHEDULER", "off")
-        d = GreptimeDB()
-        try:
-            before = REGISTRY.value("greptime_scheduler_executed_total",
-                                    ("interactive",))
-            d.sql("SELECT 1")
-            assert REGISTRY.value("greptime_scheduler_executed_total",
-                                  ("interactive",)) == before
-        finally:
-            d.close()
 
 
 # ---------------------------------------------------------------------------

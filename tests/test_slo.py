@@ -6,14 +6,10 @@ an injected clock — alerts fire during an induced storm and CLEAR once
 it passes; the idle economy's fairness invariants (weighted time split,
 greedy cannot starve the meek, the starvation bound guarantees
 liveness); exactly-one SLO accounting per scheduler entry including
-errors, sheds and caller-held (http) samples; and the
-``GREPTIME_SLO=off`` zero-overhead pin (module never imported, legacy
-idle dispatcher byte-for-byte).
+errors, sheds and caller-held (http) samples.
 """
 
 import math
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -360,8 +356,6 @@ class TestSchedulerAccounting:
         d.close()
 
     def test_every_submit_lands_in_exactly_one_sketch(self, db):
-        if db.scheduler is None or db.slo is None:
-            pytest.skip("scheduler/slo disabled in this config")
         base = db.slo.total_recorded()
         n_ok, n_err = 12, 3
         for i in range(n_ok):
@@ -373,8 +367,6 @@ class TestSchedulerAccounting:
         assert db.slo.total_recorded() == base + n_ok + n_err
 
     def test_held_sample_defers_to_the_caller(self, db):
-        if db.scheduler is None or db.slo is None:
-            pytest.skip("scheduler/slo disabled in this config")
         base = db.slo.total_recorded()
         hold = []
         db.scheduler.submit("SELECT count(v) FROM cpu", slo_hold=hold)
@@ -386,8 +378,6 @@ class TestSchedulerAccounting:
         assert hold == []  # drained: double-record impossible
 
     def test_error_with_hold_records_immediately(self, db):
-        if db.scheduler is None or db.slo is None:
-            pytest.skip("scheduler/slo disabled in this config")
         base = db.slo.total_recorded()
         hold = []
         with pytest.raises(Exception):
@@ -401,8 +391,6 @@ class TestSchedulerAccounting:
         from greptimedb_tpu.errors import ResourcesExhausted
         from greptimedb_tpu.utils.telemetry import REGISTRY
 
-        if db.scheduler is None or db.slo is None:
-            pytest.skip("scheduler/slo disabled in this config")
         db.slo.fast_burn_active = lambda: True
         try:
             with pytest.raises(ResourcesExhausted):
@@ -414,46 +402,9 @@ class TestSchedulerAccounting:
             del db.slo.fast_burn_active
 
     def test_slo_status_information_schema(self, db):
-        if db.slo is None:
-            pytest.skip("slo disabled in this config")
         db.scheduler.submit("SELECT count(v) FROM cpu")
         res = db.sql("SELECT tenant, class, protocol, total "
                      "FROM information_schema.slo_status")
         assert res.rows, "slo_status must render recorded keys"
         cols = dict(zip(res.column_names, zip(*res.rows)))
         assert "default" in cols["tenant"]
-
-
-class TestOffPin:
-    def test_slo_off_means_never_imported(self, tmp_path):
-        """GREPTIME_SLO=off: neither slo nor idle module loads, the
-        scheduler uses the legacy chained idle dispatcher, and queries
-        serve exactly as before."""
-        code = """
-import os, sys
-os.environ["GREPTIME_SLO"] = "off"
-os.environ["JAX_PLATFORMS"] = "cpu"
-from greptimedb_tpu.standalone import GreptimeDB
-d = GreptimeDB()
-assert d.slo is None and d.idle_economy is None
-d.sql("CREATE TABLE t (h STRING, ts TIMESTAMP TIME INDEX, v DOUBLE, "
-      "PRIMARY KEY(h))")
-d.sql("INSERT INTO t VALUES ('a', 1000, 1.0)")
-if d.scheduler is not None:
-    assert d.scheduler.slo is None
-    assert d.scheduler.idle_economy is None
-    r = d.scheduler.submit("SELECT count(v) FROM t")
-    assert r.rows[0][0] == 1
-    # the legacy chained dispatcher serves (two hooks mint the chain)
-    d.scheduler.add_idle_hook(lambda: False, kick=False)
-    d.scheduler.add_idle_hook(lambda: False, kick=False)
-    assert getattr(d.scheduler.idle_hook, "_gl_hooks", None) is not None
-assert "greptimedb_tpu.serving.slo" not in sys.modules
-assert "greptimedb_tpu.serving.idle" not in sys.modules
-d.close()
-print("OFF-PIN-OK")
-"""
-        out = subprocess.run([sys.executable, "-c", code],
-                             capture_output=True, text=True, timeout=240)
-        assert out.returncode == 0, out.stderr[-2000:]
-        assert "OFF-PIN-OK" in out.stdout

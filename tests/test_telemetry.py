@@ -211,7 +211,7 @@ class TestRegistryStaticCheck:
             assert required in REGISTRY._metrics, required
         # the fulltext fingerprint index: candidates/verified/matched
         # (false-positive ratio), selectivity, per-path query counts and
-        # resident bytes — the surface bench_logs.py reads
+        # resident bytes
         import greptimedb_tpu.fulltext.resident  # noqa: F401
 
         for required in (
@@ -226,8 +226,7 @@ class TestRegistryStaticCheck:
             assert required in REGISTRY._metrics, required
         # the SLO observatory + idle economy (serving/slo.py, serving/
         # idle.py): sketches, error budgets, burn rates, and the
-        # idle-grant ledger — the surface the self-monitor loop and
-        # bench_soak.py gate on
+        # idle-grant ledger — the surface the self-monitor loop gates on
         import greptimedb_tpu.serving.idle  # noqa: F401
         import greptimedb_tpu.serving.slo  # noqa: F401
 
@@ -419,15 +418,15 @@ class TestSpanTrees:
         db.sql("TQL EVAL (0, 10, '5s') sum by(h) (cpu)")
         names = {s["name"] for s in traced.drain()}
         # the fused chain (compile/fused.py) replaces the window-kernel +
-        # eager-reduce pair with ONE fused_kernel span; PLAN_FUSION=off
-        # (and every unfusable shape) keeps the window_kernel span
+        # eager-reduce pair with ONE fused_kernel span; every unfusable
+        # shape keeps the window_kernel span
         assert {"selection", "sort_layout", "group_agg",
                 "label_decode"} <= names
         assert "fused_kernel" in names or "window_kernel" in names
 
-    def test_promql_stage_spans_unfused(self, db, traced, monkeypatch):
-        monkeypatch.setenv("GREPTIME_PLAN_FUSION", "off")
-        db.sql("TQL EVAL (0, 10, '5s') sum by(h) (cpu)")
+    def test_promql_stage_spans_unfused(self, db, traced, ineligible):
+        with ineligible("fusion"):
+            db.sql("TQL EVAL (0, 10, '5s') sum by(h) (cpu)")
         names = {s["name"] for s in traced.drain()}
         assert {"selection", "sort_layout", "window_kernel", "group_agg",
                 "label_decode"} <= names
