@@ -8,6 +8,7 @@ external deps — see greptime_native.cpp).
 from __future__ import annotations
 
 import ctypes
+import json
 import os
 import subprocess
 
@@ -104,6 +105,20 @@ def lib():
             ]
         except AttributeError:
             l._gt_no_json = True
+        # a library built before the PromQL matrix encoder keeps the rest
+        try:
+            l.gt_json_matrix_bound.restype = ctypes.c_size_t
+            l.gt_json_matrix_bound.argtypes = [
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
+            ]
+            l.gt_json_matrix.restype = ctypes.c_size_t
+            l.gt_json_matrix.argtypes = [
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+                ctypes.c_char_p, ctypes.c_void_p, ctypes.c_int64,
+                ctypes.c_int64, ctypes.c_void_p,
+            ]
+        except AttributeError:
+            l._gt_no_matrix = True
         _LIB = l
     except OSError:
         _LIB = None
@@ -171,6 +186,26 @@ def wal_find_boundary(buf: bytes, start: int) -> int | None:
     return None if off < 0 else int(off)
 
 
+# stands in a reply's envelope where natively encoded bytes go; a client
+# or a label cannot guess it
+_SLOT = f"slot:{os.urandom(12).hex()}"
+
+
+def json_around(body: dict, holder: dict, key: str, encoded) -> bytes | None:
+    """``json.dumps(body)`` as bytes with ``encoded`` (what ``json_rows``
+    or ``json_matrix`` wrote) in the place of ``holder[key]``, a dict
+    inside ``body`` that is left as it was.  None where the body holds
+    the slot's own text besides: the caller keeps ``json.dumps``."""
+    kept, holder[key] = holder[key], _SLOT
+    try:
+        parts = json.dumps(body).split(f'"{_SLOT}"')
+    finally:
+        holder[key] = kept
+    if len(parts) != 2:
+        return None
+    return b"".join((parts[0].encode(), encoded, parts[1].encode()))
+
+
 def json_rows(columns) -> memoryview | None:
     """The ``rows`` array of a /v1/sql reply, row-major, from whole numpy
     columns of one length: the bytes ``json.dumps`` gives for the same
@@ -214,4 +249,38 @@ def json_rows(columns) -> memoryview | None:
         held.append(data)
     out = np.empty(l.gt_json_rows_bound(cols, len(columns), n), np.uint8)
     size = l.gt_json_rows(cols, len(columns), n, out.ctypes.data)
+    return memoryview(out)[:size]
+
+
+def json_matrix(values, step_seconds, metrics: list[bytes]
+                ) -> memoryview | None:
+    """The ``result`` array of a Prometheus matrix reply from whole
+    arrays: ``values`` float64 [S, T] (any row stride), ``step_seconds``
+    float64 [T], ``metrics`` the JSON text of each series' ``metric``
+    object.  The bytes ``json.dumps`` gives for the list of
+    ``{"metric": ..., "values": [[t, repr(v)], ...]}`` (promql/format.py
+    ``MatrixSeries.to_list``).  None when the library or the symbol is
+    missing, or a step is not finite: the caller keeps ``json.dumps``."""
+    l = lib()
+    if l is None or getattr(l, "_gt_no_matrix", False):
+        return None
+    import numpy as np
+
+    nseries, nsteps = values.shape
+    if (values.dtype != np.float64 or len(metrics) != nseries
+            or step_seconds.shape != (nsteps,)
+            or not np.isfinite(step_seconds).all()):
+        return None
+    if (values.strides[1] != values.itemsize
+            or values.strides[0] % values.itemsize):
+        values = np.ascontiguousarray(values)
+    steps = np.ascontiguousarray(step_seconds, dtype=np.float64)
+    offsets = np.zeros(nseries + 1, np.int64)
+    np.cumsum([len(m) for m in metrics], out=offsets[1:])
+    out = np.empty(l.gt_json_matrix_bound(offsets.ctypes.data, nseries,
+                                          nsteps), np.uint8)
+    size = l.gt_json_matrix(
+        values.ctypes.data, values.strides[0] // values.itemsize,
+        steps.ctypes.data, b"".join(metrics), offsets.ctypes.data,
+        nseries, nsteps, out.ctypes.data)
     return memoryview(out)[:size]

@@ -429,6 +429,102 @@ size_t gt_json_rows(const GtJsonCol* cols, int32_t ncols, int64_t nrows,
   return static_cast<size_t>(p - out);
 }
 
+// ---------------------------------------------------------------------------
+// The "result" array of a Prometheus matrix (promql/format.py
+// MatrixSeries), from the [nseries, nsteps] values: the bytes json.dumps
+// gives for [{"metric": {...}, "values": [[t, "v"], ...]}, ...] with t a
+// float and v the sample's repr in quotes ("+Inf" and "-Inf" for the
+// infinities).  A NaN is no sample and a series of nothing but NaN no
+// series.  metrics holds each series' "metric" object as JSON text, one
+// after the other, split at metric_offsets[nseries + 1].
+// ---------------------------------------------------------------------------
+
+// what a point takes at most: , _ [ t , _ " (its head: 28 with a t of
+// 24) and v " ] (26 with a v of 24)
+static const size_t GT_MATRIX_HEAD = 28;
+static const size_t GT_MATRIX_POINT = 2 + GT_MATRIX_HEAD + 26;
+
+// An upper bound of what gt_json_matrix writes, and behind it the room
+// where it lays out the steps' heads (nsteps * GT_MATRIX_POINT bytes).
+size_t gt_json_matrix_bound(const int64_t* metric_offsets, int64_t nseries,
+                            int64_t nsteps) {
+  size_t steps = static_cast<size_t>(nsteps) * GT_MATRIX_POINT;
+  // , _ {"metric": ..., "values": [...]} is 28 bytes around a series
+  return 2 + static_cast<size_t>(nseries) * (28 + steps)
+         + static_cast<size_t>(metric_offsets[nseries] - metric_offsets[0])
+         + steps;
+}
+
+// values: row s starts at values + s * row_stride doubles; step_seconds
+// are finite.  Writes into out (at least gt_json_matrix_bound bytes) and
+// returns the array's length.
+size_t gt_json_matrix(const double* values, int64_t row_stride,
+                      const double* step_seconds, const char* metrics,
+                      const int64_t* metric_offsets, int64_t nseries,
+                      int64_t nsteps, char* out) {
+  // every series opens its point at step t with the same text, [t, " :
+  // printed once a step, behind what the bound leaves for the array
+  char* heads = out + gt_json_matrix_bound(metric_offsets, nseries, nsteps)
+                - static_cast<size_t>(nsteps) * GT_MATRIX_POINT;
+  uint8_t* head_len = reinterpret_cast<uint8_t*>(heads)
+                      + static_cast<size_t>(nsteps) * GT_MATRIX_HEAD;
+  for (int64_t t = 0; t < nsteps; t++) {
+    char* h = heads + t * GT_MATRIX_HEAD;
+    char* q = h;
+    *q++ = '[';
+    q = json_double(q, step_seconds[t]);
+    *q++ = ',';
+    *q++ = ' ';
+    *q++ = '"';
+    head_len[t] = static_cast<uint8_t>(q - h);
+  }
+  char* p = out;
+  *p++ = '[';
+  bool first_series = true;
+  for (int64_t s = 0; s < nseries; s++) {
+    const double* row = values + s * row_stride;
+    int64_t t = 0;
+    while (t < nsteps && row[t] != row[t]) t++;
+    if (t == nsteps) continue;
+    if (!first_series) {
+      *p++ = ',';
+      *p++ = ' ';
+    }
+    first_series = false;
+    memcpy(p, "{\"metric\": ", 11);
+    p += 11;
+    size_t mlen = static_cast<size_t>(metric_offsets[s + 1]
+                                      - metric_offsets[s]);
+    memcpy(p, metrics + metric_offsets[s], mlen);
+    p += mlen;
+    memcpy(p, ", \"values\": [", 13);
+    p += 13;
+    for (bool first = true; t < nsteps; t++) {
+      double v = row[t];
+      if (v != v) continue;
+      if (!first) {
+        *p++ = ',';
+        *p++ = ' ';
+      }
+      first = false;
+      memcpy(p, heads + t * GT_MATRIX_HEAD, head_len[t]);
+      p += head_len[t];
+      if (v - v != 0) {
+        memcpy(p, v < 0 ? "-Inf" : "+Inf", 4);
+        p += 4;
+      } else {
+        p = json_double(p, v);
+      }
+      *p++ = '"';
+      *p++ = ']';
+    }
+    *p++ = ']';
+    *p++ = '}';
+  }
+  *p++ = ']';
+  return static_cast<size_t>(p - out);
+}
+
 #endif  // __cpp_lib_to_chars
 
 }  // extern "C"

@@ -14,7 +14,7 @@ import time
 
 import pyarrow.flight as fl
 
-from greptimedb_tpu.promql.format import evaluate
+from greptimedb_tpu.promql.format import evaluate, payload_body
 
 
 class PromGatewayServer(fl.FlightServerBase):
@@ -48,7 +48,7 @@ class PromGatewayServer(fl.FlightServerBase):
         except Exception as e:  # noqa: BLE001 — prom error envelope
             payload = {"status": "error", "errorType": "bad_data",
                        "error": str(e)}
-        yield fl.Result(json.dumps(payload).encode())
+        yield fl.Result(payload_body(payload)[0])
 
 
 def prom_query(address: str, query: str, **params) -> dict:

@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import asyncio
 import json
-import os
 import re
 import threading
 import time
@@ -34,6 +33,7 @@ from greptimedb_tpu import native
 from greptimedb_tpu.errors import (
     GreptimeError, InvalidArguments, StatusCode, TableNotFound,
 )
+from greptimedb_tpu.promql import format as prom_format
 from greptimedb_tpu.query.engine import ColumnRows, QueryResult
 from greptimedb_tpu.utils import telemetry
 from greptimedb_tpu.utils.snappy import decompress as snappy_decompress
@@ -48,7 +48,8 @@ M_LATENCY = telemetry.REGISTRY.histogram(
     "greptime_http_request_duration_seconds", "HTTP latency", ("path",)
 )
 # how a reply's body was built: "columns" is the native encoder over
-# whole columns, "rows" json.dumps over a list of rows (_json_reply)
+# whole columns or arrays, "rows" json.dumps over a list of rows or points
+# (_json_reply, _prom_reply)
 M_REPLY_ENCODED = telemetry.REGISTRY.counter(
     "greptime_http_reply_encoded_total", "Reply bodies by encoder",
     ("route", "encoder")
@@ -111,11 +112,6 @@ def _result_to_json(res: QueryResult, t0: float) -> dict:
     }
 
 
-# stands in the envelope where the natively encoded rows go; a client
-# cannot guess it, and a reply that holds it twice takes json.dumps
-_ROWS_SLOT = f"rows:{os.urandom(12).hex()}"
-
-
 def _json_reply(body: dict, route: str, headers: dict | None = None
                 ) -> web.Response:
     """The response for what ``_result_to_json`` returned.  Where its
@@ -129,18 +125,27 @@ def _json_reply(body: dict, route: str, headers: dict | None = None
     if isinstance(rows, ColumnRows):
         encoded = native.json_rows(rows.columns)
         if encoded is not None:
-            records["rows"] = _ROWS_SLOT
-            parts = json.dumps(body).split(f'"{_ROWS_SLOT}"')
-            if len(parts) == 2:
-                M_REPLY_ENCODED.labels(route, "columns").inc()
-                return web.Response(
-                    body=b"".join((parts[0].encode(), encoded,
-                                   parts[1].encode())),
-                    content_type="application/json", charset="utf-8",
-                    headers=headers)
+            encoded = native.json_around(body, records, "rows", encoded)
+        if encoded is not None:
+            M_REPLY_ENCODED.labels(route, "columns").inc()
+            return web.Response(
+                body=encoded, content_type="application/json",
+                charset="utf-8", headers=headers)
         records["rows"] = rows.to_rows()
     M_REPLY_ENCODED.labels(route, "rows").inc()
     return web.json_response(body, headers=headers)
+
+
+def _prom_reply(payload: dict, route: str, headers: dict | None = None
+                ) -> web.Response:
+    """The response for a PromQL payload, as ``_json_reply`` is for a SQL
+    result: a matrix whose ``result`` is still the untouched array holder
+    is written by the native encoder (promql/format.py ``payload_body``),
+    anything else by ``json.dumps``, and the counter says which."""
+    body, encoder = prom_format.payload_body(payload)
+    M_REPLY_ENCODED.labels(route, encoder).inc()
+    return web.Response(body=body, content_type="application/json",
+                        charset="utf-8", headers=headers)
 
 
 def _error_json(e: Exception) -> tuple[dict, int]:
@@ -532,13 +537,10 @@ class HttpServer(ThreadedAiohttpApp):
                 res, steps = await self._eval_promql(
                     query, start, end, step, trace_ctx=ctx,
                     tenant=self._tenant(request))
-                from greptimedb_tpu.promql import format as prom_format
-
                 with TRACER.stage_in(ctx, "format"):
                     payload = getattr(prom_format, payload_name)(res, steps)
                 with TRACER.stage_in(ctx, "serialize"):
-                    resp = web.json_response(payload,
-                                             headers=_trace_headers(ctx))
+                    resp = _prom_reply(payload, route, _trace_headers(ctx))
                 M_REQUESTS.labels(route, "200").inc()
                 return resp
             except Exception as e:  # noqa: BLE001

@@ -2,13 +2,17 @@
 ``json.dumps`` of the same result as rows, byte for byte; the roads that
 must fall back to ``json.dumps`` and say so in the counter; a served
 GROUP BY whose rows are never built; and the Prometheus payloads against
-their old point-by-point form."""
+their old point-by-point form: a ``query_range`` body written from the
+result's arrays, its fall back, and the hand-over to a list once something
+reads through ``result``."""
 
+import collections.abc
 import copy
 import importlib.util
 import json
 import math
 import os
+import threading
 import time
 import types
 import urllib.parse
@@ -24,6 +28,7 @@ from greptimedb_tpu.servers import http
 from greptimedb_tpu.utils.telemetry import REGISTRY
 
 ROUTE = "/v1/sql"
+RANGE_ROUTE = "/v1/prometheus/api/v1/query_range"
 COUNTER = "greptime_http_reply_encoded_total"
 
 
@@ -38,8 +43,8 @@ def encoder():
                     "a libstdc++ without floating-point to_chars)")
 
 
-def encoded(road: str) -> float:
-    return REGISTRY.value(COUNTER, (ROUTE, road))
+def encoded(road: str, route: str = ROUTE) -> float:
+    return REGISTRY.value(COUNTER, (route, road))
 
 
 def reply_and_reference(columns, names=None) -> tuple[bytes, bytes]:
@@ -374,15 +379,332 @@ def matrix(dtype):
     ("instant_payload", old_instant_payload)])
 def test_payload_equals_the_point_by_point_form(name, old, dtype):
     res, steps = matrix(dtype)
-    got, want = getattr(prom_format, name)(res, steps), old(res, steps)
+    build, want = getattr(prom_format, name), old(res, steps)
+    assert prom_format.payload_body(build(res, steps))[0] == json.dumps(
+        want).encode()
+    got = build(res, steps)
     assert got == want
-    assert json.dumps(got) == json.dumps(want)
+    assert prom_format.payload_body(got) == (json.dumps(want).encode(), "rows")
     assert len(got["data"]["result"]) == (5 if name == "range_payload" else 4)
     # a list of steps, and values wider than labels x steps (padding)
     wide = types.SimpleNamespace(
         values=np.pad(res.values, ((0, 2), (0, 0)), constant_values=1.0),
         labels=res.labels)
-    assert getattr(prom_format, name)(wide, steps.tolist()) == want
+    assert build(wide, steps.tolist()) == want
+
+
+def series(values, labels=None, steps=None):
+    values = np.atleast_2d(np.asarray(values))
+    if labels is None:
+        labels = [{"instance": f"node{i}"} for i in range(len(values))]
+    if steps is None:
+        steps = 1700000000000 + 60000 * np.arange(values.shape[1],
+                                                  dtype=np.int64)
+    return types.SimpleNamespace(values=values, labels=labels), steps
+
+
+EDGES = [-0.0, 5e-324, 1e16, 1e-5, 3.0, 9999999999999998.0, 0.1, 1 / 3]
+MATRICES = {
+    "float32": lambda: matrix(np.float32),
+    "float64": lambda: matrix(np.float64),
+    "nan_gaps": lambda: series([[np.nan, 1.5, np.nan, 2.5, np.nan],
+                                [0.5, np.nan, np.nan, np.nan, 1.0]]),
+    "all_nan_series_dropped": lambda: series(
+        [[1.0, 2.0], [np.nan, np.nan], [3.0, 4.0], [np.nan, np.nan]]),
+    "every_series_all_nan": lambda: series(np.full((3, 4), np.nan)),
+    "infinities": lambda: series([[np.inf, -np.inf, 1.0],
+                                  [np.nan, np.inf, np.nan]]),
+    "negative_zero": lambda: series([[-0.0, 0.0]]),
+    "subnormal": lambda: series([[5e-324, 2.2250738585072014e-308]]),
+    "exponent_forms": lambda: series([[1e16, 1e-5, 9.999e-5, 1e-4, 1e15,
+                                       1e22, 1.5e-100, -1.7976931348623157e308]]),
+    "whole_value": lambda: series([[3.0, 100.0, -7.0]]),
+    "edges_float32": lambda: series(np.array([EDGES], dtype=np.float32)),
+    "random_float64": lambda: series(
+        np.random.default_rng(33).standard_normal((7, 40))
+        * 10.0 ** np.random.default_rng(34).integers(-30, 30, (7, 40))),
+    "fractional_second_steps": lambda: series(
+        [[1.0, 2.0, 3.0]], steps=np.array([1700000000001, 1700000000250,
+                                           1700000000999])),
+    "steps_before_1970_and_zero": lambda: series(
+        [[1.0, 2.0, 3.0]], steps=[-1500, 0, 10]),
+    "steps_as_a_list": lambda: series(
+        [[1.0, 2.0]], steps=[1700000000000, 1700000060000]),
+    "wide": lambda: (lambda res, steps: (types.SimpleNamespace(
+        values=np.pad(res.values, ((0, 3), (0, 5)), constant_values=1.0),
+        labels=res.labels), steps))(*matrix(np.float32)),
+    "strided_columns": lambda: series(
+        np.arange(24, dtype=np.float64).reshape(3, 8)[:, ::2]),
+    "transposed": lambda: series(
+        np.arange(12, dtype=np.float64).reshape(4, 3).T),
+    "no_series": lambda: series(np.zeros((0, 3))),
+    "one_step": lambda: series([[1.5], [np.nan], [2.5]]),
+    "one_series_one_point": lambda: series([[0.25]]),
+    "label_with_a_quote": lambda: series(
+        [[1.0]], labels=[{"path": 'say "hi"', 'k"ey': "v"}]),
+    "label_with_a_backslash": lambda: series(
+        [[1.0]], labels=[{"path": "C:\\temp\\n", "tab": "a\tb\n"}]),
+    "label_non_ascii": lambda: series(
+        [[1.0], [2.0]], labels=[{"city": "Zürich 中 \U0001f600"},
+                                {"city": "café"}]),
+    "integer_label_value": lambda: series(
+        [[1.0], [2.0]], labels=[{"cpu": 3, "up": True}, {"cpu": None}]),
+    "no_labels": lambda: series([[1.0]], labels=[{}]),
+    "many_labels": lambda: series(
+        [[1.0, 2.0]], labels=[{f"l{i}": "x" * i for i in range(12)}]),
+}
+
+
+@pytest.mark.parametrize("case", MATRICES)
+def test_native_matrix_body_is_json_dumps_byte_for_byte(encoder, case):
+    res, steps = MATRICES[case]()
+    want = json.dumps(old_range_payload(res, steps)).encode()
+    payload = prom_format.range_payload(res, steps)
+    assert prom_format.payload_body(payload) == (want, "columns")
+    # encoding read nothing through: the holder is as it was, and reads
+    # as the point-by-point list
+    result = payload["data"]["result"]
+    assert result.values is not None and result.encode() is not None
+    assert list(result) == old_range_payload(res, steps)["data"]["result"]
+    assert prom_format.payload_body(payload) == (want, "rows")
+    json.loads(want)
+
+
+def test_matrix_series_becomes_its_list_at_the_first_read():
+    res, steps = matrix(np.float64)
+    want = old_range_payload(res, steps)["data"]["result"]
+    result = prom_format.range_payload(res, steps)["data"]["result"]
+    assert result.to_list() == want and result.values is not None
+    held = copy.deepcopy(result)
+    assert held.values is not result.values and held.values is not None
+    assert len(result) == 5 and result.values is None  # read: now the list
+    assert result.encode() is None
+    assert result[0] is result[0] and result[-1] == want[-1]
+    assert result[1:3] == want[1:3] and result == want and want == result
+    assert [s["metric"] for s in result] == [s["metric"] for s in want]
+    result[0]["values"][0] = "kept"
+    assert result[0]["values"][0] == "kept" and list(held) == want
+    assert held == copy.deepcopy(held) != result
+    with pytest.raises(IndexError):
+        result[5]
+    with pytest.raises(TypeError):
+        hash(result)
+    none = prom_format.range_payload(*series(np.zeros((0, 2))))
+    assert not none["data"]["result"] and list(none["data"]["result"]) == []
+
+
+class LazyLabels(collections.abc.Sequence):
+    """Labels as promql/engine.py hands them over in a fused aggregation:
+    decoded a series on demand, over state that cannot be copied."""
+
+    def __init__(self, labels):
+        self.labels, self.device_state = labels, threading.Lock()
+
+    def __len__(self):
+        return len(self.labels)
+
+    def __getitem__(self, i):
+        return dict(self.labels[i])
+
+
+def test_the_holder_survives_deepcopy_over_lazy_labels(encoder):
+    res, steps = matrix(np.float32)
+    want = json.dumps(old_range_payload(res, steps)).encode()
+    res.labels = LazyLabels(res.labels)
+    with pytest.raises(TypeError):
+        copy.deepcopy(res.labels)
+    payload = copy.deepcopy(prom_format.range_payload(res, steps))
+    assert prom_format.payload_body(payload) == (want, "columns")
+    assert prom_format.payload_body(
+        prom_format.instant_payload(res, steps))[1] == "rows"
+
+
+def no_matrix_symbol(monkeypatch):
+    if native.lib() is not None:
+        monkeypatch.setattr(native.lib(), "_gt_no_matrix", True, raising=False)
+
+
+def read_through(payload):
+    assert payload["data"]["result"][0]["values"]
+
+
+def not_a_holder(payload):
+    payload["data"]["result"] = list(payload["data"]["result"])
+
+
+def nan_step(payload):
+    payload["data"]["result"].step_seconds[0] = np.nan  # json.dumps: NaN
+
+
+PROM_FALLBACKS = {
+    # name: (payload builder, what to do first: to the world, to the payload)
+    "library_without_symbol": ("range_payload", no_matrix_symbol, None),
+    "no_library": ("range_payload", no_library, None),
+    "result_read_through": ("range_payload", None, read_through),
+    "result_is_a_list": ("range_payload", None, not_a_holder),
+    "step_not_finite": ("range_payload", None, nan_step),
+    "instant_vector": ("instant_payload", None, None),
+}
+
+
+@pytest.mark.parametrize("case", PROM_FALLBACKS)
+def test_what_the_matrix_encoder_does_not_take_is_json_dumps(
+        monkeypatch, case):
+    name, break_it, touch = PROM_FALLBACKS[case]
+    res, steps = matrix(np.float32)
+    payload = getattr(prom_format, name)(res, steps)
+    if break_it is not None:
+        break_it(monkeypatch)
+    if touch is not None:
+        touch(payload)
+    reference = copy.deepcopy(payload)
+    reference["data"]["result"] = list(reference["data"]["result"])
+    if case != "step_not_finite":
+        assert reference == globals()[f"old_{name}"](res, steps)
+    before = encoded("columns", RANGE_ROUTE), encoded("rows", RANGE_ROUTE)
+    resp = http._prom_reply(payload, RANGE_ROUTE, {"x-greptime-trace-id": "t"})
+    assert resp.body == json.dumps(reference).encode()
+    assert resp.headers["x-greptime-trace-id"] == "t"
+    assert resp.headers["Content-Type"] == "application/json; charset=utf-8"
+    assert (encoded("columns", RANGE_ROUTE), encoded("rows", RANGE_ROUTE)
+            ) == (before[0], before[1] + 1)
+
+
+def test_an_error_payload_and_an_altered_envelope(encoder):
+    error = {"status": "error", "errorType": "bad_data", "error": "é"}
+    assert prom_format.payload_body(error) == (json.dumps(error).encode(),
+                                               "rows")
+    res, steps = matrix(np.float32)
+    payload = prom_format.range_payload(res, steps)
+    payload["warnings"] = ['a "w"']  # the envelope is json.dumps' own
+    want = dict(old_range_payload(res, steps), warnings=['a "w"'])
+    assert prom_format.payload_body(payload) == (json.dumps(want).encode(),
+                                                 "columns")
+    # the slot's text inside the payload a second time: json.dumps
+    payload = prom_format.range_payload(res, steps)
+    payload["warnings"] = [native._SLOT]
+    want["warnings"] = [native._SLOT]
+    assert prom_format.payload_body(payload) == (json.dumps(want).encode(),
+                                                 "rows")
+
+
+def test_a_point_written_through_the_holder_reaches_the_client(encoder):
+    """benchmark/tests/test_faults.py alters a reply so: deepcopy, then a
+    write into the first series' first point."""
+    res, steps = matrix(np.float32)
+    body = copy.deepcopy(prom_format.range_payload(res, steps))
+    result = body.get("data", {}).get("result")
+    assert result
+    t, v = result[0]["values"][0]
+    result[0]["values"][0] = [t, repr(float(v) * 1.001 + 1e-3)]
+    before = encoded("columns", RANGE_ROUTE), encoded("rows", RANGE_ROUTE)
+    sent = json.loads(http._prom_reply(body, RANGE_ROUTE).body)
+    want = old_range_payload(res, steps)
+    assert sent["data"]["result"][0]["values"][0] == [
+        t, repr(float(want["data"]["result"][0]["values"][0][1]) * 1.001
+                + 1e-3)]
+    assert sent["data"]["result"][0]["values"][1:] == (
+        want["data"]["result"][0]["values"][1:])
+    assert sent["data"]["result"][1:] == want["data"]["result"][1:]
+    assert (encoded("columns", RANGE_ROUTE), encoded("rows", RANGE_ROUTE)
+            ) == (before[0], before[1] + 1)
+
+
+@pytest.fixture(scope="module")
+def served_prom():
+    """No data home, as tests/test_stage_boundary.py serves PromQL: a
+    program first built through a home's artifact store keeps another
+    name in this process, which that file's profiler test reads."""
+    from greptimedb_tpu.standalone import GreptimeDB
+
+    db = GreptimeDB()
+    db.sql("CREATE TABLE cpu (h STRING, ts TIMESTAMP(3) TIME INDEX, "
+           "v DOUBLE, PRIMARY KEY (h))")
+    db.sql("INSERT INTO cpu VALUES " + ",".join(
+        f"('h\"{i % 4}é', {1000 * i}, {float(i)})" for i in range(200)))
+    srv = http.HttpServer(db, host="127.0.0.1", port=0)
+    srv.start()
+
+    def query_range(query="sum by (h) (rate(cpu[20s]))"):
+        q = urllib.parse.urlencode(
+            {"query": query, "start": "20", "end": "180", "step": "10"})
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{srv.port}{RANGE_ROUTE}?{q}") as r:
+            return r.headers, r.read()
+
+    yield types.SimpleNamespace(db=db, query_range=query_range, port=srv.port)
+    srv.stop()
+    db.close()
+
+
+def test_served_query_range_is_written_from_arrays(encoder, served_prom,
+                                                   monkeypatch):
+    seen = []
+    real = prom_format.range_payload
+
+    def watch(res, steps):
+        seen.append(real(res, steps))
+        return seen[-1]
+
+    monkeypatch.setattr(prom_format, "range_payload", watch)
+    before = encoded("columns", RANGE_ROUTE), encoded("rows", RANGE_ROUTE)
+    headers, reply = served_prom.query_range()
+    assert (encoded("columns", RANGE_ROUTE), encoded("rows", RANGE_ROUTE)
+            ) == (before[0] + 1, before[1])
+    assert len(seen) == 1
+    result = seen[0]["data"]["result"]
+    assert result.values is not None  # no point was built
+    assert headers["Content-Type"] == "application/json; charset=utf-8"
+    # the bytes are json.dumps' own over the point-by-point form
+    seen[0]["data"]["result"] = result.to_list()
+    assert reply == json.dumps(seen[0]).encode()
+    sent = json.loads(reply)["data"]["result"]
+    assert len(sent) == 4 and len(sent[0]["values"]) == 17
+    assert sorted(s["metric"]["h"] for s in sent) == [
+        f'h"{i}é' for i in range(4)]
+    # the same request without the symbol: the same bytes, by json.dumps
+    no_matrix_symbol(monkeypatch)
+    assert served_prom.query_range()[1] == reply
+    assert (encoded("columns", RANGE_ROUTE), encoded("rows", RANGE_ROUTE)
+            ) == (before[0] + 1, before[1] + 1)
+
+
+def test_served_instant_query_takes_json_dumps(served_prom):
+    route = "/v1/prometheus/api/v1/query"
+    before = encoded("columns", route), encoded("rows", route)
+    q = urllib.parse.urlencode({"query": "cpu", "time": "100"})
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{served_prom.port}{route}?{q}") as r:
+        out = json.loads(r.read())
+    assert out["data"]["resultType"] == "vector" and len(
+        out["data"]["result"]) == 4
+    assert (encoded("columns", route), encoded("rows", route)) == (
+        before[0], before[1] + 1)
+
+
+def test_the_grpc_gateway_answers_the_same_bytes(encoder, served_prom):
+    import pyarrow.flight as fl
+
+    from greptimedb_tpu.rpc.promgateway import PromGatewayServer
+
+    query = "sum by (h) (rate(cpu[20s]))"
+    srv = PromGatewayServer(served_prom.db)
+    threading.Thread(target=srv.serve, daemon=True).start()
+    client = fl.connect(f"grpc://{srv.address}")
+    try:
+        def ask(**req):
+            (out,) = client.do_action(
+                fl.Action("prom_query", json.dumps(req).encode()))
+            return out.body.to_pybytes()
+
+        assert ask(query=query, start=20, end=180, step=10) == (
+            served_prom.query_range(query)[1])
+        vector = json.loads(ask(query="cpu", time=100))
+        assert vector["data"]["resultType"] == "vector"
+        assert json.loads(ask(query="cpu{{{"))["status"] == "error"
+    finally:
+        client.close()
+        srv.shutdown()
 
 
 def test_fmt_val():
@@ -392,16 +714,21 @@ def test_fmt_val():
 
 # ---- the benchmark's reader of the counter --------------------------------
 
-def test_reply_columnar_pct_reads_the_counter():
+def layer_reader(name):
     path = os.path.join(os.path.dirname(__file__), "..", "benchmark",
-                        "layer_metrics", "reply_columnar_pct.py")
-    spec = importlib.util.spec_from_file_location("reply_columnar_pct", path)
+                        "layer_metrics", f"{name}.py")
+    spec = importlib.util.spec_from_file_location(name, path)
     reader = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(reader)
+    return reader
 
-    def key(route, road):
-        return f'{COUNTER}{{route="{route}",encoder="{road}"}}'
 
+def key(route, road):
+    return f'{COUNTER}{{route="{route}",encoder="{road}"}}'
+
+
+def test_reply_columnar_pct_reads_the_counter():
+    reader = layer_reader("reply_columnar_pct")
     before = {key(ROUTE, "columns"): 2.0, key(ROUTE, "rows"): 5.0, "x": 1.0}
     after = {key(ROUTE, "columns"): 8.0, key(ROUTE, "rows"): 7.0,
              key("/v1/logs", "rows"): 0.0, "x": 9.0}
@@ -416,3 +743,39 @@ def test_reply_columnar_pct_reads_the_counter():
                         "metrics_after": after}) is None
     assert reader.read({"metrics_before": {"x": 1.0},
                         "metrics_after": {"x": 2.0}}) is None
+
+
+def test_prom_reply_native_pct_reads_the_promql_routes():
+    reader = layer_reader("prom_reply_native_pct")
+    instant = "/v1/prometheus/api/v1/query"
+    before = {key(RANGE_ROUTE, "columns"): 4.0, key(RANGE_ROUTE, "rows"): 8.0,
+              key(ROUTE, "columns"): 1.0, "x": 1.0}
+    after = {key(RANGE_ROUTE, "columns"): 10.0, key(RANGE_ROUTE, "rows"): 9.0,
+             key(instant, "rows"): 1.0, key(ROUTE, "columns"): 50.0,
+             key(ROUTE, "rows"): 7.0, "x": 9.0}
+    assert reader.read({"metrics_before": before,
+                        "metrics_after": after}) == 75.0
+    every = dict(before, **{key(RANGE_ROUTE, "columns"): 3004.0})
+    assert reader.read({"metrics_before": before,
+                        "metrics_after": every}) == 100.0
+    # only /v1/sql moved; no PromQL reply in the window; and a program
+    # that does not count its PromQL replies (the parent of PR 33)
+    sql_only = dict(before, **{key(ROUTE, "columns"): 9.0})
+    assert reader.read({"metrics_before": before,
+                        "metrics_after": sql_only}) is None
+    assert reader.read({"metrics_before": after,
+                        "metrics_after": after}) is None
+    assert reader.read({"metrics_before": {key(ROUTE, "rows"): 1.0},
+                        "metrics_after": {key(ROUTE, "rows"): 5.0}}) is None
+
+
+def test_prom_reply_native_pct_is_declared_for_the_promql_cell():
+    with open(os.path.join(os.path.dirname(__file__), "..",
+                           "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entry = bench["per_layer"][-1]
+    assert entry == {"name": "prom_reply_native_pct", "unit": "%",
+                     "better": "higher", "source": "program_counter",
+                     "layer": "wire + server", "moves": "qps",
+                     "workloads": ["node64.cpu_rate"]}
+    assert callable(layer_reader(entry["name"]).read)
