@@ -224,7 +224,8 @@ class LokiEvaluator:
         """[S, T] window values + per-series labels + step timestamps.
         Windows are PromQL's left-exclusive (t - range, t]."""
         from greptimedb_tpu.promql.engine import (
-            _KERNEL_CACHE, WindowParams, _window_kernel, slab_width,
+            _KERNEL_CACHE, WindowParams, _window_kernel, search_bits,
+            slab_width,
         )
 
         q = agg.query
@@ -267,7 +268,8 @@ class LokiEvaluator:
                 num_sel=int(sel_dev.shape[0]), total_series=total,
                 kind="gauge_window",
                 slab_w=slab_width(step_u, T, range_u, int(spacing),
-                                  int(max_run)))
+                                  int(max_run)),
+                run_bits=search_bits(max_run))
             kern = _KERNEL_CACHE.get(p)
             if kern is None:
                 kern = _window_kernel(p)

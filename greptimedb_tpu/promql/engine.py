@@ -38,7 +38,7 @@ from greptimedb_tpu.promql.parser import (
     StringLit, SubqueryExpr, UnaryExpr, VectorSelector, parse_promql,
 )
 from greptimedb_tpu.storage.memtable import TSID
-from greptimedb_tpu.utils.tracing import M_WINDOW_ROWS, TRACER
+from greptimedb_tpu.utils.tracing import TRACER, count_window_dispatch
 
 DEFAULT_LOOKBACK_S = 300.0
 
@@ -238,6 +238,10 @@ class WindowParams:
     # the query's span and the layout's spacing, never of a request's
     # times, so every evaluation of one dashboard panel shares a program
     slab_w: int
+    # rounds of the search for a series' first sample in its own run
+    # (``search_bits``): 2**run_bits exceeds the layout's longest run, so it
+    # moves only when the longest series doubles
+    run_bits: int
 
 
 _KERNEL_CACHE: dict[WindowParams, object] = {}
@@ -277,6 +281,13 @@ def slab_width(step_ms: int, num_steps: int, range_ms: int, spacing: int,
     whole series row."""
     span = (num_steps - 1) * step_ms + range_ms
     return min(_pow2(span // max(int(spacing), 1) + 2), _pow2(max_run))
+
+
+def search_bits(max_run: int) -> int:
+    """Search rounds that cover every series' run: the table has 2**24
+    rows where a series has a hundred, and each round is a gather of two
+    words a matched series (10 ns an element on a v5e, PERF.md)."""
+    return max(int(max_run), 1).bit_length()
 
 
 def _count_le(probe, length, thr, bits: int):
@@ -400,7 +411,7 @@ def _slab_geometry(p: WindowParams, ts_hi, ts_lo, val_s, row_ptr, sel_tsids,
         at = jnp.clip(r0 + i, 0, n - 1)
         return _join_i64(ts_hi[at], ts_lo[at])
 
-    base = r0 + _count_le(ts_at, run, start_ms - p.range_ms, n.bit_length())
+    base = r0 + _count_le(ts_at, run, start_ms - p.range_ms, p.run_bits)
     # the table read as [n/128, 128] (a bitcast of the TPU's 1-D tiling),
     # whole chunks gathered from the one that holds ``base``: ONE gather op
     # a column (the TPU compiler turns a W-long slice-gather from a 1-D
@@ -1012,6 +1023,7 @@ class PromEvaluator:
             kind=kind,
             slab_w=slab_width(self.step_ms, num_steps, int(rng), spacing,
                               max_run),
+            run_bits=search_bits(max_run),
         )
         args = (*layout, sel_dev, np.int64(start))
         return args, p, tsids, labels, pinned, start, int(rng)
@@ -1040,7 +1052,7 @@ class PromEvaluator:
         # happened, so the first call must not be attributed as one
         # (the promql twin of physical.aot_kernel_call's discipline)
         compiling = jit_miss and not getattr(kern, "aot", False)
-        M_WINDOW_ROWS.inc(p.num_sel * p.slab_w)
+        count_window_dispatch(len(tsids), p.num_sel, p.slab_w)
         out = self._timed_kernel(
             "window_kernel", lambda: kern(*args), jit_miss, compiling,
             kind=kind)
@@ -1075,7 +1087,8 @@ class PromEvaluator:
         if cnt_kern is None:
             cnt_kern = _count_max_kernel(ck)
             _KERNEL_CACHE[ck] = cnt_kern
-        M_WINDOW_ROWS.inc(2 * p.num_sel * p.slab_w)  # sizing pass + matrix
+        # sizing pass + matrix
+        count_window_dispatch(len(tsids), p.num_sel, p.slab_w, programs=2)
         cnt_max = int(cnt_kern(*args))
         lmax = max(2, _pow2(cnt_max))
         mk = (p, "matrix", lmax)

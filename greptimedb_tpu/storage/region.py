@@ -325,12 +325,9 @@ class Region:
             if out_codes is not None:
                 out_codes[name] = col_codes.astype(np.int32)
             code_arrays.append(col_codes)
-        # vectorized any-arity series resolution: pack per-column codes
-        # into one int64 key when the combined bit width fits (exact,
-        # injective), factorize the packed ints, then a python loop over
-        # UNIQUE keys only (the metric-engine physical region routinely
-        # has many tag columns, so no per-row python fallback is
-        # acceptable on the ingest hot path)
+        # vectorized any-arity series resolution: fold the per-column
+        # codes into one int64 key a row, factorize the keys, then a
+        # python loop over UNIQUE keys only
         if len(code_arrays) == 1:
             # single-tag tables resolve through a dense code→tsid mirror
             # of _series: one gather per write, python only for codes
@@ -370,63 +367,59 @@ class Region:
             max(int(a.max()) if n else 0, 1).bit_length()
             for a in code_arrays
         ]
-        if sum(widths) <= 62:
-            packed = code_arrays[0]
-            for a, w in zip(code_arrays[1:], widths[1:]):
-                packed = (packed << np.int64(w)) | a
-        else:  # astronomically wide key space: exact structured unique
-            packed = None
-        if packed is not None:
-            pmax = int(packed.max()) + 1 if n else 0
-            if 0 < pmax <= max(1024, 4 * n):
-                # dense key space (the common case: few live series):
-                # bincount-factorize is O(n + keyspace) with no hash
-                # table.  Uniques are then reordered to FIRST-OCCURRENCE
-                # order — exactly pd.factorize's — because the order NEW
-                # series ids are assigned in is observable downstream
-                # (first/last picks on equal timestamps follow the
-                # device layout's tsid order)
-                uniq_sorted = np.flatnonzero(
-                    np.bincount(packed, minlength=pmax))
-                remap = np.zeros(pmax, dtype=np.int64)
-                remap[uniq_sorted] = np.arange(len(uniq_sorted))
-                inv_s = remap[packed]
-                first = np.empty(len(uniq_sorted), dtype=np.int64)
-                first[inv_s[::-1]] = np.arange(n - 1, -1, -1,
-                                               dtype=np.int64)
-                order = np.argsort(first, kind="stable")
-                rank = np.empty(len(order), dtype=np.int64)
-                rank[order] = np.arange(len(order), dtype=np.int64)
-                uniq_packed = uniq_sorted[order]
-                inv2 = rank[inv_s]
-            else:
-                inv2, uniq_packed = pd.factorize(packed)
-            # first-occurrence row per unique packed key (reversed write:
-            # the earliest row wins), to recover the exact code tuple
-            first_row = np.empty(len(uniq_packed), dtype=np.int64)
-            rev = np.arange(n - 1, -1, -1)
-            first_row[inv2[rev]] = rev
-            tsids = np.empty(len(uniq_packed), dtype=np.int64)
-            for j in range(len(uniq_packed)):
-                r = int(first_row[j])
-                key = tuple(int(a[r]) for a in code_arrays)
-                tsid = self._series.get(key)
-                if tsid is None:
-                    tsid = len(self._series)
-                    self._series[key] = tsid
-                tsids[j] = tsid
-            return tsids[inv2]
-        code_mat = np.stack(code_arrays, axis=1)  # [n, k] int64
-        uniq_rows, inv2 = np.unique(code_mat, axis=0, return_inverse=True)
-        tsids = np.empty(len(uniq_rows), dtype=np.int64)
-        for j in range(len(uniq_rows)):
-            key = tuple(int(c) for c in uniq_rows[j])
+        # the columns' codes folded into one int64 key a row (exact,
+        # injective).  Where the widths pass 62 bits — many tags, several
+        # with as many values as there are series — the running key is
+        # re-coded densely before the next column goes in: hash passes
+        # over int64, never a sort of n rows of k words (the metric-
+        # engine physical region routinely has many tag columns)
+        packed, bits = code_arrays[0], widths[0]
+        for a, w in zip(code_arrays[1:], widths[1:]):
+            if bits + w > 62:
+                packed, seen = pd.factorize(packed)
+                bits = max(len(seen) - 1, 1).bit_length()
+            packed = (packed << np.int64(w)) | a
+            bits += w
+        pmax = int(packed.max()) + 1 if n else 0
+        if 0 < pmax <= max(1024, 4 * n):
+            # dense key space (the common case: few live series):
+            # bincount-factorize is O(n + keyspace) with no hash
+            # table.  Uniques are then reordered to FIRST-OCCURRENCE
+            # order — exactly pd.factorize's — because the order NEW
+            # series ids are assigned in is observable downstream
+            # (first/last picks on equal timestamps follow the
+            # device layout's tsid order)
+            uniq_sorted = np.flatnonzero(
+                np.bincount(packed, minlength=pmax))
+            remap = np.zeros(pmax, dtype=np.int64)
+            remap[uniq_sorted] = np.arange(len(uniq_sorted))
+            inv_s = remap[packed]
+            first = np.empty(len(uniq_sorted), dtype=np.int64)
+            first[inv_s[::-1]] = np.arange(n - 1, -1, -1,
+                                           dtype=np.int64)
+            order = np.argsort(first, kind="stable")
+            rank = np.empty(len(order), dtype=np.int64)
+            rank[order] = np.arange(len(order), dtype=np.int64)
+            inv2 = rank[inv_s]
+            n_uniq = len(order)
+        else:
+            inv2, uniq_packed = pd.factorize(packed)
+            n_uniq = len(uniq_packed)
+        # first-occurrence row per unique key (reversed write: the
+        # earliest row wins), to recover the exact code tuple; python
+        # only over UNIQUE keys, and there only the registry's look-up
+        first_row = np.empty(n_uniq, dtype=np.int64)
+        rev = np.arange(n - 1, -1, -1)
+        first_row[inv2[rev]] = rev
+        keys = np.stack([a[first_row] for a in code_arrays], axis=1)
+        tsids = np.empty(n_uniq, dtype=np.int64)
+        for j, key in enumerate(map(tuple, keys.tolist())):
             tsid = self._series.get(key)
             if tsid is None:
                 tsid = len(self._series)
                 self._series[key] = tsid
             tsids[j] = tsid
-        return tsids[inv2.reshape(-1)]
+        return tsids[inv2]
 
     def write(self, data: dict[str, list | np.ndarray], op: int = OP_PUT,
               wire_payload: bytes | None = None) -> int:

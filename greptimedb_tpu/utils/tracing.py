@@ -55,6 +55,25 @@ M_WINDOW_ROWS = REGISTRY.counter(
     "Slab cells (padded matched series x slab width) gathered by "
     "dispatched PromQL window programs",
 )
+M_SELECTED_SERIES = REGISTRY.counter(
+    "greptime_promql_selected_series_total",
+    "Series the label matchers kept, of dispatched PromQL window programs",
+)
+M_PADDED_SERIES = REGISTRY.counter(
+    "greptime_promql_padded_series_total",
+    "Series slots (the selection padded to the program's static size) of "
+    "dispatched PromQL window programs",
+)
+
+
+def count_window_dispatch(selected: int, padded: int, slab_w: int,
+                          programs: int = 1) -> None:
+    """The three counters of one PromQL window dispatch: host integers
+    off static shapes and the selection's length."""
+    M_WINDOW_ROWS.inc(programs * padded * slab_w)
+    M_SELECTED_SERIES.inc(programs * selected)
+    M_PADDED_SERIES.inc(programs * padded)
+
 
 _TRACE_ANNOTATION = None
 

@@ -33,6 +33,12 @@ FIELD_NAMES = tuple(f"f{i}" for i in range(FIELDS))
 # 512 series of 64 instances, 1 h at 60 s over [5m] gathers 512 samples each
 PROM_ROWS, PROM_SERIES, PROM_SEL, PROM_GROUPS, PROM_W = (
     12_582_912, 4096, 512, 64, 512)
+# k8s100k.namespace_cpu (benchmark/configs/prom-k8s-100k.json): 105,000
+# counters x 1 h at 30 s in a layout of the same 12.58M padded rows; the
+# panel's matchers keep 63,000 series (65,536 padded) in 200 namespaces,
+# 30 min at 30 s over [5m] gathers 128 samples each
+K8S_SERIES, K8S_SEL, K8S_MATCHED, K8S_GROUPS, K8S_W = (
+    105_000, 65_536, 63_000, 200, 128)
 
 
 @pytest.fixture(scope="module")
@@ -106,23 +112,30 @@ def _grid_args(sh):
             _shape((), jnp.int64, sh), _shape((), jnp.int32, sh))
 
 
+def _window_params(step_ms, scrape_ms, run, sel, series, want_w):
+    """The counter program's shape class for ``num_steps`` 61 over [5m]
+    on a layout scraped every ``scrape_ms`` whose longest run is ``run``."""
+    from greptimedb_tpu.promql.engine import (WindowParams, search_bits,
+                                              slab_width)
+
+    w = slab_width(step_ms, 61, 300_000, scrape_ms, run)
+    assert w == want_w
+    return WindowParams(step_ms=step_ms, num_steps=61, range_ms=300_000,
+                        num_sel=sel, total_series=series, kind="counter",
+                        slab_w=w, run_bits=search_bits(run))
+
+
 def _promql_params():
-    from greptimedb_tpu.promql.engine import WindowParams, slab_width
-
     # sum by (instance)(rate(node_cpu_seconds_total{mode=..}[5m])), 1 h at 60 s
-    w = slab_width(60_000, 61, 300_000, 15_000, 2880)
-    assert w == PROM_W
-    return WindowParams(step_ms=60_000, num_steps=61, range_ms=300_000,
-                        num_sel=PROM_SEL, total_series=PROM_SERIES,
-                        kind="counter", slab_w=w)
+    return _window_params(60_000, 15_000, 2880, PROM_SEL, PROM_SERIES, PROM_W)
 
 
-def _layout_args(sh):
+def _layout_args(sh, series=PROM_SERIES, sel=PROM_SEL):
     return (_shape((PROM_ROWS,), jnp.int32, sh),
             _shape((PROM_ROWS,), jnp.uint32, sh),
             _shape((PROM_ROWS,), jnp.float32, sh),
-            _shape((PROM_SERIES + 1,), jnp.int32, sh),
-            _shape((PROM_SEL,), jnp.int32, sh), _shape((), jnp.int64, sh))
+            _shape((series + 1,), jnp.int32, sh),
+            _shape((sel,), jnp.int32, sh), _shape((), jnp.int64, sh))
 
 
 def _promql_window():
@@ -136,6 +149,15 @@ def _promql_fused():
 
     return _build_fused(_promql_params(), "rate", "sum", PROM_GROUPS,
                         PROM_SEL, 300)
+
+
+def _k8s_fused():
+    """sum by (namespace)(rate(container_cpu_usage_seconds_total{..}[5m])),
+    30 min at 30 s: the one program of every request of the cell."""
+    from greptimedb_tpu.compile.fused import _build_fused
+
+    p = _window_params(30_000, 30_000, 120, K8S_SEL, K8S_SERIES, K8S_W)
+    return _build_fused(p, "rate", "sum", K8S_GROUPS, K8S_MATCHED, 300)
 
 
 def _segment(form, op, rows, sh):
@@ -154,6 +176,10 @@ CASES = {
     "promql-fused": lambda sh: (
         _promql_fused(),
         _layout_args(sh) + (_shape((PROM_SEL,), jnp.int32, sh),)),
+    "promql-fused-k8s-65536": lambda sh: (
+        _k8s_fused(),
+        _layout_args(sh, K8S_SERIES, K8S_SEL)
+        + (_shape((K8S_MATCHED,), jnp.int32, sh),)),
     # the form `auto` takes on every backend, at table size
     "segment-scatter-mean": lambda sh: _segment("scatter", "mean", ROWS, sh),
     # the form only `force` reaches: its scan is minutes slow past this

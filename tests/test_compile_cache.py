@@ -101,7 +101,7 @@ class TestShape:
 
         p = WindowParams(step_ms=60000, num_steps=11, range_ms=300000,
                          num_sel=4, total_series=4, kind="counter",
-                         slab_w=64)
+                         slab_w=64, run_bits=7)
         c = canon_key('promql', (p, "rate", "sum"))
         assert c is not None and "counter" in c and "slab_w=i64" in c
         p2 = dataclasses.replace(p, kind="gauge_window")

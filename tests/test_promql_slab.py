@@ -79,6 +79,15 @@ def _series(kind: str):
                             _counter(rng, n, (8, 0, 5, 0, 0)[i]))
         out["empty"] = (t0 + 15_000 * np.arange(4), np.full(4, np.nan))
         return out
+    if kind in ("run127", "run128"):
+        # the longest run one under and exactly a power of two: the
+        # first-sample search has as many rounds as that length has bits
+        # (``search_bits``), and the windows lie at the run's far end
+        longest = int(kind[3:])
+        return {
+            f"s{i}": (t0 + 15_000 * np.arange(n),
+                      _counter(rng, n, (0, 9, 0, 6)[i]))
+            for i, n in enumerate((longest, longest - 1, 64, 2))}
     raise AssertionError(kind)
 
 
@@ -101,6 +110,8 @@ SCENARIOS = {
     # ranges after the last: windows wholly outside the data on both sides
     "outside": ("regular", (T0_S - 700, T0_S + 3600 + 700, 100), "", 0,
                 None),
+    "run127": ("run127", (T0_S + 1500, T0_S + 1980, 60), "", 0, None),
+    "run128": ("run128", (T0_S + 1500, T0_S + 1980, 60), "", 0, None),
     "offset": ("regular", (T0_S + 900, T0_S + 1500, 60), " offset 90s",
                90_000, None),
     "at": ("regular", (T0_S + 900, T0_S + 1140, 60), f" @ {T0_S + 2000}",
@@ -265,6 +276,7 @@ def test_kind_against_plain_reference(db, scenario, kind):
     _args, p, *_rest = ev._prep_window(sel, "counter")
     longest = max(int((~np.isnan(v)).sum()) for _t, v in data.values())
     cap = pe._pow2(longest)
+    assert p.run_bits == longest.bit_length()   # 127 -> 7, 128 -> 8
     if dataset == "irregular":
         assert p.slab_w == cap
     else:

@@ -773,7 +773,8 @@ def test_prom_reply_native_pct_is_declared_for_the_promql_cell():
     with open(os.path.join(os.path.dirname(__file__), "..",
                            "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-1]
+    entry = next(m for m in bench["per_layer"]
+                 if m["name"] == "prom_reply_native_pct")
     assert entry == {"name": "prom_reply_native_pct", "unit": "%",
                      "better": "higher", "source": "program_counter",
                      "layer": "wire + server", "moves": "qps",
