@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from greptimedb_tpu.compile import named_jit
 from greptimedb_tpu.errors import TableNotFound
-from greptimedb_tpu.utils.tracing import TRACER, count_window_dispatch
+from greptimedb_tpu.utils.tracing import TRACER
 
 # diagnostics: fused dispatches this process (tests/bench read it)
 FUSED_DISPATCHES = {"count": 0}
@@ -226,7 +226,7 @@ def try_fused_aggregation(ev, e):
         fused_args = tuple(place(a) for a in fused_args)
     # AOT-store hits deserialize — first call is NOT an XLA compile
     compiling = jit_miss and not getattr(kern, "aot", False)
-    count_window_dispatch(len(tsids), p.num_sel, p.slab_w)
+    pe.count_dispatch(p, args, len(tsids))
     vals = ev._timed_kernel(
         "fused_kernel", lambda: kern(*fused_args), jit_miss, compiling,
         op=e.op, func=func or "instant")

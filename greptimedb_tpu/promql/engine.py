@@ -247,9 +247,13 @@ class WindowParams:
 _KERNEL_CACHE: dict[WindowParams, object] = {}
 
 # widest slab that is swept: up to it window edges, picks and min/max are
-# compare-select passes over [S, T, W] (4 ps a cell on a v5e), past it
-# searches and gathers (10 ns an element there, whatever W) — the same
-# integers and the same picked values either way (PERF.md, PR 31)
+# compare-select passes over [S, T, F] (4 ps a cell on a v5e) and the
+# gathered chunks are folded to the F = max(W, chunk) columns a window can
+# read (``Slab.fold``: counts and one-hot picks need no time order), past
+# it searches and gathers along the gathered slab as it is (10 ns an
+# element there, whatever W; they need ascending rows and would save 128
+# columns of 8,320) — the same integers and the same picked values either
+# way (PERF.md, PR 31 and PR 35)
 _SWEEP_WIDTH = 1 << 13
 
 
@@ -288,6 +292,27 @@ def search_bits(max_run: int) -> int:
     rows where a series has a hundred, and each round is a gather of two
     words a matched series (10 ns an element on a v5e, PERF.md)."""
     return max(int(max_run), 1).bit_length()
+
+
+def swept_columns(slab_w: int, n: int) -> int:
+    """Columns of one matched series that every [S, T, ·] pass of a window
+    program runs over, for slab width ``slab_w`` on a layout of ``n`` rows
+    read in chunks of gcd(n, 128): the folded slab's max(W, chunk) where
+    the slab is swept, the gathered chunks (one more) where it is
+    searched.  Static shapes only: ``_slab_geometry`` takes its widths
+    from here, and so does the counter that says the fold engaged."""
+    c = math.gcd(n, 128)
+    readable = -(-slab_w // c) * c
+    return readable if slab_w <= _SWEEP_WIDTH else readable + c
+
+
+def count_dispatch(p: WindowParams, args, selected: int,
+                   programs: int = 1) -> None:
+    """Count one dispatch of ``programs`` window programs of class ``p``
+    over the kernel arguments ``args`` (``_prep_window``'s)."""
+    count_window_dispatch(
+        selected, p.num_sel, p.slab_w,
+        swept_columns(p.slab_w, args[2].shape[0]), programs)
 
 
 def _count_le(probe, length, thr, bits: int):
@@ -355,26 +380,68 @@ def _build_sort_layout(ts, val, tsid, mask, total_series: int):
         ts_s, tsid[order], valid.sum(dtype=jnp.int32), total_series)
 
 
+def _fold(a, off, f: int):
+    """[S, f + chunk] gathered columns → the [S, f] a window can read.
+    Gathered columns j and j + f are never both readable (j is iff
+    j ≥ off, j + f iff j < off), so one select over the first chunk folds
+    the sentinel chunk away; the samples come out rotated by ``off``,
+    which a count or a one-hot pick does not see."""
+    c = a.shape[1] - f
+    first = jnp.where(
+        jnp.arange(c, dtype=jnp.int32)[None, :] < off[:, None],
+        a[:, f:], a[:, :c])
+    return first if f == c else jnp.concatenate([first, a[:, c:f]], axis=1)
+
+
 class Slab(typing.NamedTuple):
     """The matched series' samples that can fall in (start − range, end],
-    dense [S, W], and the window edges over it — see _slab_geometry."""
+    and the window edges over them — see _slab_geometry.
 
-    rel: jnp.ndarray  # [S, W] ts − start_ms; sentinels outside the run
-    val: jnp.ndarray  # [S, W] f32, 0 outside the run
-    ok: jnp.ndarray  # [S, W] column holds a sample of this series
-    lo: jnp.ndarray  # [S, T] first slab column of each window
+    ``rel``, ``val`` and ``ok`` are the GATHERED chunks in time order,
+    [S, G]: what a prefix or a neighbour compare needs.  The [S, T, ·]
+    passes run over ``fold`` of them, [S, width]: where the slab is swept
+    the F = G − chunk readable columns rotated by ``off``, no sentinel
+    chunk; where it is searched (ascending rows) the gathered slab as it
+    is.  ``lo``/``hi`` index a window's samples in time order from the
+    slab's own origin (the first readable sample where it is swept, the
+    first gathered column where it is searched); ``col`` turns such an
+    index into a column of a folded array."""
+
+    rel: jnp.ndarray  # [S, G] ts − start_ms; sentinels outside the run
+    val: jnp.ndarray  # [S, G] f32, 0 outside the run
+    ok: jnp.ndarray  # [S, G] column holds a readable sample of the series
+    off: jnp.ndarray  # [S] i32 gathered column of the first readable row
+    lo: jnp.ndarray  # [S, T] index of each window's first sample
     hi: jnp.ndarray  # [S, T] one past its last
     cnt: jnp.ndarray  # [S, T] i32 samples in the window
     has: jnp.ndarray  # [S, T] window non-empty and series selected
     sel_ok: jnp.ndarray  # [S]
-    sweep: bool  # W is within _SWEEP_WIDTH
+    sweep: bool  # W is within _SWEEP_WIDTH: the slab is folded and swept
+    width: int  # columns the [S, T, ·] passes run over (``swept_columns``)
+
+    def fold(self, a):
+        return _fold(a, self.off, self.width) if self.sweep else a
+
+    def col(self, i):
+        """The column of a folded array that holds index ``i`` [S, ...]."""
+        if not self.sweep:
+            return i
+        off = self.off.reshape((-1,) + (1,) * (i.ndim - 1))
+        return (i + off) % self.width
+
+    def index(self):
+        """[S, width] the index each folded column holds (``col``'s
+        inverse)."""
+        j = jnp.arange(self.width, dtype=jnp.int32)[None, :]
+        return (j - self.off[:, None]) % self.width if self.sweep else j
 
 
 def _slab_edges(rel, thr, sweep: bool):
-    """[S, T] count of each slab row's columns with ``rel`` ≤ ``thr[t]``
-    (rows ascend: −inf sentinels, the samples, +inf sentinels): compares
-    over [S, T, W], or past _SWEEP_WIDTH a log-W search along the slab's
-    axis — the same integers either way."""
+    """[S, T] count of each slab row's columns with ``rel`` ≤ ``thr[t]``:
+    compares over [S, T, F] of the folded slab (a count needs no order),
+    or past _SWEEP_WIDTH a log-W search along the gathered slab's axis
+    (rows ascend: −inf sentinels, the samples, +inf sentinels) — the
+    same count of samples either way."""
     S, w = rel.shape
     T = thr.shape[0]
     if sweep:
@@ -392,13 +459,16 @@ def _slab_geometry(p: WindowParams, ts_hi, ts_lo, val_s, row_ptr, sel_tsids,
     resident layout (_build_sort_layout): the ONE definition the stats
     kernel, the matrix kernels and the fused programs build on.
 
-    Gathers, for each selected series, ``p.slab_w`` consecutive rows of
-    its run from its first sample after ``start − range`` on (found in
-    the series' own run through ``row_ptr``), and places every window's
-    half-open column range [lo, hi) on that slab with LEFT-EXCLUSIVE
-    window semantics (t - range, t].  After the gather nothing has the
-    table's length: work is proportional to the matched series, not to
-    the table."""
+    Gathers, for each selected series, the rows of its run from its first
+    sample after ``start − range`` on (found in the series' own run
+    through ``row_ptr``) — ``p.slab_w`` of them can fall in the query's
+    span — and places every window's half-open index range [lo, hi) on
+    them with LEFT-EXCLUSIVE window semantics (t - range, t].  After the
+    gather nothing has the table's length: work is proportional to the
+    matched series, not to the table.  Where the slab is swept
+    (``Slab.sweep``) the edges are counted on the folded slab and come
+    out relative to the first readable sample; where it is searched, on
+    the gathered one, relative to its first column."""
     T, S, w = p.num_steps, p.num_sel, p.slab_w
     n = val_s.shape[0]
     # padding slots (-1) and series newer than the layout own no rows
@@ -415,8 +485,9 @@ def _slab_geometry(p: WindowParams, ts_hi, ts_lo, val_s, row_ptr, sel_tsids,
     # the table read as [n/128, 128] (a bitcast of the TPU's 1-D tiling),
     # whole chunks gathered from the one that holds ``base``: ONE gather op
     # a column (the TPU compiler turns a W-long slice-gather from a 1-D
-    # operand into a loop with an iteration a series).  Columns before
-    # ``base`` or past the run are masked.
+    # operand into a loop with an iteration a series).  One chunk more
+    # than W fills, because ``base`` lies anywhere in the first: columns
+    # before ``base`` or past the run are masked.
     c = math.gcd(n, 128)
     k = -(-w // c) + 1
     chunk = (base // c)[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
@@ -441,13 +512,17 @@ def _slab_geometry(p: WindowParams, ts_hi, ts_lo, val_s, row_ptr, sel_tsids,
     rel = jnp.clip(_join_i64(take(ts_hi), take(ts_lo)) - start_ms,
                    -big, big - 1).astype(tdt)
     rel = jnp.where(ok, rel, jnp.where(before, -big - 1, big).astype(tdt))
+    # the sweeps read the folded slab, the searches the gathered one
     sweep = w <= _SWEEP_WIDTH
-    lo = _slab_edges(rel, jnp.asarray((steps - p.range_ms).astype(tdt)),
+    off = base % c
+    width = swept_columns(w, n)
+    swept = _fold(rel, off, width) if sweep else rel
+    lo = _slab_edges(swept, jnp.asarray((steps - p.range_ms).astype(tdt)),
                      sweep)
-    hi = _slab_edges(rel, jnp.asarray(steps.astype(tdt)), sweep)
+    hi = _slab_edges(swept, jnp.asarray(steps.astype(tdt)), sweep)
     cnt = hi - lo
     has = (cnt > 0) & sel_ok[:, None]
-    return Slab(rel, val, ok, lo, hi, cnt, has, sel_ok, sweep)
+    return Slab(rel, val, ok, off, lo, hi, cnt, has, sel_ok, sweep, width)
 
 
 def _prefix_sum(x):
@@ -511,13 +586,20 @@ def _window_body(p: WindowParams):  # gl: warm-path
     def kernel(ts_hi, ts_lo, val_s, row_ptr, sel_tsids, start_ms):
         slab = _slab_geometry(p, ts_hi, ts_lo, val_s, row_ptr, sel_tsids,
                               start_ms)
-        rel, val, ok, lo, hi, cnt, has, sel_ok, sweep = slab
-        w = val.shape[1]
+        lo, hi, cnt, has, sel_ok = (slab.lo, slab.hi, slab.cnt, slab.has,
+                                    slab.sel_ok)
+        sweep, w = slab.sweep, slab.width
+        # the gathered rows in time order, for what reads a neighbour or
+        # a prefix; every [S, T, ·] pass below reads ``slab.fold`` of such
+        # an array, addressed through ``slab.col``
+        g_rel, g_val, ok = slab.rel, slab.val, slab.ok
+        rel, val = slab.fold(g_rel), slab.fold(g_val)
 
         def pick(a, i):
-            """a[s, i[s, t]]: one compare-select pass where the slab is
-            swept (a single non-zero term, so the sum is the value), else
-            a gather."""
+            """a[s, col(i[s, t])] of a folded array: one compare-select
+            pass where the slab is swept (a single non-zero term, so the
+            sum is the value), else a gather."""
+            i = slab.col(i)
             if not sweep:
                 return jnp.take_along_axis(a, i, axis=1)
             cols = jnp.arange(a.shape[1], dtype=jnp.int32)[None, None, :]
@@ -534,24 +616,25 @@ def _window_body(p: WindowParams):  # gl: warm-path
         prev_same = jnp.concatenate(
             [jnp.zeros((S, 1), bool), ok[:, 1:] & ok[:, :-1]], axis=1)
         prev_val = jnp.concatenate(
-            [jnp.zeros((S, 1), val.dtype), val[:, :-1]], axis=1)
-        drop = jnp.where(prev_same & (prev_val > val), prev_val, 0.0)
+            [jnp.zeros((S, 1), g_val.dtype), g_val[:, :-1]], axis=1)
+        drop = jnp.where(prev_same & (prev_val > g_val), prev_val, 0.0)
         gdrop = _prefix_sum(drop.astype(jnp.float64))
-        adj = val.astype(jnp.float64) + gdrop
+        adj = slab.fold(g_val.astype(jnp.float64) + gdrop)
 
-        # cumulative sums (leading zero) along each series' slab row;
-        # ``val`` is already 0 outside the run
+        # window sums from f64 prefixes along each series' gathered row
+        # (``g_val`` is already 0 outside the run), taken in time order
+        # and folded afterwards: the inclusive prefix at the window's
+        # last sample less the one before its first (0 at the row's head)
         def cs(x):
-            return jnp.concatenate(
-                [jnp.zeros((S, 1), jnp.float64),
-                 _prefix_sum(x.astype(jnp.float64))], axis=1)
+            return slab.fold(_prefix_sum(x.astype(jnp.float64)))
 
-        cs_v = cs(val)
-        cs_v2 = cs(val.astype(jnp.float64) ** 2)
-        tsec = jnp.where(ok, rel, 0).astype(jnp.float64) / 1000.0
-        cs_t = cs(tsec)
-        cs_tv = cs(tsec * val.astype(jnp.float64))
-        cs_t2 = cs(tsec * tsec)
+        def win_sum(c, first=lo):
+            head = pick(c, jnp.clip(first - 1, 0, w - 1))
+            return pick(c, jnp.clip(hi - 1, 0, w - 1)) - jnp.where(
+                first > 0, head, 0.0)
+
+        g_val64 = g_val.astype(jnp.float64)
+        tsec = jnp.where(ok, g_rel, 0).astype(jnp.float64) / 1000.0
 
         has2 = (cnt >= 2) & sel_ok[:, None]
 
@@ -582,22 +665,18 @@ def _window_body(p: WindowParams):  # gl: warm-path
             # resets/changes counts via indicator cumsums — a SEPARATE
             # kind so the (much hotter) rate/increase/delta path doesn't
             # pay two extra prefixes it never reads
-            ind_reset = jnp.where(prev_same & (prev_val > val), 1.0, 0.0)
-            ind_change = jnp.where(prev_same & (prev_val != val), 1.0, 0.0)
-            cs_r = cs(ind_reset)
-            cs_c = cs(ind_change)
+            ind_reset = jnp.where(prev_same & (prev_val > g_val), 1.0, 0.0)
+            ind_change = jnp.where(prev_same & (prev_val != g_val), 1.0, 0.0)
             # exclude the boundary pair crossing into the window: indicator at
             # index i compares i-1,i; window pairs are (lo+1..hi-1)
-            lo1 = jnp.clip(lo + 1, 0, w)
             out["resets"] = jnp.where(
-                has, (pick(cs_r, hi) - pick(cs_r, lo1)).astype(jnp.float32),
-                nan)
+                has, win_sum(cs(ind_reset), lo + 1).astype(jnp.float32), nan)
             out["changes"] = jnp.where(
-                has, (pick(cs_c, hi) - pick(cs_c, lo1)).astype(jnp.float32),
+                has, win_sum(cs(ind_change), lo + 1).astype(jnp.float32),
                 nan)
         if p.kind in ("gauge_window",):
-            sum64 = pick(cs_v, hi) - pick(cs_v, lo)
-            sum2_64 = pick(cs_v2, hi) - pick(cs_v2, lo)
+            sum64 = win_sum(cs(g_val))
+            sum2_64 = win_sum(cs(g_val64 ** 2))
             s = sum64.astype(jnp.float32)
             out["sum"] = jnp.where(has, s, nan)
             out["avg"] = jnp.where(has, s / jnp.maximum(fcnt, 1), nan)
@@ -609,10 +688,10 @@ def _window_body(p: WindowParams):  # gl: warm-path
             out["first_ts"] = jnp.where(has, pick_ts(first_i), 0)
             out["last_ts"] = jnp.where(has, pick_ts(last_i), 0)
         if p.kind == "regression":
-            sw = pick(cs_v, hi) - pick(cs_v, lo)
-            st = pick(cs_t, hi) - pick(cs_t, lo)
-            stv = pick(cs_tv, hi) - pick(cs_tv, lo)
-            st2 = pick(cs_t2, hi) - pick(cs_t2, lo)
+            sw = win_sum(cs(g_val))
+            st = win_sum(cs(tsec))
+            stv = win_sum(cs(tsec * g_val64))
+            st2 = win_sum(cs(tsec * tsec))
             cn = cnt.astype(jnp.float64)
             denom = cn * st2 - st * st
             slope = jnp.where(denom != 0, (cn * stv - st * sw) / denom, jnp.nan)
@@ -628,17 +707,17 @@ def _window_body(p: WindowParams):  # gl: warm-path
             out["prev_val"] = jnp.where(has2, pick(val, prev_i), nan)
         if p.kind == "minmax":
             if sweep:
-                # reduce over the slab under the edge mask
-                j = jnp.arange(w, dtype=jnp.int32)[None, None, :]
+                # reduce over the folded slab under the edge mask
+                j = slab.index()[:, None, :]
                 in_win = (j >= lo[:, :, None]) & (j < hi[:, :, None])
                 mn = jnp.min(jnp.where(in_win, val[:, None, :], jnp.inf),
                              axis=-1)
                 mx = jnp.max(jnp.where(in_win, val[:, None, :], -jnp.inf),
                              axis=-1)
             else:
-                mn = _range_extreme(jnp.where(ok, val, jnp.inf), lo, hi, cnt,
-                                    jnp.minimum)
-                mx = _range_extreme(jnp.where(ok, val, -jnp.inf), lo, hi,
+                mn = _range_extreme(jnp.where(ok, g_val, jnp.inf), lo, hi,
+                                    cnt, jnp.minimum)
+                mx = _range_extreme(jnp.where(ok, g_val, -jnp.inf), lo, hi,
                                     cnt, jnp.maximum)
             out["min"] = jnp.where(jnp.isfinite(mn), mn, nan)
             out["max"] = jnp.where(jnp.isfinite(mx), mx, nan)
@@ -681,13 +760,14 @@ def _matrix_kernel(p: WindowParams, lmax: int, kind: str):  # gl: warm-path
         *layout_sel_start, a1, a2 = args
         slab = _slab_geometry(p, *layout_sel_start)
         has = slab.has
-        w = slab.val.shape[1]
         cntf = slab.cnt.reshape(-1)  # [S*T]
         j = jnp.arange(lmax, dtype=jnp.int32)
-        idx = jnp.clip(slab.lo[:, :, None] + j[None, None, :], 0, w - 1)
+        idx = slab.col(jnp.clip(
+            slab.lo[:, :, None] + j[None, None, :], 0, slab.width - 1))
         # [S*T, L] time-ordered window samples
         rows = jnp.take_along_axis(
-            slab.val[:, None, :], idx, axis=2).reshape(S * T, lmax)
+            slab.fold(slab.val)[:, None, :], idx, axis=2).reshape(
+                S * T, lmax)
         ok = j[None, :] < cntf[:, None]
         nan = jnp.float32(jnp.nan)
         inf = jnp.float32(jnp.inf)
@@ -1052,7 +1132,7 @@ class PromEvaluator:
         # happened, so the first call must not be attributed as one
         # (the promql twin of physical.aot_kernel_call's discipline)
         compiling = jit_miss and not getattr(kern, "aot", False)
-        count_window_dispatch(len(tsids), p.num_sel, p.slab_w)
+        count_dispatch(p, args, len(tsids))
         out = self._timed_kernel(
             "window_kernel", lambda: kern(*args), jit_miss, compiling,
             kind=kind)
@@ -1088,7 +1168,7 @@ class PromEvaluator:
             cnt_kern = _count_max_kernel(ck)
             _KERNEL_CACHE[ck] = cnt_kern
         # sizing pass + matrix
-        count_window_dispatch(len(tsids), p.num_sel, p.slab_w, programs=2)
+        count_dispatch(p, args, len(tsids), programs=2)
         cnt_max = int(cnt_kern(*args))
         lmax = max(2, _pow2(cnt_max))
         mk = (p, "matrix", lmax)

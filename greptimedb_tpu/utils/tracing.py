@@ -55,6 +55,14 @@ M_WINDOW_ROWS = REGISTRY.counter(
     "Slab cells (padded matched series x slab width) gathered by "
     "dispatched PromQL window programs",
 )
+# Says the fold engaged: S_padded x the columns every [S, T, .] pass runs
+# over (promql/engine.py ``swept_columns``); equal to the counter above
+# where no pass sweeps a sentinel column.
+M_SWEPT_COLUMNS = REGISTRY.counter(
+    "greptime_promql_swept_columns_total",
+    "Slab columns (padded matched series x swept width) that each pass of "
+    "dispatched PromQL window programs runs over",
+)
 M_SELECTED_SERIES = REGISTRY.counter(
     "greptime_promql_selected_series_total",
     "Series the label matchers kept, of dispatched PromQL window programs",
@@ -67,10 +75,11 @@ M_PADDED_SERIES = REGISTRY.counter(
 
 
 def count_window_dispatch(selected: int, padded: int, slab_w: int,
-                          programs: int = 1) -> None:
-    """The three counters of one PromQL window dispatch: host integers
+                          swept: int, programs: int = 1) -> None:
+    """The four counters of one PromQL window dispatch: host integers
     off static shapes and the selection's length."""
     M_WINDOW_ROWS.inc(programs * padded * slab_w)
+    M_SWEPT_COLUMNS.inc(programs * padded * swept)
     M_SELECTED_SERIES.inc(programs * selected)
     M_PADDED_SERIES.inc(programs * padded)
 

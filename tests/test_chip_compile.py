@@ -14,6 +14,7 @@ fixture: the process that loads the TPU's library keeps it, so nothing
 here touches the topology at import, ``skipif`` or ``parametrize`` time.
 """
 
+import re
 import time
 
 import jax
@@ -188,6 +189,34 @@ CASES = {
 }
 
 
+# fused PromQL case -> (padded series S, steps T, the folded slab's columns
+# max(W, 128)): what every [S, T, .] pass of the program may read
+SWEPT = {"promql-fused": (PROM_SEL, 61, PROM_W),
+         "promql-fused-k8s-65536": (K8S_SEL, 61, K8S_W)}
+
+
+def _operands_of_step_passes(text: str, s: int, t: int) -> dict[str, int]:
+    """{instruction: its widest [s, X] operand} over the fusions of the
+    compiled program whose result holds an [s, t] array: the compare-
+    select-reduce passes over [s, t, X] (and the epilogue over [s, t])."""
+    shape_of, passes = {}, {}
+    for line in text.splitlines():
+        name, eq, rhs = line.strip().removeprefix("ROOT ").partition(" = ")
+        op = re.search(r" ([a-z][a-z0-9-]*)\(", rhs)
+        if not eq or not name.startswith("%") or op is None:
+            continue
+        shape_of[name] = rhs[:op.start()]
+        if op.group(1) == "fusion" and f"[{s},{t}]" in shape_of[name]:
+            passes[name] = re.findall(
+                r"%[\w.-]+", rhs[op.end():].split("), kind=")[0])
+    widths = {}
+    for name, operands in passes.items():
+        cols = [int(x) for o in operands for x in re.findall(
+            rf"\[{s},(\d+)\]", shape_of.get(o, ""))]
+        widths[name] = max(cols, default=0)
+    return widths
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_compiles_for_one_v5e(one_chip, case):
     fn, args = CASES[case](one_chip)
@@ -199,6 +228,15 @@ def test_compiles_for_one_v5e(one_chip, case):
              + mem.output_size_in_bytes)
     assert total < 12 << 30, f"{case}: {total} bytes on a 16 GB chip"
     assert seconds < 60, f"{case}: {seconds:.0f} s to compile"
+    if case in SWEPT:
+        # the sentinel chunk is folded away before the sweeps: no pass
+        # over [S, T, .] reads the gathered W + 128 columns, and none is
+        # a loop over series
+        s, t, width = SWEPT[case]
+        text = compiled.as_text()
+        assert " while(" not in text
+        passes = _operands_of_step_passes(text, s, t)
+        assert max(passes.values()) == width, passes
 
 
 def test_bucket_major_shards_over_the_mesh(topo, one_chip):

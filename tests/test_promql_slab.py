@@ -8,12 +8,16 @@ scrape (a narrow slab inside long series), irregular timestamps (the
 slab falls to its cap, the whole series row), series shorter than the
 slab and empty ones, counters that reset on both sides of the slab's
 first column, windows wholly before the first and after the last
-sample, ``offset`` and ``@``.  Stored DOUBLEs compute in f32 on the
+sample, ``offset`` and ``@``, and series laid so that each one's first
+readable row falls at a chosen place of its 128-row chunk (the fold's
+seam, ``Slab.fold``).  Stored DOUBLEs compute in f32 on the
 device, so the reference rounds its inputs to f32 and the comparison is
 by a tolerance suited to f32.
 """
 
+import importlib.util
 import math
+import os
 
 import numpy as np
 import pytest
@@ -29,8 +33,16 @@ T0_S = 10_000  # first scrape of every data set, seconds
 
 
 @pytest.fixture
-def db():
+def db(monkeypatch):
+    """One device: this file tests the slab's geometry, not its placement.
+    On the harness's eight virtual CPU devices the layout is row-sharded
+    (parallel/dist.py ``promql_row_shardings``), so every window program
+    opens with an in-process all-gather that needs all eight device
+    threads at once; under six xdist workers two of them can fail to
+    arrive, and XLA's ``rendezvous.cc`` aborts the worker after 60 s."""
+    monkeypatch.setenv("GREPTIME_MESH", "off")
     d = GreptimeDB()
+    assert d.mesh is None
     yield d
     d.close()
 
@@ -88,7 +100,25 @@ def _series(kind: str):
             f"s{i}": (t0 + 15_000 * np.arange(n),
                       _counter(rng, n, (0, 9, 0, 6)[i]))
             for i, n in enumerate((longest, longest - 1, 64, 2))}
+    if kind == "aligned":
+        # one 15 s scrape in one phase; the runs' lengths put each series'
+        # row ``FOLD_CUT`` (its first readable one on the fold grids) at
+        # column 0, 1, 64 and 127 of a 128-row chunk of the layout: the
+        # fold's seam falls at the slab's head, its middle and its end
+        lengths = dict(zip(("p", "s0", "s1", "s2", "s3"),
+                           (88, 641, 575, 575, 600)))
+        return {
+            name: (t0 + 15_000 * np.arange(n),
+                   _counter(rng, n, (0, 7, 0, 11, 5)[i]))
+            for i, (name, n) in enumerate(lengths.items())}
     raise AssertionError(kind)
+
+
+# samples of an "aligned" series at or before ``start − range`` of the fold
+# grids, and the chunk columns their first readable rows are laid at
+FOLD_CUT = 40
+FOLD_START_S = T0_S + RANGE_S + 15 * (FOLD_CUT - 1)
+FOLD_COLUMNS = {"s0": 0, "s1": 1, "s2": 64, "s3": 127}
 
 
 def load(db, data, table="m"):
@@ -116,6 +146,14 @@ SCENARIOS = {
                90_000, None),
     "at": ("regular", (T0_S + 900, T0_S + 1140, 60), f" @ {T0_S + 2000}",
            0, T0_S + 2000),
+    # W under one chunk (64), one chunk (128) and four (512); windows of
+    # 20 samples straddle the fold's seam wherever it falls inside W
+    "fold64": ("aligned", (FOLD_START_S, FOLD_START_S + 600, 60), "", 0,
+               None),
+    "fold128": ("aligned", (FOLD_START_S, FOLD_START_S + 1500, 60), "", 0,
+                None),
+    "fold512": ("aligned", (FOLD_START_S, FOLD_START_S + 6600, 120), "", 0,
+                None),
 }
 
 # kind -> [(function, takes a [range])]
@@ -284,17 +322,34 @@ def test_kind_against_plain_reference(db, scenario, kind):
             ev.step_ms, p.num_steps, RANGE_S * 1000, 15_000, longest)
         # only a grid longer than the data itself reaches the cap
         assert (p.slab_w < cap) == (scenario != "outside")
+    if dataset == "aligned":
+        assert p.slab_w == int(scenario[4:])
+        *_layout, val_s, row_ptr, _sel, _start = _args
+        assert val_s.shape[0] % 128 == 0
+        r0 = dict(zip(sorted(data), np.asarray(row_ptr)))
+        assert {name: (int(r0[name]) + FOLD_CUT) % 128
+                for name in FOLD_COLUMNS} == FOLD_COLUMNS
+
+
+# layout -> (data set, grid)
+EDGE_LAYOUTS = {
+    "lengths": ("lengths", (T0_S - 400, T0_S + 1100, 50)),
+    **{name: SCENARIOS[name][:2]
+       for name in ("fold64", "fold128", "fold512")},
+}
 
 
 @pytest.mark.parametrize("kind", sorted(pe.PromEvaluator._KIND_KEYS))
-def test_edge_forms_agree_bit_for_bit(db, monkeypatch, kind):
-    """Window edges by the [S, T, W] compare sweep and by the log-W search
-    are the same integers, and picks by a compare-select pass and by a
-    gather (min/max: the masked sweep and the sparse table) the same
-    values, so every output is the same bits."""
-    data = _series("lengths")
+@pytest.mark.parametrize("layout", sorted(EDGE_LAYOUTS))
+def test_edge_forms_agree_bit_for_bit(db, monkeypatch, layout, kind):
+    """Window edges by the compare sweep over the FOLDED slab and by the
+    log-W search along the gathered one place the same samples in every
+    window, and picks by a compare-select pass and by a gather (min/max:
+    the masked sweep and the sparse table) read the same values, so every
+    output is the same bits: the fold moved the layout and nothing else."""
+    dataset, grid = EDGE_LAYOUTS[layout]
+    data = _series(dataset)
     load(db, data)
-    grid = (T0_S - 400, T0_S + 1100, 50)
     func, ranged = KIND_FUNCS[kind][0]
 
     def run():
@@ -356,25 +411,80 @@ def test_one_program_for_every_hour_and_mode(db):
     assert np.isfinite(first).all() and np.isfinite(second).all()
 
 
+ROWS = "greptime_promql_window_rows_total"
+SWEPT = "greptime_promql_swept_columns_total"
+
+
+@pytest.mark.parametrize("slab_w, rows, swept", [
+    (64, 2048, 128),       # W under one 128-row chunk: half is sentinels
+    (128, 2048, 128),      # k8s100k.namespace_cpu's: one chunk, folded
+    (512, 2048, 512),      # node64.cpu_rate's: four chunks, folded
+    (8192, 1 << 20, 8192),  # the widest slab that is swept
+    (16384, 1 << 20, 16384 + 128),  # searched: the gathered chunks stay
+    (8, 8 * 1250, 16),     # a layout that tiles by 16 rows only
+])
+def test_swept_columns_of_a_shape_class(slab_w, rows, swept):
+    assert pe.swept_columns(slab_w, rows) == swept
+
+
+def _counted(db, name, query, start_s=T0_S + 4000, span_s=3600):
+    """How far counter ``name`` moves over one evaluation of ``query``."""
+    before = REGISTRY.value(name, ())
+    ev = pe.PromEvaluator(db, start_s, start_s + span_s, 60)
+    ev.eval(parse_promql(query))
+    return REGISTRY.value(name, ()) - before
+
+
+def _slab_fill_reader():
+    """benchmark/layer_metrics/slab_fill_pct.py, loaded by its path."""
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                        "benchmark", "layer_metrics", "slab_fill_pct.py")
+    spec = importlib.util.spec_from_file_location("slab_fill_pct", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    return reader
+
+
+def test_swept_columns_counter_advances_by_folded_cells(db):
+    """Padded series x max(W, 128) a dispatch: what every [S, T, .] pass
+    of the program runs over, from the helper the geometry sizes with."""
+    _node_fleet(db)
+    # W = 512 is four whole chunks: the fold leaves no sentinel column
+    assert _counted(db, SWEPT, 'sum by (cpu)(rate(cpu{mode="user"}[5m]))') \
+        == 4 * 512
+    assert _counted(db, SWEPT, 'rate(cpu[5m])') == 8 * 512
+    assert _counted(db, SWEPT,
+                    'quantile_over_time(0.5, cpu{mode="idle"}[5m])') \
+        == 2 * 4 * 512
+    # the lookback alone is W = 32: the sweeps still run over one chunk
+    assert _counted(db, SWEPT, "cpu", span_s=0) == 8 * 128
+
+    # the benchmark's reader divides the two counters
+    def snap():
+        return {name: REGISTRY.value(name, ()) for name in (ROWS, SWEPT)}
+
+    read = _slab_fill_reader().read
+    before = snap()
+    _counted(db, SWEPT, 'rate(cpu[5m])')
+    after = snap()
+    assert read({"metrics_before": before, "metrics_after": after}) == 100.0
+    _counted(db, SWEPT, "cpu", span_s=0)
+    assert read({"metrics_before": after, "metrics_after": snap()}) == 25.0
+    # the parent's program has no such counter; an idle window no dispatch
+    assert read({"metrics_before": {}, "metrics_after": {ROWS: 9.0}}) is None
+    assert read({"metrics_before": after, "metrics_after": after}) is None
+
+
 def test_window_rows_counter_advances_by_slab_cells(db):
     _node_fleet(db)
-    name = "greptime_promql_window_rows_total"
-
-    def cells(query, start_s=T0_S + 4000):
-        before = REGISTRY.value(name, ())
-        ev = pe.PromEvaluator(db, start_s, start_s + 3600, 60)
-        ev.eval(parse_promql(query))
-        return REGISTRY.value(name, ()) - before
-
     # four matched series (padded to 4) x W = 512, a dispatch
-    assert cells('sum by (cpu)(rate(cpu{mode="user"}[5m]))') == 4 * 512
+    assert _counted(db, ROWS, 'sum by (cpu)(rate(cpu{mode="user"}[5m]))') \
+        == 4 * 512
     # every series, unfused
-    assert cells('rate(cpu[5m])') == 8 * 512
+    assert _counted(db, ROWS, 'rate(cpu[5m])') == 8 * 512
     # a matrix kernel dispatches its sizing pass and itself
-    assert cells('quantile_over_time(0.5, cpu{mode="idle"}[5m])') \
+    assert _counted(db, ROWS,
+                    'quantile_over_time(0.5, cpu{mode="idle"}[5m])') \
         == 2 * 4 * 512
     # an instant vector at one step gathers the lookback only: 300 s / 15 s
-    ev = pe.PromEvaluator(db, T0_S + 4000, T0_S + 4000, 60)
-    before = REGISTRY.value(name, ())
-    ev.eval(parse_promql("cpu"))
-    assert REGISTRY.value(name, ()) - before == 8 * 32
+    assert _counted(db, ROWS, "cpu", span_s=0) == 8 * 32
