@@ -112,10 +112,9 @@ def _build_fused(p, func, op, ng, n_sel, range_s):  # gl: warm-path
     body = pe._window_body(p)
     S = p.num_sel
 
-    def fused(*args):
-        gid = args[-1]  # [n_sel] i32 group ids (dense, first-appearance)
-        out = body(*args[:-1])
-        start_ms = args[-2]
+    def fused(layout, sel_tsids, start_ms, gid):
+        # gid: [n_sel] i32 group ids (dense, first-appearance)
+        out = body(layout, sel_tsids, start_ms)
         v = _apply_func(func, p, out, start_ms, range_s)  # [S, T]
         pad = S - n_sel
         gid_full = (
@@ -223,7 +222,7 @@ def try_fused_aggregation(ev, e):
                     return jax.device_put(a, sh["rows"])
             return a
 
-        fused_args = tuple(place(a) for a in fused_args)
+        fused_args = jax.tree.map(place, fused_args)
     # AOT-store hits deserialize — first call is NOT an XLA compile
     compiling = jit_miss and not getattr(kern, "aot", False)
     pe.count_dispatch(p, args, len(tsids))

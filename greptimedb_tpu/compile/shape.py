@@ -53,9 +53,13 @@ def _norm(v) -> str | None:
         return "{" + ",".join(parts) + "}"
     if dataclasses.is_dataclass(v) and not isinstance(v, type):
         # WindowParams and friends: field order is the class definition,
-        # stable across processes
+        # stable across processes; a field added later says
+        # ``omit_default`` and is left out at its default, so the classes
+        # that were there keep their ids (and their stored artifacts)
         fields = [(f.name, _norm(getattr(v, f.name)))
-                  for f in dataclasses.fields(v)]
+                  for f in dataclasses.fields(v)
+                  if not (f.metadata.get("omit_default")
+                          and getattr(v, f.name) == f.default)]
         if any(p is None for _n, p in fields):
             return None
         inner = ",".join(f"{n}={p}" for n, p in fields)

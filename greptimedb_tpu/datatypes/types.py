@@ -112,6 +112,16 @@ class ConcreteDataType(enum.Enum):
         tree/compensated reductions where precision matters (Prometheus
         semantics, SURVEY.md §7.3 item 7); final scalar touch-up happens on
         host in f64.
+
+        This is the dtype of the column every consumer reads.  What f32
+        drops of a DOUBLE is not lost where it matters: a column that
+        holds a magnitude of 2^24 or more (a byte counter) keeps the
+        remainder as a second f32 column in the resident table
+        (storage/cache.py ``low_word_col``; two words are 48 bits, exact
+        for whole numbers under 2^49), and the PromQL window programs
+        read every value as the two words joined (promql/engine.py
+        ``WindowParams.wide``).  SQL and the grid read this f32 column
+        alone.
         """
         if self.is_string_like:
             return np.dtype(np.int32)

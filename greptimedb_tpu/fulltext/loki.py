@@ -224,8 +224,8 @@ class LokiEvaluator:
         """[S, T] window values + per-series labels + step timestamps.
         Windows are PromQL's left-exclusive (t - range, t]."""
         from greptimedb_tpu.promql.engine import (
-            _KERNEL_CACHE, WindowParams, _window_kernel, search_bits,
-            slab_width,
+            _KERNEL_CACHE, SortLayout, WindowParams, _window_kernel,
+            search_bits, slab_width,
         )
 
         q = agg.query
@@ -274,13 +274,13 @@ class LokiEvaluator:
             if kern is None:
                 kern = _window_kernel(p)
                 _KERNEL_CACHE[p] = kern
-            out = kern(ts_hi, ts_lo, vals, row_ptr, sel_dev,
+            out = kern(SortLayout(ts_hi, ts_lo, vals, row_ptr), sel_dev,
                        np.int64(start_u))
             sums = np.asarray(out["sum"])[: len(sel_tsids)]  # gl: allow[GL-H001] -- THE one [S, T] result readback per metric eval
             if ind is vals:
                 counts = sums
             else:
-                out2 = kern(ts_hi, ts_lo, ind, row_ptr, sel_dev,
+                out2 = kern(SortLayout(ts_hi, ts_lo, ind, row_ptr), sel_dev,
                             np.int64(start_u))
                 counts = np.asarray(out2["sum"])[: len(sel_tsids)]
         values = self._finish_range_fn(agg, sums, range_u)

@@ -242,6 +242,11 @@ class WindowParams:
     # (``search_bits``): 2**run_bits exceeds the layout's longest run, so it
     # moves only when the longest series doubles
     run_bits: int
+    # the layout carries the values' low word (``SelectorData.sort_layout``:
+    # a DOUBLE column that passes 2^24): the kernel takes one array more
+    # and works on f64(high) + f64(low).  Left out of a narrow class's
+    # canonical key (compile/shape.py), which stays what it was
+    wide: bool = field(default=False, metadata={"omit_default": True})
 
 
 _KERNEL_CACHE: dict[WindowParams, object] = {}
@@ -275,6 +280,15 @@ def _join_i64(hi, lo):
     return (hi.astype(jnp.int64) << 32) | lo.astype(jnp.int64)
 
 
+def _join_f64(hi, lo=None):
+    """A value from its f32 words: the one word of a narrow layout as it
+    is, the two of a wide one (storage/cache.py ``low_word_col``) as
+    f64(hi) + f64(lo) — the form the TPU itself keeps an f64 in."""
+    if lo is None:
+        return hi
+    return hi.astype(jnp.float64) + lo.astype(jnp.float64)
+
+
 def slab_width(step_ms: int, num_steps: int, range_ms: int, spacing: int,
                max_run: int) -> int:
     """Slab width W for one shape class: every sample a series can have
@@ -306,13 +320,33 @@ def swept_columns(slab_w: int, n: int) -> int:
     return readable if slab_w <= _SWEEP_WIDTH else readable + c
 
 
+class SortLayout(typing.NamedTuple):
+    """A resident table's columns in (tsid, ts) order with the row pointer
+    (``_build_sort_layout``): the one argument every window program takes
+    its table as.  WIDE where it carries the values' low word (a DOUBLE
+    column that passes 2^24, storage/cache.py ``low_word_col``); a narrow
+    layout has ``None`` there, which is no leaf, so its programs take the
+    four arrays they always took."""
+
+    ts_hi: jnp.ndarray  # [N] i32, the timestamps' words (``_split_i64``)
+    ts_lo: jnp.ndarray  # [N] u32
+    val_s: jnp.ndarray  # [N] f32
+    row_ptr: jnp.ndarray  # [total_series + 1] i32
+    val_lo: "jnp.ndarray | None" = None  # [N] f32: v − f64(val_s)
+
+    @property
+    def wide(self) -> bool:
+        return self.val_lo is not None
+
+
 def count_dispatch(p: WindowParams, args, selected: int,
                    programs: int = 1) -> None:
     """Count one dispatch of ``programs`` window programs of class ``p``
     over the kernel arguments ``args`` (``_prep_window``'s)."""
     count_window_dispatch(
         selected, p.num_sel, p.slab_w,
-        swept_columns(p.slab_w, args[2].shape[0]), programs)
+        swept_columns(p.slab_w, args[0].val_s.shape[0]), programs,
+        wide=p.wide)
 
 
 def _count_le(probe, length, thr, bits: int):
@@ -349,7 +383,7 @@ def _row_pointer(ts_s, tsid_s, n_valid, total_series: int):
 
 
 @named_jit("promql_sort_layout", static_argnums=4)
-def _build_sort_layout(ts, val, tsid, mask, total_series: int):
+def _build_sort_layout(ts, val, tsid, mask, total_series: int, val_lo=None):
     """Composite-key sort of a resident table, QUERY-INDEPENDENT: the key
     packs (tsid, ts − ts_min) with a stride covering the table's full time
     span, so the permutation (and everything derived from it) depends
@@ -362,7 +396,13 @@ def _build_sort_layout(ts, val, tsid, mask, total_series: int):
     Returns (ts_hi, ts_lo, val_s, row_ptr, spacing, max_run): the sorted
     columns (timestamps as ``_split_i64`` words) and ``_row_pointer``'s
     geometry (spacing/max_run are 0-d; the caller reads them once, as
-    host integers, when the layout is built).
+    host integers, when the layout is built).  With ``val_lo`` (the
+    resident table's low word of a DOUBLE column, storage/cache.py) one
+    output more, the low word sorted the same way: a value travels as two
+    f32 words the way a timestamp travels as two 32-bit words.  Without
+    it the program is, text for text, the one it was before there were
+    wide layouts (a compile cache that holds it keeps serving it);
+    ``SelectorData.sort_layout`` names the outputs (``SortLayout``).
     """
     valid = mask & ~jnp.isnan(val)
     any_valid = valid.any()
@@ -376,8 +416,9 @@ def _build_sort_layout(ts, val, tsid, mask, total_series: int):
                     _I64_MAX)
     order = jnp.argsort(key)
     ts_s = ts[order]
-    return _split_i64(ts_s) + (val[order],) + _row_pointer(
+    out = _split_i64(ts_s) + (val[order],) + _row_pointer(
         ts_s, tsid[order], valid.sum(dtype=jnp.int32), total_series)
+    return out if val_lo is None else out + (val_lo[order],)
 
 
 def _fold(a, off, f: int):
@@ -408,7 +449,7 @@ class Slab(typing.NamedTuple):
     index into a column of a folded array."""
 
     rel: jnp.ndarray  # [S, G] ts − start_ms; sentinels outside the run
-    val: jnp.ndarray  # [S, G] f32, 0 outside the run
+    val: jnp.ndarray  # [S, G] 0 outside the run; f32, f64 off a wide layout
     ok: jnp.ndarray  # [S, G] column holds a readable sample of the series
     off: jnp.ndarray  # [S] i32 gathered column of the first readable row
     lo: jnp.ndarray  # [S, T] index of each window's first sample
@@ -453,7 +494,7 @@ def _slab_edges(rel, thr, sweep: bool):
         jnp.broadcast_to(thr[None, :], (S, T)), w.bit_length())
 
 
-def _slab_geometry(p: WindowParams, ts_hi, ts_lo, val_s, row_ptr, sel_tsids,
+def _slab_geometry(p: WindowParams, layout: SortLayout, sel_tsids,
                    start_ms) -> Slab:
     """Shared window geometry for all window kernels over a PRESORTED
     resident layout (_build_sort_layout): the ONE definition the stats
@@ -468,7 +509,13 @@ def _slab_geometry(p: WindowParams, ts_hi, ts_lo, val_s, row_ptr, sel_tsids,
     matched series, not to the table.  Where the slab is swept
     (``Slab.sweep``) the edges are counted on the folded slab and come
     out relative to the first readable sample; where it is searched, on
-    the gathered one, relative to its first column."""
+    the gathered one, relative to its first column.
+
+    Off a wide layout the values' low word is gathered beside the high
+    one by the same chunks and joined with it, so ``Slab.val`` is f64
+    there and no consumer of the slab reads half a value."""
+    ts_hi, ts_lo, val_s, row_ptr = (layout.ts_hi, layout.ts_lo,
+                                    layout.val_s, layout.row_ptr)
     T, S, w = p.num_steps, p.num_sel, p.slab_w
     n = val_s.shape[0]
     # padding slots (-1) and series newer than the layout own no rows
@@ -500,7 +547,11 @@ def _slab_geometry(p: WindowParams, ts_hi, ts_lo, val_s, row_ptr, sel_tsids,
         return a.reshape(n // c, c)[
             jnp.clip(chunk, 0, n // c - 1)].reshape(S, k * c)
 
-    val = jnp.where(ok, take(val_s), 0.0)
+    # a wide layout's two words are joined here, once, so that whatever
+    # reads ``Slab.val`` reads all of the value (exact: |low| is under
+    # half an ulp of high)
+    val = jnp.where(ok, _join_f64(
+        take(val_s), take(layout.val_lo) if layout.wide else None), 0.0)
     # timestamps rebased to start_ms; int32 where the query's span fits
     # (the compare sweep is the slab's widest pass): integer compares stay
     # exact, and a sample beyond the span saturates below the sentinel
@@ -564,8 +615,7 @@ def _range_extreme(level, lo, hi, cnt, op):
 def _window_kernel(p: WindowParams):  # gl: warm-path
     """Build the jitted kernel computing window stats for selected series.
 
-    Inputs: the presorted resident layout (ts_hi [N] i32, ts_lo [N] u32,
-            val_s [N] f32, row_ptr [total_series + 1] i32 — see
+    Inputs: the presorted resident layout (a ``SortLayout``, see
             _build_sort_layout), sel_tsids [S] i32 (padded with -1),
             start_ms scalar i64.
     Output dict of [S, T] arrays depending on p.kind.
@@ -583,9 +633,8 @@ def _window_body(p: WindowParams):  # gl: warm-path
 
     S = p.num_sel
 
-    def kernel(ts_hi, ts_lo, val_s, row_ptr, sel_tsids, start_ms):
-        slab = _slab_geometry(p, ts_hi, ts_lo, val_s, row_ptr, sel_tsids,
-                              start_ms)
+    def kernel(layout, sel_tsids, start_ms):
+        slab = _slab_geometry(p, layout, sel_tsids, start_ms)
         lo, hi, cnt, has, sel_ok = (slab.lo, slab.hi, slab.cnt, slab.has,
                                     slab.sel_ok)
         sweep, w = slab.sweep, slab.width
@@ -608,6 +657,14 @@ def _window_body(p: WindowParams):  # gl: warm-path
 
         def pick_ts(i):
             return start_ms + pick(rel, i).astype(jnp.int64)
+
+        # ``g_val`` is f32, or f64 off a wide layout (``p.wide``): every
+        # compare, difference and prefix below reads it as it is, and what
+        # leaves the kernel as a value is rounded once, after the
+        # difference — the extrapolation, the sum over series and the reply
+        # are f32 either way (on f32 the cast is no operation)
+        def f32(x):
+            return x.astype(jnp.float32)
 
         # per-series counter-reset adjustment (for counter kinds).  A
         # window never reads a drop at or before its own first sample, so
@@ -648,7 +705,7 @@ def _window_body(p: WindowParams):  # gl: warm-path
                       "instant"):
             out["count"] = jnp.where(has, fcnt, 0.0)
         if p.kind == "instant":
-            out["last"] = jnp.where(has, pick(val, last_i), nan)
+            out["last"] = jnp.where(has, f32(pick(val, last_i)), nan)
             out["last_ts"] = jnp.where(has, pick_ts(last_i), 0)
         if p.kind == "counter":
             fv = pick(val, first_i)
@@ -657,10 +714,10 @@ def _window_body(p: WindowParams):  # gl: warm-path
                 jnp.float32)
             out["first_ts"] = jnp.where(has, pick_ts(first_i), 0)
             out["last_ts"] = jnp.where(has, pick_ts(last_i), 0)
-            out["first_val"] = jnp.where(has, fv, nan)
-            out["last_val"] = jnp.where(has, lv, nan)
+            out["first_val"] = jnp.where(has, f32(fv), nan)
+            out["last_val"] = jnp.where(has, f32(lv), nan)
             out["delta_adj"] = jnp.where(has2, d_adj, nan)
-            out["delta_raw"] = jnp.where(has2, lv - fv, nan)
+            out["delta_raw"] = jnp.where(has2, f32(lv - fv), nan)
         if p.kind == "counter_rc":
             # resets/changes counts via indicator cumsums — a SEPARATE
             # kind so the (much hotter) rate/increase/delta path doesn't
@@ -683,8 +740,8 @@ def _window_body(p: WindowParams):  # gl: warm-path
             mean = s.astype(jnp.float64) / jnp.maximum(cnt, 1)
             var = sum2_64 / jnp.maximum(cnt, 1) - mean * mean
             out["var"] = jnp.where(has, jnp.maximum(var, 0.0).astype(jnp.float32), nan)
-            out["last"] = jnp.where(has, pick(val, last_i), nan)
-            out["first"] = jnp.where(has, pick(val, first_i), nan)
+            out["last"] = jnp.where(has, f32(pick(val, last_i)), nan)
+            out["first"] = jnp.where(has, f32(pick(val, first_i)), nan)
             out["first_ts"] = jnp.where(has, pick_ts(first_i), 0)
             out["last_ts"] = jnp.where(has, pick_ts(last_i), 0)
         if p.kind == "regression":
@@ -701,11 +758,16 @@ def _window_body(p: WindowParams):  # gl: warm-path
             out["last_ts"] = jnp.where(has, pick_ts(last_i), 0)
         if p.kind == "irate":
             prev_i = jnp.clip(hi - 2, 0, w - 1)
+            # the pair ``_instant_pair`` differences leaves as it was
+            # read (f64 off a wide layout): f32 after the difference
             out["last_ts"] = jnp.where(has2, pick_ts(last_i), 0)
             out["prev_ts"] = jnp.where(has2, pick_ts(prev_i), 0)
             out["last_val"] = jnp.where(has2, pick(val, last_i), nan)
             out["prev_val"] = jnp.where(has2, pick(val, prev_i), nan)
         if p.kind == "minmax":
+            # rounding keeps order, so the extreme of the rounded samples
+            # is the rounded extreme: the sweep stays f32 on a wide layout
+            val, g_val = f32(val), f32(g_val)
             if sweep:
                 # reduce over the folded slab under the edge mask
                 j = slab.index()[:, None, :]
@@ -731,8 +793,8 @@ def _count_max_kernel(p: WindowParams):  # gl: warm-path
     kernels' static padded width (one cheap pass, cached per shape)."""
 
     @named_jit("promql_window_cnt_max")
-    def kernel(*layout_sel_start):
-        slab = _slab_geometry(p, *layout_sel_start)
+    def kernel(layout, sel_tsids, start_ms):
+        slab = _slab_geometry(p, layout, sel_tsids, start_ms)
         return jnp.max(jnp.where(slab.sel_ok[:, None], slab.cnt, 0))
 
     return kernel
@@ -756,18 +818,19 @@ def _matrix_kernel(p: WindowParams, lmax: int, kind: str):  # gl: warm-path
     T, S = p.num_steps, p.num_sel
 
     @named_jit(f"promql_matrix_{kind}")
-    def kernel(*args):
-        *layout_sel_start, a1, a2 = args
-        slab = _slab_geometry(p, *layout_sel_start)
+    def kernel(layout, sel_tsids, start_ms, a1, a2):
+        slab = _slab_geometry(p, layout, sel_tsids, start_ms)
         has = slab.has
         cntf = slab.cnt.reshape(-1)  # [S*T]
         j = jnp.arange(lmax, dtype=jnp.int32)
         idx = slab.col(jnp.clip(
             slab.lo[:, :, None] + j[None, None, :], 0, slab.width - 1))
         # [S*T, L] time-ordered window samples
+        # rounded to f32 off a wide layout: an order statistic of the
+        # rounded samples is the rounded order statistic
         rows = jnp.take_along_axis(
-            slab.fold(slab.val)[:, None, :], idx, axis=2).reshape(
-                S * T, lmax)
+            slab.fold(slab.val).astype(jnp.float32)[:, None, :], idx,
+            axis=2).reshape(S * T, lmax)
         ok = j[None, :] < cntf[:, None]
         nan = jnp.float32(jnp.nan)
         inf = jnp.float32(jnp.inf)
@@ -932,13 +995,17 @@ class SelectorData:
 
     def sort_layout(self, fieldcol: str) -> tuple:
         """The resident composite-key sort of this table for ``fieldcol``
-        (see _build_sort_layout) with its row pointer: (ts_hi, ts_lo,
-        val_s, row_ptr, spacing, max_run), the last two host integers.  Served
+        (see _build_sort_layout) with its row pointer: (SortLayout,
+        spacing, max_run), the last two host integers.  Served
         from PromLayoutCache per (resident-table dicts_version, field
         column); a miss builds and — if admission under the promql_cache
         workload quota succeeds — stores it.  A rejected build serves
         this eval transiently from the same arrays (reject-to-fallback,
-        bit-exact either way)."""
+        bit-exact either way).
+
+        WIDE (``SortLayout.val_lo``) where the resident table kept the
+        column's low word (a DOUBLE that passes 2^24, storage/cache.py
+        ``low_word_col``): read off the column, never off an option."""
         cache = self.promql_cache()
         rid = getattr(self.region, "region_id", None)
         version = self.table.dicts_version
@@ -948,23 +1015,31 @@ class SelectorData:
                 self.events["sort_hit"] += 1
                 return payload
             self.events["sort_miss"] += 1
+        from greptimedb_tpu.storage.cache import low_word_col
+
         cols = self.table.columns
-        *arrays, spacing, max_run = _build_sort_layout(
-            cols[self.ts_name], cols[fieldcol], cols[TSID],
-            self.table.row_mask, max(self.region.num_series, 1))
+        ts_hi, ts_lo, val_s, row_ptr, spacing, max_run, *val_lo = \
+            _build_sort_layout(
+                cols[self.ts_name], cols[fieldcol], cols[TSID],
+                self.table.row_mask, max(self.region.num_series, 1),
+                cols.get(low_word_col(fieldcol)))
+        arrays = SortLayout(ts_hi, ts_lo, val_s, row_ptr, *val_lo)
         if cache is not None and cache.mesh is not None:
             from greptimedb_tpu.parallel.dist import promql_row_shardings
 
-            sh = promql_row_shardings(cache.mesh, int(arrays[0].shape[0]))
+            sh = promql_row_shardings(cache.mesh,
+                                      int(arrays.val_s.shape[0]))
             if sh is not None:
                 # the sorted columns split by rows; the row pointer is
                 # small and read whole by every device
-                arrays[:3] = [jax.device_put(a, sh["rows"])
-                              for a in arrays[:3]]
+                arrays = jax.tree.map(
+                    lambda a: jax.device_put(a, sh["rows"]),
+                    arrays._replace(row_ptr=None),
+                )._replace(row_ptr=arrays.row_ptr)
         spacing, max_run = jax.device_get((spacing, max_run))
-        layout = (*arrays, int(spacing), int(max_run))
+        layout = (arrays, int(spacing), int(max_run))
         if cache is not None and rid is not None:
-            nbytes = sum(int(a.nbytes) for a in arrays)
+            nbytes = sum(int(a.nbytes) for a in jax.tree.leaves(arrays))
             if cache.admit(nbytes):
                 cache.store("sort", rid, (fieldcol,), version, layout,
                             nbytes)
@@ -1092,7 +1167,8 @@ class PromEvaluator:
             start = self.start_ms - offset_ms
             num_steps = self.num_steps
         with TRACER.stage("sort_layout") as st:
-            *layout, spacing, max_run = d.sort_layout(fieldcol)
+            layout, spacing, max_run = d.sort_layout(fieldcol)
+            st.set(value_words=2 if layout.wide else 1)
         self._stage_mark("sort_layout", st)
         p = WindowParams(
             step_ms=self.step_ms,
@@ -1104,8 +1180,9 @@ class PromEvaluator:
             slab_w=slab_width(self.step_ms, num_steps, int(rng), spacing,
                               max_run),
             run_bits=search_bits(max_run),
+            wide=layout.wide,
         )
-        args = (*layout, sel_dev, np.int64(start))
+        args = (layout, sel_dev, np.int64(start))
         return args, p, tsids, labels, pinned, start, int(rng)
 
     def _run_window(
@@ -1977,6 +2054,7 @@ def _instant_pair(f: str, last_ts, prev_ts, last_val, prev_val,
     dv = last_val - prev_val
     if f == "irate":
         dv = jnp.where(dv < 0, last_val, dv)  # counter reset
+    dv = dv.astype(jnp.float32)  # a wide layout's pair is f64 up to here
     ok = dt > 0
     if guard is not None:
         ok = ok & guard

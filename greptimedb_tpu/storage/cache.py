@@ -4,10 +4,21 @@ queries.
 The TPU answer to the reference's tiered read cache
 (src/mito2/src/cache/: page/vector caches keep decoded batches hot in RAM;
 here the hot tier is HBM). A region's merged scan result is canonicalized
-once — tags to int32 codes, ts to int64, fields to f32, rows padded to a
-shape-class bucket — and uploaded; queries then jit straight over the
-cached tensors. Invalidation is by region generation (bumped on every
-write/flush/compact).
+once — tags to int32 codes, ts to int64, fields to their device dtype
+(a DOUBLE to f32), rows padded to a shape-class bucket — and uploaded;
+queries then jit straight over the cached tensors. Invalidation is by
+region generation (bumped on every write/flush/compact).
+
+A DOUBLE field whose column holds a magnitude f32 cannot count by one
+(|v| ≥ 2^24: a byte counter) keeps what f32 dropped as a second f32
+column beside it (``low_word_col``): v = f64(high) + f64(low) to 48
+bits, exact for whole numbers under 2^49.  It reaches HBM with the
+build, survives an extend and a flush, and is read by the PromQL sort
+layout alone (promql/engine.py: the window programs join the words, so
+a counter's increase and a reset are judged on all of the value); SQL,
+the grid and every other consumer read the f32 column as before.  A column of
+small magnitudes (CPU seconds, percentages) has no such companion and
+nothing about it changes.
 
 Capacity: simple LRU by bytes; eviction drops device references and lets
 JAX free HBM.
@@ -26,6 +37,7 @@ import numpy as np
 
 from greptimedb_tpu.datatypes.batch import pad_rows
 from greptimedb_tpu.datatypes.schema import Schema
+from greptimedb_tpu.datatypes.types import ConcreteDataType
 from greptimedb_tpu.storage.memtable import (
     SEQ, TAGCODE_PREFIX, TSID, tagcode_col,
 )
@@ -196,6 +208,34 @@ def _canonical_column(
     return arr  # internal numeric column (e.g. __op__)
 
 
+# from here on an f32 no longer holds every whole number
+_F32_WHOLE = float(1 << 24)
+
+
+def low_word_col(name: str) -> str:
+    """The resident column that holds what f32 dropped of DOUBLE field
+    ``name`` (absent where the field's magnitudes stay under 2^24)."""
+    return f"__lo_{name}__"
+
+
+def _low_word(schema: Schema, name: str, arr: np.ndarray, high: np.ndarray,
+              always: bool = False) -> "np.ndarray | None":
+    """f32(v − f64(f32(v))) of a DOUBLE field's host values, or None for
+    another kind of column and — unless ``always`` — for one whose
+    magnitudes all stay under 2^24.  0 where f32(v) is not finite."""
+    if name == TSID or not schema.has_column(name):
+        return None
+    c = schema.column(name)
+    if c.is_tag or c.dtype is not ConcreteDataType.FLOAT64:
+        return None
+    arr = np.asarray(arr, dtype=np.float64)
+    with np.errstate(invalid="ignore"):   # NaN (NULL) compares false
+        if not always and not (np.abs(arr) >= _F32_WHOLE).any():
+            return None
+        return np.where(np.isfinite(high), arr - high.astype(np.float64),
+                        0.0).astype(np.float32)
+
+
 def _pad_value(schema: Schema, name: str, dtype: np.dtype):
     """Padding-row fill for a canonicalized column: poison code -1 for
     tag/string-dict columns, NaN for floats, 0 otherwise."""
@@ -245,6 +285,11 @@ def build_device_table(
         out[:n] = vals
         host_canon[name] = vals
         dev_cols[name] = _to_device(out)
+        low = _low_word(schema, name, arr, vals)
+        if low is not None:
+            out = np.zeros(padded, dtype=np.float32)
+            out[:n] = low
+            dev_cols[low_word_col(name)] = _to_device(out)
     mask = np.zeros(padded, dtype=bool)
     mask[:n] = True
     # monotone tag detection: rows are (tsid, ts)-sorted; a tag qualifies
@@ -269,12 +314,14 @@ def build_device_table(
 
 
 def _canonical_delta(
-    region, chunks: list[dict], dicts: dict[str, list]
-) -> tuple[dict[str, np.ndarray], int]:
+    region, chunks: list[dict], dicts: dict[str, list], resident
+) -> "tuple[dict[str, np.ndarray] | None, int]":
     """Canonicalize append-log chunks (same rules as build_device_table —
     shared _canonical_column — unpadded).  ``dicts`` holds the resident
     table's dictionaries and is extended in place so codes stay
-    consistent across deltas."""
+    consistent across deltas.  A DOUBLE field that has its low word among
+    the ``resident`` columns gets the delta's; one that has none and now
+    needs it (the delta passes 2^24) cannot be extended: None."""
     schema = region.schema
     host = {
         k: np.concatenate([np.asarray(c[k]) for c in chunks])
@@ -295,12 +342,18 @@ def _canonical_delta(
             continue
         out[name] = _canonical_column(schema, region.encoders, name, arr,
                                       dicts)
+        wide = low_word_col(name) in resident
+        low = _low_word(schema, name, arr, out[name], always=wide)
+        if low is not None:
+            if not wide:
+                return None, dn
+            out[low_word_col(name)] = low
     return out, dn
 
 
 def extend_device_table(
     table: DeviceTable, region, chunks: list[dict], live_rows: int
-) -> tuple[DeviceTable, int]:
+) -> "tuple[DeviceTable, int] | None":
     """Append new rows to a resident DeviceTable WITHOUT re-uploading the
     base: only the delta crosses host→device; growth beyond the padding
     bucket concatenates on device; the (tsid, ts) sort order every
@@ -310,9 +363,14 @@ def extend_device_table(
     Correctness precondition (enforced by Region's append log): delta rows
     are PUT-only with timestamps strictly after all resident rows, so no
     dedup/tombstone interaction with the base is possible.
+
+    Returns None where the delta needs a column the resident table was
+    built without (a DOUBLE field's low word): the caller rebuilds.
     """
     dicts = dict(table.dicts)
-    delta, dn = _canonical_delta(region, chunks, dicts)
+    delta, dn = _canonical_delta(region, chunks, dicts, table.columns)
+    if delta is None:
+        return None
     n_old = live_rows
     n_new = n_old + dn
     old_padded = table.padded_rows
@@ -485,19 +543,22 @@ class RegionCacheManager:
             chunks = _chunks_since(region, entry.delta_pos)
             delta_rows = (sum(len(c[TSID]) for c in chunks)
                           if chunks is not None else None)
+            extended = None
             if delta_rows is not None and delta_rows <= max(
                 self.min_extend_rows,
                 entry.live_rows * self.rebuild_fraction,
             ):
+                extended = extend_device_table(
+                    entry.table, region, chunks, entry.live_rows
+                )
+            if extended is not None:
                 with self._struct_lock:
                     self.extends += 1
                 M_CACHE_EVENTS.labels(
                     "region_device", "table", "extend").inc()
                 # whole-entry swap (not field mutation): a concurrent
                 # reader holds a self-consistent entry either way
-                new_table, new_rows = extend_device_table(
-                    entry.table, region, chunks, entry.live_rows
-                )
+                new_table, new_rows = extended
                 with self._struct_lock:
                     if self._lru.get(key) is entry:
                         # bytes delta only when the swap applies — an
@@ -522,7 +583,9 @@ class RegionCacheManager:
                         self._lru.move_to_end(key)
                 self._shrink()
                 return new_table
-            self._evict(key)  # too much drift (or trimmed past): rebuild
+            # too much drift, trimmed past, or a column the resident table
+            # was built without: rebuild
+            self._evict(key)
 
         with self._struct_lock:
             self.misses += 1

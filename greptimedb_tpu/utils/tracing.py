@@ -72,13 +72,24 @@ M_PADDED_SERIES = REGISTRY.counter(
     "Series slots (the selection padded to the program's static size) of "
     "dispatched PromQL window programs",
 )
+# Says the values' low word engaged: the share of the slab cells above
+# whose program ran on a WIDE layout (promql/engine.py ``WindowParams.wide``:
+# a DOUBLE column past 2^24 read as two f32 words).
+M_WIDE_ROWS = REGISTRY.counter(
+    "greptime_promql_wide_rows_total",
+    "Slab cells (padded matched series x slab width) of dispatched PromQL "
+    "window programs that read a two-word value column",
+)
 
 
 def count_window_dispatch(selected: int, padded: int, slab_w: int,
-                          swept: int, programs: int = 1) -> None:
-    """The four counters of one PromQL window dispatch: host integers
-    off static shapes and the selection's length."""
+                          swept: int, programs: int = 1,
+                          wide: bool = False) -> None:
+    """The counters of one PromQL window dispatch: host integers off
+    static shapes and the selection's length."""
     M_WINDOW_ROWS.inc(programs * padded * slab_w)
+    # by 0 off a narrow layout: the counter is there to be read as 0
+    M_WIDE_ROWS.inc(programs * padded * slab_w if wide else 0)
     M_SWEPT_COLUMNS.inc(programs * padded * swept)
     M_SELECTED_SERIES.inc(programs * selected)
     M_PADDED_SERIES.inc(programs * padded)
@@ -125,6 +136,11 @@ class _Stage:
         self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
+
+    def set(self, **attrs) -> None:
+        """Span attributes known only once the stage has run."""
+        if self._span is not None:
+            self._span["attributes"].update(attrs)
 
     def __exit__(self, exc_type, exc, tb):
         self.seconds = dt = time.perf_counter() - self._t0

@@ -69,6 +69,88 @@ def ineligible(monkeypatch):
     return road
 
 
+_BENCH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                      "benchmark")
+
+
+@pytest.fixture(scope="session")
+def bench_run():
+    """benchmark/run.py, imported the way its own tests import it."""
+    import importlib.util
+    import sys
+
+    if _BENCH not in sys.path:
+        sys.path.insert(0, _BENCH)
+    spec = importlib.util.spec_from_file_location(
+        "bench_run_for_tests", os.path.join(_BENCH, "run.py"))
+    run = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(run)
+    return run
+
+
+class ServedCell:
+    """One HTTP server holding one seed's data of one benchmark cell at
+    its rehearsal size, and the cell's traffic."""
+
+    def __init__(self, run, workload: str, seed: int):
+        from greptimedb_tpu.servers import HttpServer
+        from greptimedb_tpu.standalone import GreptimeDB
+
+        spec = run.load_json(run.ROOT, "BENCHMARK.json")
+        _entry, config, self.mix = run.load_cell(spec, workload)
+        self.family = run.load_module("queries", self.mix["family"])
+        self.cell = run.new_cell(config, rehearse=True, seed=seed)
+        self.run = run
+        self.db = GreptimeDB()
+        self.srv = HttpServer(self.db, port=0)
+        self.srv.start()
+        self.client = run.Client(self.srv.port)
+        ds, p = self.cell.ds, self.cell.params
+        for stmt in ds.ddl(p):
+            self.client.sql(stmt)
+        acked = sum(self.client.arrow_write(table, body)
+                    for table, body, _n in ds.arrow_bodies(self.cell.data, p))
+        assert acked == ds.rows(p)
+        assert self.client.sql(ds.count_sql(p))[0][0] == acked
+        self.traffic = run.Traffic(self.family, self.cell, self.mix, seed,
+                                   stream=1)
+
+    def judge(self, req, answer=None):
+        """The harness's verdict on the served reply to ``req``, or on
+        ``answer`` put in its place (the control)."""
+        if answer is not None:
+            return self.run.judge(self.family, self.cell, req, None, None,
+                                  answer=answer)
+        rec = self.run.exchange(self.client, req)
+        return self.run.judge(self.family, self.cell, req, rec["status"],
+                              rec["reply"])
+
+    def close(self):
+        self.client.close()
+        self.srv.stop()
+        self.db.close()
+
+
+@pytest.fixture
+def served(bench_run, request, monkeypatch):
+    """``@pytest.mark.parametrize("served", [(workload, seed)],
+    indirect=True)``: that cell, loaded and served by the default
+    ``GreptimeDB()``, which on the harness's eight virtual devices forms
+    the mesh (row-sharded layout, the fused program placed by
+    ``promql_row_shardings``).  ``(workload, seed, "one-device")`` serves
+    it with ``GREPTIME_MESH=off``: for further seeds of a case the mesh
+    already runs, since there a window program opens with an all-gather
+    that can starve under xdist (PERF.md 7.7)."""
+    workload, seed, *placement = request.param
+    assert placement in ([], ["one-device"]), placement
+    if placement:
+        monkeypatch.setenv("GREPTIME_MESH", "off")
+    s = ServedCell(bench_run, workload, seed)
+    assert (s.db.mesh is None) == bool(placement)
+    yield s
+    s.close()
+
+
 @pytest.fixture
 def tmp_data_dir(tmp_path):
     d = tmp_path / "data"

@@ -40,6 +40,11 @@ PROM_ROWS, PROM_SERIES, PROM_SEL, PROM_GROUPS, PROM_W = (
 # 30 min at 30 s over [5m] gathers 128 samples each
 K8S_SERIES, K8S_SEL, K8S_MATCHED, K8S_GROUPS, K8S_W = (
     105_000, 65_536, 63_000, 200, 128)
+# k8snet120k.namespace_bandwidth (benchmark/configs/prom-k8s-net-120k.json):
+# 126,000 byte counters a table x 1 h at 30 s in a WIDE layout of 14.68M
+# padded rows (the values as two f32 words); every series matches (131,072
+# padded) into 200 namespaces, the same 128 samples each
+NET_ROWS, NET_SERIES, NET_SEL = 14_680_064, 126_000, 131_072
 
 
 @pytest.fixture(scope="module")
@@ -113,7 +118,7 @@ def _grid_args(sh):
             _shape((), jnp.int64, sh), _shape((), jnp.int32, sh))
 
 
-def _window_params(step_ms, scrape_ms, run, sel, series, want_w):
+def _window_params(step_ms, scrape_ms, run, sel, series, want_w, wide=False):
     """The counter program's shape class for ``num_steps`` 61 over [5m]
     on a layout scraped every ``scrape_ms`` whose longest run is ``run``."""
     from greptimedb_tpu.promql.engine import (WindowParams, search_bits,
@@ -123,7 +128,7 @@ def _window_params(step_ms, scrape_ms, run, sel, series, want_w):
     assert w == want_w
     return WindowParams(step_ms=step_ms, num_steps=61, range_ms=300_000,
                         num_sel=sel, total_series=series, kind="counter",
-                        slab_w=w, run_bits=search_bits(run))
+                        slab_w=w, run_bits=search_bits(run), wide=wide)
 
 
 def _promql_params():
@@ -131,11 +136,15 @@ def _promql_params():
     return _window_params(60_000, 15_000, 2880, PROM_SEL, PROM_SERIES, PROM_W)
 
 
-def _layout_args(sh, series=PROM_SERIES, sel=PROM_SEL):
-    return (_shape((PROM_ROWS,), jnp.int32, sh),
-            _shape((PROM_ROWS,), jnp.uint32, sh),
-            _shape((PROM_ROWS,), jnp.float32, sh),
-            _shape((series + 1,), jnp.int32, sh),
+def _layout_args(sh, series=PROM_SERIES, sel=PROM_SEL, rows=PROM_ROWS,
+                 value_words=1):
+    from greptimedb_tpu.promql.engine import SortLayout
+
+    f32 = _shape((rows,), jnp.float32, sh)
+    return (SortLayout(_shape((rows,), jnp.int32, sh),
+                       _shape((rows,), jnp.uint32, sh), f32,
+                       _shape((series + 1,), jnp.int32, sh),
+                       f32 if value_words == 2 else None),
             _shape((sel,), jnp.int32, sh), _shape((), jnp.int64, sh))
 
 
@@ -161,6 +170,17 @@ def _k8s_fused():
     return _build_fused(p, "rate", "sum", K8S_GROUPS, K8S_MATCHED, 300)
 
 
+def _k8s_net_fused():
+    """sum by (namespace)(rate(container_network_receive_bytes_total{..}[5m]))
+    over the wide layout: ``_k8s_fused``'s program but for the value's
+    width."""
+    from greptimedb_tpu.compile.fused import _build_fused
+
+    p = _window_params(30_000, 30_000, 120, NET_SEL, NET_SERIES, K8S_W,
+                       wide=True)
+    return _build_fused(p, "rate", "sum", K8S_GROUPS, NET_SERIES, 300)
+
+
 def _segment(form, op, rows, sh):
     from greptimedb_tpu.ops import segment
 
@@ -181,6 +201,10 @@ CASES = {
         _k8s_fused(),
         _layout_args(sh, K8S_SERIES, K8S_SEL)
         + (_shape((K8S_MATCHED,), jnp.int32, sh),)),
+    "promql-fused-k8snet-131072": lambda sh: (
+        _k8s_net_fused(),
+        _layout_args(sh, NET_SERIES, NET_SEL, NET_ROWS, value_words=2)
+        + (_shape((NET_SERIES,), jnp.int32, sh),)),
     # the form `auto` takes on every backend, at table size
     "segment-scatter-mean": lambda sh: _segment("scatter", "mean", ROWS, sh),
     # the form only `force` reaches: its scan is minutes slow past this
@@ -192,7 +216,8 @@ CASES = {
 # fused PromQL case -> (padded series S, steps T, the folded slab's columns
 # max(W, 128)): what every [S, T, .] pass of the program may read
 SWEPT = {"promql-fused": (PROM_SEL, 61, PROM_W),
-         "promql-fused-k8s-65536": (K8S_SEL, 61, K8S_W)}
+         "promql-fused-k8s-65536": (K8S_SEL, 61, K8S_W),
+         "promql-fused-k8snet-131072": (NET_SEL, 61, K8S_W)}
 
 
 def _operands_of_step_passes(text: str, s: int, t: int) -> dict[str, int]:

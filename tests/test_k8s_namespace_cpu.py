@@ -10,10 +10,7 @@ codes, ``image!=""`` over empty and NULL tags, and the counters the
 cell's per-layer metrics read.
 """
 
-import importlib.util
 import json
-import os
-import sys
 import urllib.parse
 import urllib.request
 
@@ -25,69 +22,15 @@ from greptimedb_tpu.servers import HttpServer
 from greptimedb_tpu.standalone import GreptimeDB
 from greptimedb_tpu.utils.telemetry import REGISTRY
 
-BENCH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                     "benchmark")
 WORKLOAD = "k8s100k.namespace_cpu"
 SELECTED = "greptime_promql_selected_series_total"
 PADDED = "greptime_promql_padded_series_total"
 BUILDS = "greptime_compile_xla_builds_total"
 
 
-@pytest.fixture(scope="module")
-def bench():
-    """benchmark/run.py, imported the way its own tests import it."""
-    if BENCH not in sys.path:
-        sys.path.insert(0, BENCH)
-    spec = importlib.util.spec_from_file_location(
-        "bench_run_k8s", os.path.join(BENCH, "run.py"))
-    run = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(run)
-    return run
-
-
-class Served:
-    """One server holding one seed's data, and the cell's traffic."""
-
-    def __init__(self, run, seed: int):
-        spec = run.load_json(run.ROOT, "BENCHMARK.json")
-        _entry, config, self.mix = run.load_cell(spec, WORKLOAD)
-        self.family = run.load_module("queries", self.mix["family"])
-        self.cell = run.new_cell(config, rehearse=True, seed=seed)
-        self.run = run
-        self.db = GreptimeDB()
-        self.srv = HttpServer(self.db, port=0)
-        self.srv.start()
-        self.client = run.Client(self.srv.port)
-        ds, p = self.cell.ds, self.cell.params
-        for stmt in ds.ddl(p):
-            self.client.sql(stmt)
-        acked = sum(self.client.arrow_write(table, body)
-                    for table, body, _n in ds.arrow_bodies(self.cell.data, p))
-        assert acked == ds.rows(p)
-        assert self.client.sql(ds.count_sql(p))[0][0] == acked
-        self.traffic = run.Traffic(self.family, self.cell, self.mix, seed,
-                                   stream=1)
-
-    def judge(self, req):
-        rec = self.run.exchange(self.client, req)
-        return self.run.judge(self.family, self.cell, req, rec["status"],
-                              rec["reply"])
-
-    def close(self):
-        self.client.close()
-        self.srv.stop()
-        self.db.close()
-
-
-@pytest.fixture
-def served(bench, request):
-    s = Served(bench, request.param)
-    yield s
-    s.close()
-
-
-@pytest.mark.parametrize("served", [7, 2100000777, 1900000333],
-                         indirect=True)
+@pytest.mark.parametrize(
+    "served", [(WORKLOAD, seed) for seed in (7, 2100000777, 1900000333)],
+    indirect=True)
 def test_replies_agree_with_the_reference(served):
     data = served.cell.data
     # the data holds what the issue asks the program to cope with
@@ -104,7 +47,7 @@ def test_replies_agree_with_the_reference(served):
         assert len(keys) == served.cell.params["namespaces"] * 61
 
 
-@pytest.mark.parametrize("served", [11], indirect=True)
+@pytest.mark.parametrize("served", [(WORKLOAD, 11)], indirect=True)
 def test_another_end_builds_no_program_and_counts_its_series(served):
     reqs = [served.traffic.next() for _ in range(8)]
     first = reqs[0]

@@ -539,3 +539,44 @@ class TestCounterOverSubqueries:
         r = db.sql("TQL EVAL (60, 60, '60') delta(cs[60:10])")
         # gauge delta: no reset adjustment → last - first extrapolated
         assert r.rows[0][-1] < 30
+
+
+class TestWideColumnOnTheMesh:
+    """The default ``GreptimeDB()`` of a multi-device host: a DOUBLE
+    column past 2^24 keeps its low word, the WIDE sort layout is split by
+    rows over the harness's eight devices (both value words with the
+    timestamps' two; the row pointer whole) and the window program runs
+    on that placement, fused and unfused, against the plain float64
+    reference of tests/test_promql_wide.py, whose own cases run on one
+    device."""
+
+    @pytest.mark.parametrize("road", ["fused", "unfused"])
+    def test_rate_against_float64_reference(self, db, ineligible, road):
+        from tests import test_promql_wide as wide
+
+        if db.mesh is None:
+            pytest.skip("needs the 8-device virtual mesh")
+        data = wide.series()
+        wide.load(db, data)
+        if road == "unfused":
+            with ineligible("fusion"):
+                got = wide.served(db, "rate")
+        else:
+            got = wide.served(db, "rate")
+        (key,) = [k for k in db.promql_cache._lru if k[1] == "sort"]
+        layout = db.promql_cache._lru[key].arrays[0]
+        assert layout.wide
+        devices = db.mesh.devices.size
+        assert devices == 8
+        for word in (layout.ts_hi, layout.ts_lo, layout.val_s,
+                     layout.val_lo):
+            assert len(word.sharding.device_set) == devices, word.sharding
+            assert not word.sharding.is_fully_replicated
+        assert layout.row_ptr.sharding.is_fully_replicated
+        want = wide.reference("rate", data)
+        assert {"huge", "big", "edge", "old", "new"} <= set(want)
+        assert wide.worst_error(got, want) <= wide.TOL
+        # and f32 samples would not have passed
+        assert wide.worst_error(
+            wide.reference("rate", data, cast=np.float32),
+            want) >= 10 * wide.TOL

@@ -231,7 +231,8 @@ class TestMeshSharding:
         v_on, l_on, _ = eval_q(db, 'sum by (pod) (rate(m[5m]))')
         entry = [k for k in db.promql_cache._lru if k[1] == "sort"]
         assert entry, "sort layout not resident"
-        key_s = db.promql_cache._lru[entry[0]].arrays[0]
+        layout = db.promql_cache._lru[entry[0]].arrays[0]
+        key_s = layout.val_s
         ndev = len(set(key_s.sharding.device_set))
         assert ndev == db.cache.mesh.devices.size, key_s.sharding
         # sharded placement must not change results

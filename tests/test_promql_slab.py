@@ -121,13 +121,15 @@ FOLD_START_S = T0_S + RANGE_S + 15 * (FOLD_CUT - 1)
 FOLD_COLUMNS = {"s0": 0, "s1": 1, "s2": 64, "s3": 127}
 
 
-def load(db, data, table="m"):
+def load(db, data, table="m", lift=0.0):
+    """``lift`` is added to every value: past 2^24 the resident column
+    keeps its low word and the sort layout is the wide one."""
     db.sql(f"CREATE TABLE {table} (name STRING, ts TIMESTAMP(3) TIME INDEX, "
            f"val DOUBLE, PRIMARY KEY (name))")
     r = db._region_of(table)
     for name, (ts, vals) in data.items():
         r.write({"name": [name] * len(ts), "ts": np.asarray(ts, np.int64),
-                 "val": np.asarray(vals, np.float64)})
+                 "val": np.asarray(vals, np.float64) + lift})
 
 
 # scenario -> (data set, grid (start_s, end_s, step_s), selector suffix,
@@ -324,8 +326,8 @@ def test_kind_against_plain_reference(db, scenario, kind):
         assert (p.slab_w < cap) == (scenario != "outside")
     if dataset == "aligned":
         assert p.slab_w == int(scenario[4:])
-        *_layout, val_s, row_ptr, _sel, _start = _args
-        assert val_s.shape[0] % 128 == 0
+        row_ptr = _args[0].row_ptr
+        assert _args[0].val_s.shape[0] % 128 == 0
         r0 = dict(zip(sorted(data), np.asarray(row_ptr)))
         assert {name: (int(r0[name]) + FOLD_CUT) % 128
                 for name in FOLD_COLUMNS} == FOLD_COLUMNS
@@ -366,19 +368,72 @@ def test_edge_forms_agree_bit_for_bit(db, monkeypatch, layout, kind):
     assert not np.isnan(swept).all()
 
 
-def _node_fleet(db):
+# every counter sits at 2^40 + a few hundred: one f32 for all of them, the
+# whole value in the low word, and a reset that only both words show
+WIDE_LIFT = float(1 << 40)
+
+
+@pytest.mark.parametrize("kind", sorted(pe.PromEvaluator._KIND_KEYS))
+@pytest.mark.parametrize("layout", ["lengths", "fold128"])
+def test_edge_forms_agree_bit_for_bit_on_a_wide_layout(db, monkeypatch,
+                                                       layout, kind):
+    """The same two forms over a WIDE layout (values as two f32 words):
+    the low word is gathered, folded and picked beside the high one in
+    both, so every output is the same bits again — and the counter kinds
+    read what f32 alone cannot hold."""
+    dataset, grid = EDGE_LAYOUTS[layout]
+    data = _series(dataset)
+    load(db, data, lift=WIDE_LIFT)
+    func, ranged = KIND_FUNCS[kind][0]
+    expr = parse_promql(_query(func, ranged, ""))
+
+    def run():
+        pe._KERNEL_CACHE.clear()
+        ev = pe.PromEvaluator(db, *grid)
+        out = np.asarray(ev.eval(expr).values)
+        assert all(k.wide for k in pe._KERNEL_CACHE
+                   if isinstance(k, pe.WindowParams))
+        return out
+
+    swept = run()
+    monkeypatch.setattr(pe, "_SWEEP_WIDTH", 0)
+    searched = run()
+    pe._KERNEL_CACHE.clear()
+    assert np.array_equal(swept, searched, equal_nan=True)
+    assert not np.isnan(swept).all()
+    if kind in ("counter", "counter_rc"):
+        # the lift cancels in every difference: the answers are those of
+        # the unlifted counters, which f32 holds exactly (but for the
+        # extrapolation's cut at the counter's zero, which sees the lift)
+        db.sql("DROP TABLE m")
+        load(db, data)
+        pe._KERNEL_CACHE.clear()
+        plain = np.asarray(pe.PromEvaluator(db, *grid).eval(expr).values)
+        pe._KERNEL_CACHE.clear()
+        assert np.array_equal(np.isnan(swept), np.isnan(plain))
+        if kind != "counter":
+            assert np.array_equal(swept, plain, equal_nan=True)
+        else:
+            # (a lifted counter is never cut short there: at least as much)
+            assert (np.nan_to_num(plain) <= np.nan_to_num(swept) * (1 + 1e-6)
+                    ).all() and (swept > 0).any()
+            assert (swept == plain).any()
+
+
+def _node_fleet(db, table="cpu", lift=0.0):
     """Two modes x four CPUs at a 15 s scrape, 2 h: the cell's shape in
-    small (benchmark/configs/prom-node-64.json)."""
-    db.sql("CREATE TABLE cpu (mode STRING, cpu STRING, "
+    small (benchmark/configs/prom-node-64.json).  ``lift`` is added to
+    every value (past 2^24: a wide layout)."""
+    db.sql(f"CREATE TABLE {table} (mode STRING, cpu STRING, "
            "ts TIMESTAMP(3) TIME INDEX, val DOUBLE, PRIMARY KEY (mode, cpu))")
-    r = db._region_of("cpu")
+    r = db._region_of(table)
     rng = np.random.default_rng(5)
     n = 600
     ts = T0_S * 1000 + 15_000 * np.arange(n)
     for mode in ("user", "idle"):
         for c in range(4):
             r.write({"mode": [mode] * n, "cpu": [str(c)] * n, "ts": ts,
-                     "val": np.cumsum(rng.uniform(0, 15, n))})
+                     "val": lift + np.cumsum(rng.uniform(0, 15, n))})
 
 
 def test_one_program_for_every_hour_and_mode(db):
@@ -413,6 +468,7 @@ def test_one_program_for_every_hour_and_mode(db):
 
 ROWS = "greptime_promql_window_rows_total"
 SWEPT = "greptime_promql_swept_columns_total"
+WIDE = "greptime_promql_wide_rows_total"
 
 
 @pytest.mark.parametrize("slab_w, rows, swept", [
@@ -488,3 +544,18 @@ def test_window_rows_counter_advances_by_slab_cells(db):
         == 2 * 4 * 512
     # an instant vector at one step gathers the lookback only: 300 s / 15 s
     assert _counted(db, ROWS, "cpu", span_s=0) == 8 * 32
+    # a narrow layout (CPU seconds under 2^24) counts no wide cell; the
+    # same fleet lifted to 1e10 has a wide one and counts every cell twice:
+    # padded series x W under both names
+    for query in ('sum by (cpu)(rate(cpu{mode="user"}[5m]))', "rate(cpu[5m])",
+                  'quantile_over_time(0.5, cpu{mode="idle"}[5m])'):
+        assert _counted(db, WIDE, query) == 0
+    _node_fleet(db, "net", lift=1e10)
+    for name in (WIDE, ROWS):
+        assert _counted(db, name,
+                        'sum by (cpu)(rate(net{mode="user"}[5m]))') == 4 * 512
+        assert _counted(db, name, "rate(net[5m])") == 8 * 512
+        assert _counted(db, name,
+                        'quantile_over_time(0.5, net{mode="idle"}[5m])') \
+            == 2 * 4 * 512
+        assert _counted(db, name, "net", span_s=0) == 8 * 32
