@@ -251,14 +251,17 @@ class WindowParams:
 
 _KERNEL_CACHE: dict[WindowParams, object] = {}
 
-# widest slab that is swept: up to it window edges, picks and min/max are
-# compare-select passes over [S, T, F] (4 ps a cell on a v5e) and the
-# gathered chunks are folded to the F = max(W, chunk) columns a window can
-# read (``Slab.fold``: counts and one-hot picks need no time order), past
-# it searches and gathers along the gathered slab as it is (10 ns an
-# element there, whatever W; they need ascending rows and would save 128
-# columns of 8,320) — the same integers and the same picked values either
-# way (PERF.md, PR 31 and PR 35)
+# widest slab that is swept: up to it a window edge is ONE compare-select
+# traversal of [S, T, F] that counts the edge and reads every 32-bit word
+# of what the kind reads there (``_read_edges``: 4 to 6 ps a cell on a v5e
+# for the count and three or four words, where a pass of its own for each
+# cost 2 to 8 ps, an f64 the most), min/max two more, and the gathered
+# chunks are folded to the F = max(W, chunk) columns a window can read
+# (``Slab.fold``: counts and one-hot picks need no time order); past it
+# searches and gathers along the gathered slab as it is (10 ns an element
+# there, whatever W; they need ascending rows and would save 128 columns
+# of 8,320) — the same integers and the same picked words either way
+# (PERF.md, PRs 31, 35 and 38)
 _SWEEP_WIDTH = 1 << 13
 
 
@@ -287,6 +290,18 @@ def _join_f64(hi, lo=None):
     if lo is None:
         return hi
     return hi.astype(jnp.float64) + lo.astype(jnp.float64)
+
+
+def _split_f64(x):
+    """An f64 array as the two f32 words ``_join_f64`` joins: hi = f32(x),
+    lo = f32(x − hi).  On the TPU that is the f64's own representation, so
+    the joined words are ``x`` again bit for bit; elsewhere they hold its
+    first 48 bits.  Taken on a [S, G] slab so that what crosses an
+    [S, T, F] traversal is 32-bit words (``_read_edges``).  ±inf keeps a
+    zero low word (inf − inf would make it NaN)."""
+    hi = x.astype(jnp.float32)
+    lo = (x - hi.astype(jnp.float64)).astype(jnp.float32)
+    return hi, jnp.where(jnp.isinf(hi), 0, lo)
 
 
 def slab_width(step_ms: int, num_steps: int, range_ms: int, spacing: int,
@@ -342,11 +357,13 @@ class SortLayout(typing.NamedTuple):
 def count_dispatch(p: WindowParams, args, selected: int,
                    programs: int = 1) -> None:
     """Count one dispatch of ``programs`` window programs of class ``p``
-    over the kernel arguments ``args`` (``_prep_window``'s)."""
+    over the kernel arguments ``args`` (``_prep_window``'s): ``p``'s own
+    and, with two, the sizing pass that places the edges for it."""
     count_window_dispatch(
         selected, p.num_sel, p.slab_w,
         swept_columns(p.slab_w, args[0].val_s.shape[0]), programs,
-        wide=p.wide)
+        wide=p.wide, passes=sweep_passes(p.kind, p.slab_w)
+        + (programs - 1) * sweep_passes("cnt_max", p.slab_w))
 
 
 def _count_le(probe, length, thr, bits: int):
@@ -446,19 +463,31 @@ class Slab(typing.NamedTuple):
     is.  ``lo``/``hi`` index a window's samples in time order from the
     slab's own origin (the first readable sample where it is swept, the
     first gathered column where it is searched); ``col`` turns such an
-    index into a column of a folded array."""
+    index into a column of a folded array.
+
+    ``_slab_gather`` leaves the edges ``None``: ``_read_edges`` places
+    them, alone (``_slab_geometry``) or in the traversal that reads a
+    window's first and last sample (``_window_body``).  A traversal of
+    [S, T, width] costs a fixed part a (series, step) cell it writes and a
+    part a column and an operation (PERF.md section 5), so what is read
+    at one edge is read in one traversal, as 32-bit words: ``val_words``
+    are the value's own (one f32 off a narrow layout, the two a wide
+    layout keeps), an f64 that the program made goes through
+    ``_split_f64``."""
 
     rel: jnp.ndarray  # [S, G] ts − start_ms; sentinels outside the run
     val: jnp.ndarray  # [S, G] 0 outside the run; f32, f64 off a wide layout
+    val_words: tuple  # [S, G] f32 each: ``val`` as ``_join_f64`` joins it
     ok: jnp.ndarray  # [S, G] column holds a readable sample of the series
     off: jnp.ndarray  # [S] i32 gathered column of the first readable row
-    lo: jnp.ndarray  # [S, T] index of each window's first sample
-    hi: jnp.ndarray  # [S, T] one past its last
-    cnt: jnp.ndarray  # [S, T] i32 samples in the window
-    has: jnp.ndarray  # [S, T] window non-empty and series selected
     sel_ok: jnp.ndarray  # [S]
+    thr: tuple  # ([T], [T]) rel of each window's open start and of its end
     sweep: bool  # W is within _SWEEP_WIDTH: the slab is folded and swept
     width: int  # columns the [S, T, ·] passes run over (``swept_columns``)
+    lo: "jnp.ndarray | None" = None  # [S, T] index of a window's first sample
+    hi: "jnp.ndarray | None" = None  # [S, T] one past its last
+    cnt: "jnp.ndarray | None" = None  # [S, T] i32 samples in the window
+    has: "jnp.ndarray | None" = None  # [S, T] non-empty and series selected
 
     def fold(self, a):
         return _fold(a, self.off, self.width) if self.sweep else a
@@ -475,6 +504,11 @@ class Slab(typing.NamedTuple):
         inverse)."""
         j = jnp.arange(self.width, dtype=jnp.int32)[None, :]
         return (j - self.off[:, None]) % self.width if self.sweep else j
+
+    def edged(self, lo, hi):
+        cnt = hi - lo
+        return self._replace(lo=lo, hi=hi, cnt=cnt,
+                             has=(cnt > 0) & self.sel_ok[:, None])
 
 
 def _slab_edges(rel, thr, sweep: bool):
@@ -494,26 +528,131 @@ def _slab_edges(rel, thr, sweep: bool):
         jnp.broadcast_to(thr[None, :], (S, T)), w.bit_length())
 
 
-def _slab_geometry(p: WindowParams, layout: SortLayout, sel_tsids,
-                   start_ms) -> Slab:
-    """Shared window geometry for all window kernels over a PRESORTED
-    resident layout (_build_sort_layout): the ONE definition the stats
-    kernel, the matrix kernels and the fused programs build on.
+def _earlier(a, fill):
+    """[S, G] → each column's time-order predecessor, ``fill`` ahead of
+    the first: read at a window's index i it is the sample at i − 1."""
+    return jnp.concatenate(
+        [jnp.full((a.shape[0], 1), fill, a.dtype), a[:, :-1]], axis=1)
+
+
+# the window edges, in the order ``Slab.thr`` and ``_read_edges`` have them
+_EDGES = ("first", "last")
+
+
+def sweep_passes(kind: str, slab_w: int) -> int:
+    """Traversals of [S, T, width] that the program of window kind ``kind``
+    emits: none where the slab is searched; where it is swept one a window
+    edge (``_read_edges``: the count of the samples at or before the edge
+    and whatever the kind reads at the edge's sample, under one mask) and,
+    for min/max, the two masked reduces.  A matrix kind's program and its
+    sizing pass place the edges and gather.  Static, like
+    ``swept_columns``: the dispatch counter reads it."""
+    if slab_w > _SWEEP_WIDTH:
+        return 0
+    return len(_EDGES) + (2 if kind == "minmax" else 0)
+
+
+def _read_edges(slab: Slab, first=None, last=None):
+    """(lo, hi, at_first, at_last): the window edges over the slab, [S, T],
+    and what the [S, G] arrays of ``first`` (name → array, time order
+    along G) hold at every window's first sample, index clip(lo), and
+    those of ``last`` at its last one, clip(hi − 1): name → [S, T].  A
+    picked value means something only where the window holds a sample
+    (hi > lo).
+
+    An array crosses as 32-bit words: an f64 as the two of ``_split_f64``
+    (taken here, on [S, G]; a tuple is taken for words already split, as
+    ``Slab.val_words``), joined again on [S, T] — on the TPU the f64's
+    own representation, so the value read is the value stored, bit for
+    bit, and nothing 64 bits wide is selected or added over [S, T, ·].
+
+    Where the slab is swept an edge is ONE traversal of [S, T, width], a
+    variadic reduce that sums along the folded columns the compare that
+    counts (rel ≤ thr[t]) and every word under the mask of the one column
+    at the edge.  The mask comes from the thresholds, not from the count:
+    the first sample is the column past the threshold whose time-order
+    predecessor is not, the last one the column at or before it whose
+    successor is past it, so no [S, T] index is broadcast along the lanes
+    and the count rides in the same traversal.  The cube is laid
+    [width, T, S]: the sums come out [T, S] (the layout the TPU compiler
+    gives an [S, T] result anyway) by adding whole tiles, where a sum
+    along the minor axis reduces every tile across its lanes (PERF.md,
+    PR 38: 23.9 ms a call of ``namespace_cpu``'s program against 26.6).
+    A one-hot sum has one non-zero term: the words come out as they went
+    in.  An edge nothing is read at is counted by ``_slab_edges``.  Where the slab is searched
+    the edges are searches and the words gathers at the same indices —
+    the same integers and the same words either way."""
+    def words_of(a):
+        if isinstance(a, tuple):
+            return a
+        return _split_f64(a) if a.dtype == jnp.float64 else (a,)
+
+    def cube(a):
+        # [S, F] as [F, 1, S]: the columns lead, so the sum along them
+        # adds whole (step, series) tiles and crosses no lane
+        return a.T[:, None, :]
+
+    groups = [{name: words_of(a) for name, a in (group or {}).items()}
+              for group in (first, last)]
+    flat = [tuple(w for words in g.values() for w in words) for g in groups]
+    rel = slab.fold(slab.rel)
+    if slab.sweep:
+        lowest, highest = jnp.iinfo(rel.dtype).min, jnp.iinfo(rel.dtype).max
+        idx = slab.index()
+        counts, picked = [], []
+        for edge, thr, words in zip(_EDGES, slab.thr, flat):
+            if not words:
+                counts.append(_slab_edges(rel, jnp.asarray(thr), True))
+                picked.append(())
+                continue
+            # a folded column's time-order neighbour is the column beside
+            # it, but at the two ends of the readable samples
+            if edge == "first":
+                beside = jnp.where(idx == 0, lowest,
+                                   jnp.roll(rel, 1, axis=1))
+            else:
+                beside = jnp.where(idx == slab.width - 1, highest,
+                                   jnp.roll(rel, -1, axis=1))
+            t = jnp.asarray(thr)[None, :, None]
+            le = cube(rel) <= t
+            at = (~le & (cube(beside) <= t) if edge == "first"
+                  else le & (cube(beside) > t))
+            operands = (le.astype(jnp.int32),) + tuple(
+                jnp.where(at, cube(slab.fold(a)), jnp.zeros((), a.dtype))
+                for a in words)
+            count, *sums = jax.lax.reduce(
+                operands, tuple(jnp.zeros((), o.dtype) for o in operands),
+                lambda x, y: tuple(a + b for a, b in zip(x, y)), (0,))
+            counts.append(count.T)
+            picked.append([x.T for x in sums])
+        lo, hi = counts
+    else:
+        lo, hi = (_slab_edges(rel, jnp.asarray(t), False) for t in slab.thr)
+        picked = [[jnp.take_along_axis(
+            a, jnp.clip(i, 0, slab.width - 1), axis=1) for a in words]
+            for words, i in zip(flat, (lo, hi - 1))]
+    out = []
+    for group, words in zip(groups, picked):
+        words = iter(words)
+        out.append({name: _join_f64(*(next(words) for _ in split))
+                    for name, split in group.items()})
+    return lo, hi, out[0], out[1]
+
+
+def _slab_gather(p: WindowParams, layout: SortLayout, sel_tsids,
+                 start_ms) -> Slab:
+    """The slab of ``_slab_geometry`` before its window edges are placed.
 
     Gathers, for each selected series, the rows of its run from its first
     sample after ``start − range`` on (found in the series' own run
     through ``row_ptr``) — ``p.slab_w`` of them can fall in the query's
-    span — and places every window's half-open index range [lo, hi) on
-    them with LEFT-EXCLUSIVE window semantics (t - range, t].  After the
-    gather nothing has the table's length: work is proportional to the
-    matched series, not to the table.  Where the slab is swept
-    (``Slab.sweep``) the edges are counted on the folded slab and come
-    out relative to the first readable sample; where it is searched, on
-    the gathered one, relative to its first column.
+    span.  After the gather nothing has the table's length: work is
+    proportional to the matched series, not to the table.
 
     Off a wide layout the values' low word is gathered beside the high
-    one by the same chunks and joined with it, so ``Slab.val`` is f64
-    there and no consumer of the slab reads half a value."""
+    one by the same chunks: ``Slab.val`` is the two joined (f64), so no
+    consumer of the slab reads half a value, and ``Slab.val_words`` the
+    two as they are."""
     ts_hi, ts_lo, val_s, row_ptr = (layout.ts_hi, layout.ts_lo,
                                     layout.val_s, layout.row_ptr)
     T, S, w = p.num_steps, p.num_sel, p.slab_w
@@ -547,11 +686,16 @@ def _slab_geometry(p: WindowParams, layout: SortLayout, sel_tsids,
         return a.reshape(n // c, c)[
             jnp.clip(chunk, 0, n // c - 1)].reshape(S, k * c)
 
-    # a wide layout's two words are joined here, once, so that whatever
-    # reads ``Slab.val`` reads all of the value (exact: |low| is under
-    # half an ulp of high)
-    val = jnp.where(ok, _join_f64(
-        take(val_s), take(layout.val_lo) if layout.wide else None), 0.0)
+    if layout.wide:
+        # the two words are joined here, once, so that whatever reads
+        # ``Slab.val`` reads all of the value (exact: |low| is under half
+        # an ulp of high)
+        val_words = tuple(jnp.where(ok, take(a), 0.0)
+                          for a in (val_s, layout.val_lo))
+        val = _join_f64(*val_words)
+    else:
+        val = jnp.where(ok, take(val_s), 0.0)
+        val_words = (val,)
     # timestamps rebased to start_ms; int32 where the query's span fits
     # (the compare sweep is the slab's widest pass): integer compares stay
     # exact, and a sample beyond the span saturates below the sentinel
@@ -564,16 +708,25 @@ def _slab_geometry(p: WindowParams, layout: SortLayout, sel_tsids,
                    -big, big - 1).astype(tdt)
     rel = jnp.where(ok, rel, jnp.where(before, -big - 1, big).astype(tdt))
     # the sweeps read the folded slab, the searches the gathered one
-    sweep = w <= _SWEEP_WIDTH
-    off = base % c
-    width = swept_columns(w, n)
-    swept = _fold(rel, off, width) if sweep else rel
-    lo = _slab_edges(swept, jnp.asarray((steps - p.range_ms).astype(tdt)),
-                     sweep)
-    hi = _slab_edges(swept, jnp.asarray(steps.astype(tdt)), sweep)
-    cnt = hi - lo
-    has = (cnt > 0) & sel_ok[:, None]
-    return Slab(rel, val, ok, off, lo, hi, cnt, has, sel_ok, sweep, width)
+    return Slab(rel, val, val_words, ok, base % c, sel_ok,
+                ((steps - p.range_ms).astype(tdt), steps.astype(tdt)),
+                w <= _SWEEP_WIDTH, swept_columns(w, n))
+
+
+def _slab_geometry(p: WindowParams, layout: SortLayout, sel_tsids,
+                   start_ms) -> Slab:
+    """Shared window geometry for all window kernels over a PRESORTED
+    resident layout (_build_sort_layout): the ONE definition the stats
+    kernel, the matrix kernels and the fused programs build on.
+
+    ``_slab_gather``'s slab with every window's half-open index range
+    [lo, hi) placed on it with LEFT-EXCLUSIVE window semantics
+    (t - range, t].  Where the slab is swept (``Slab.sweep``) the edges
+    are counted on the folded slab and come out relative to the first
+    readable sample; where it is searched, on the gathered one, relative
+    to its first column."""
+    slab = _slab_gather(p, layout, sel_tsids, start_ms)
+    return slab.edged(*_read_edges(slab)[:2])
 
 
 def _prefix_sum(x):
@@ -634,29 +787,12 @@ def _window_body(p: WindowParams):  # gl: warm-path
     S = p.num_sel
 
     def kernel(layout, sel_tsids, start_ms):
-        slab = _slab_geometry(p, layout, sel_tsids, start_ms)
-        lo, hi, cnt, has, sel_ok = (slab.lo, slab.hi, slab.cnt, slab.has,
-                                    slab.sel_ok)
-        sweep, w = slab.sweep, slab.width
-        # the gathered rows in time order, for what reads a neighbour or
-        # a prefix; every [S, T, ·] pass below reads ``slab.fold`` of such
-        # an array, addressed through ``slab.col``
+        slab = _slab_gather(p, layout, sel_tsids, start_ms)
+        sel_ok = slab.sel_ok
+        # the gathered rows in time order: what reads a neighbour or a
+        # prefix reads these, and ``_read_edges`` folds what it sweeps
         g_rel, g_val, ok = slab.rel, slab.val, slab.ok
-        rel, val = slab.fold(g_rel), slab.fold(g_val)
-
-        def pick(a, i):
-            """a[s, col(i[s, t])] of a folded array: one compare-select
-            pass where the slab is swept (a single non-zero term, so the
-            sum is the value), else a gather."""
-            i = slab.col(i)
-            if not sweep:
-                return jnp.take_along_axis(a, i, axis=1)
-            cols = jnp.arange(a.shape[1], dtype=jnp.int32)[None, None, :]
-            return jnp.sum(
-                jnp.where(cols == i[:, :, None], a[:, None, :], 0), axis=-1)
-
-        def pick_ts(i):
-            return start_ms + pick(rel, i).astype(jnp.int64)
+        val_words = slab.val_words
 
         # ``g_val`` is f32, or f64 off a wide layout (``p.wide``): every
         # compare, difference and prefix below reads it as it is, and what
@@ -666,37 +802,75 @@ def _window_body(p: WindowParams):  # gl: warm-path
         def f32(x):
             return x.astype(jnp.float32)
 
+        def ts_of(rel):
+            return start_ms + rel.astype(jnp.int64)
+
         # per-series counter-reset adjustment (for counter kinds).  A
         # window never reads a drop at or before its own first sample, so
         # prefixes that start at the slab's first column give the same
         # differences as table-wide ones
         prev_same = jnp.concatenate(
             [jnp.zeros((S, 1), bool), ok[:, 1:] & ok[:, :-1]], axis=1)
-        prev_val = jnp.concatenate(
-            [jnp.zeros((S, 1), g_val.dtype), g_val[:, :-1]], axis=1)
-        drop = jnp.where(prev_same & (prev_val > g_val), prev_val, 0.0)
-        gdrop = _prefix_sum(drop.astype(jnp.float64))
-        adj = slab.fold(g_val.astype(jnp.float64) + gdrop)
+        prev_val = _earlier(g_val, 0)
 
         # window sums from f64 prefixes along each series' gathered row
-        # (``g_val`` is already 0 outside the run), taken in time order
-        # and folded afterwards: the inclusive prefix at the window's
-        # last sample less the one before its first (0 at the row's head)
+        # (``g_val`` is already 0 outside the run), taken in time order:
+        # the inclusive prefix at the window's last sample less the one
+        # before its first, which is what the prefix's time-order
+        # predecessor holds AT the first sample (0 at the row's head: every
+        # summand is 0 ahead of the run)
         def cs(x):
-            return slab.fold(_prefix_sum(x.astype(jnp.float64)))
+            return _prefix_sum(x.astype(jnp.float64))
 
-        def win_sum(c, first=lo):
-            head = pick(c, jnp.clip(first - 1, 0, w - 1))
-            return pick(c, jnp.clip(hi - 1, 0, w - 1)) - jnp.where(
-                first > 0, head, 0.0)
+        def win_sums(prefixes, skip_first=False):
+            """name → the sum over each window's samples (but its first
+            with ``skip_first``) of the series ``prefixes`` are of, as
+            what to read at the two edges."""
+            return ({name: c if skip_first else _earlier(c, 0)
+                     for name, c in prefixes.items()}, dict(prefixes))
 
         g_val64 = g_val.astype(jnp.float64)
         tsec = jnp.where(ok, g_rel, 0).astype(jnp.float64) / 1000.0
+        sample = {"val": val_words, "rel": g_rel}
+        first, last = {}, {}
+        if p.kind == "instant":
+            last = sample
+        if p.kind == "counter":
+            drop = jnp.where(prev_same & (prev_val > g_val), prev_val, 0.0)
+            adj = g_val64 + _prefix_sum(drop.astype(jnp.float64))
+            first = last = {**sample, "adj": adj}
+        if p.kind == "counter_rc":
+            # resets/changes counts via indicator cumsums — a SEPARATE
+            # kind so the (much hotter) rate/increase/delta path doesn't
+            # pay two extra prefixes it never reads.  The boundary pair
+            # crossing into the window is left out: the indicator at
+            # index i compares i-1, i; window pairs are (lo+1..hi-1)
+            first, last = win_sums({
+                "resets": cs(jnp.where(
+                    prev_same & (prev_val > g_val), 1.0, 0.0)),
+                "changes": cs(jnp.where(
+                    prev_same & (prev_val != g_val), 1.0, 0.0)),
+            }, skip_first=True)
+        if p.kind == "gauge_window":
+            first, last = win_sums({"sum": cs(g_val),
+                                    "sum2": cs(g_val64 ** 2)})
+            first.update(sample)
+            last.update(sample)
+        if p.kind == "regression":
+            first, last = win_sums({
+                "v": cs(g_val), "t": cs(tsec), "tv": cs(tsec * g_val64),
+                "t2": cs(tsec * tsec)})
+            last["rel"] = g_rel
+        if p.kind == "irate":
+            # the sample before a window's last is its time-order
+            # predecessor AT the last
+            last = {**sample, "prev_rel": _earlier(g_rel, 0),
+                    "prev_val": tuple(_earlier(a, 0) for a in val_words)}
 
+        lo, hi, fst, lst = _read_edges(slab, first, last)
+        slab = slab.edged(lo, hi)
+        cnt, has = slab.cnt, slab.has
         has2 = (cnt >= 2) & sel_ok[:, None]
-
-        first_i = jnp.clip(lo, 0, w - 1)
-        last_i = jnp.clip(hi - 1, 0, w - 1)
         out = {}
         fcnt = cnt.astype(jnp.float32)
         nan = jnp.float32(jnp.nan)
@@ -705,70 +879,56 @@ def _window_body(p: WindowParams):  # gl: warm-path
                       "instant"):
             out["count"] = jnp.where(has, fcnt, 0.0)
         if p.kind == "instant":
-            out["last"] = jnp.where(has, f32(pick(val, last_i)), nan)
-            out["last_ts"] = jnp.where(has, pick_ts(last_i), 0)
+            out["last"] = jnp.where(has, f32(lst["val"]), nan)
+            out["last_ts"] = jnp.where(has, ts_of(lst["rel"]), 0)
         if p.kind == "counter":
-            fv = pick(val, first_i)
-            lv = pick(val, last_i)
-            d_adj = (pick(adj, last_i) - pick(adj, first_i)).astype(
-                jnp.float32)
-            out["first_ts"] = jnp.where(has, pick_ts(first_i), 0)
-            out["last_ts"] = jnp.where(has, pick_ts(last_i), 0)
-            out["first_val"] = jnp.where(has, f32(fv), nan)
-            out["last_val"] = jnp.where(has, f32(lv), nan)
+            d_adj = (lst["adj"] - fst["adj"]).astype(jnp.float32)
+            out["first_ts"] = jnp.where(has, ts_of(fst["rel"]), 0)
+            out["last_ts"] = jnp.where(has, ts_of(lst["rel"]), 0)
+            out["first_val"] = jnp.where(has, f32(fst["val"]), nan)
+            out["last_val"] = jnp.where(has, f32(lst["val"]), nan)
             out["delta_adj"] = jnp.where(has2, d_adj, nan)
-            out["delta_raw"] = jnp.where(has2, f32(lv - fv), nan)
+            out["delta_raw"] = jnp.where(
+                has2, f32(lst["val"] - fst["val"]), nan)
         if p.kind == "counter_rc":
-            # resets/changes counts via indicator cumsums — a SEPARATE
-            # kind so the (much hotter) rate/increase/delta path doesn't
-            # pay two extra prefixes it never reads
-            ind_reset = jnp.where(prev_same & (prev_val > g_val), 1.0, 0.0)
-            ind_change = jnp.where(prev_same & (prev_val != g_val), 1.0, 0.0)
-            # exclude the boundary pair crossing into the window: indicator at
-            # index i compares i-1,i; window pairs are (lo+1..hi-1)
-            out["resets"] = jnp.where(
-                has, win_sum(cs(ind_reset), lo + 1).astype(jnp.float32), nan)
-            out["changes"] = jnp.where(
-                has, win_sum(cs(ind_change), lo + 1).astype(jnp.float32),
-                nan)
+            for name in ("resets", "changes"):
+                out[name] = jnp.where(
+                    has, (lst[name] - fst[name]).astype(jnp.float32), nan)
         if p.kind in ("gauge_window",):
-            sum64 = win_sum(cs(g_val))
-            sum2_64 = win_sum(cs(g_val64 ** 2))
+            sum64 = lst["sum"] - fst["sum"]
+            sum2_64 = lst["sum2"] - fst["sum2"]
             s = sum64.astype(jnp.float32)
             out["sum"] = jnp.where(has, s, nan)
             out["avg"] = jnp.where(has, s / jnp.maximum(fcnt, 1), nan)
             mean = s.astype(jnp.float64) / jnp.maximum(cnt, 1)
             var = sum2_64 / jnp.maximum(cnt, 1) - mean * mean
             out["var"] = jnp.where(has, jnp.maximum(var, 0.0).astype(jnp.float32), nan)
-            out["last"] = jnp.where(has, f32(pick(val, last_i)), nan)
-            out["first"] = jnp.where(has, f32(pick(val, first_i)), nan)
-            out["first_ts"] = jnp.where(has, pick_ts(first_i), 0)
-            out["last_ts"] = jnp.where(has, pick_ts(last_i), 0)
+            out["last"] = jnp.where(has, f32(lst["val"]), nan)
+            out["first"] = jnp.where(has, f32(fst["val"]), nan)
+            out["first_ts"] = jnp.where(has, ts_of(fst["rel"]), 0)
+            out["last_ts"] = jnp.where(has, ts_of(lst["rel"]), 0)
         if p.kind == "regression":
-            sw = win_sum(cs(g_val))
-            st = win_sum(cs(tsec))
-            stv = win_sum(cs(tsec * g_val64))
-            st2 = win_sum(cs(tsec * tsec))
+            sw, st, stv, st2 = (lst[k] - fst[k]
+                                for k in ("v", "t", "tv", "t2"))
             cn = cnt.astype(jnp.float64)
             denom = cn * st2 - st * st
             slope = jnp.where(denom != 0, (cn * stv - st * sw) / denom, jnp.nan)
             intercept = jnp.where(cn > 0, (sw - slope * st) / cn, jnp.nan)
             out["slope"] = jnp.where(has2, slope.astype(jnp.float32), nan)
             out["intercept"] = jnp.where(has2, intercept.astype(jnp.float32), nan)
-            out["last_ts"] = jnp.where(has, pick_ts(last_i), 0)
+            out["last_ts"] = jnp.where(has, ts_of(lst["rel"]), 0)
         if p.kind == "irate":
-            prev_i = jnp.clip(hi - 2, 0, w - 1)
             # the pair ``_instant_pair`` differences leaves as it was
             # read (f64 off a wide layout): f32 after the difference
-            out["last_ts"] = jnp.where(has2, pick_ts(last_i), 0)
-            out["prev_ts"] = jnp.where(has2, pick_ts(prev_i), 0)
-            out["last_val"] = jnp.where(has2, pick(val, last_i), nan)
-            out["prev_val"] = jnp.where(has2, pick(val, prev_i), nan)
+            out["last_ts"] = jnp.where(has2, ts_of(lst["rel"]), 0)
+            out["prev_ts"] = jnp.where(has2, ts_of(lst["prev_rel"]), 0)
+            out["last_val"] = jnp.where(has2, lst["val"], nan)
+            out["prev_val"] = jnp.where(has2, lst["prev_val"], nan)
         if p.kind == "minmax":
             # rounding keeps order, so the extreme of the rounded samples
             # is the rounded extreme: the sweep stays f32 on a wide layout
-            val, g_val = f32(val), f32(g_val)
-            if sweep:
+            val, g_val = f32(slab.fold(g_val)), f32(g_val)
+            if slab.sweep:
                 # reduce over the folded slab under the edge mask
                 j = slab.index()[:, None, :]
                 in_win = (j >= lo[:, :, None]) & (j < hi[:, :, None])

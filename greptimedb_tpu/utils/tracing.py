@@ -80,13 +80,25 @@ M_WIDE_ROWS = REGISTRY.counter(
     "Slab cells (padded matched series x slab width) of dispatched PromQL "
     "window programs that read a two-word value column",
 )
+# Says the grouped picks engaged: traversals of [series, steps, swept
+# width] in the source of the dispatched programs (promql/engine.py
+# ``sweep_passes``): one a window edge where the slab is swept, 0 where it
+# is searched; a pick of its own for every array read would count 8 for
+# ``rate``.
+M_SWEEP_PASSES = REGISTRY.counter(
+    "greptime_promql_sweep_passes_total",
+    "Compare-select-reduce traversals of the swept slab that dispatched "
+    "PromQL window programs emit",
+)
 
 
 def count_window_dispatch(selected: int, padded: int, slab_w: int,
                           swept: int, programs: int = 1,
-                          wide: bool = False) -> None:
+                          wide: bool = False, passes: int = 0) -> None:
     """The counters of one PromQL window dispatch: host integers off
-    static shapes and the selection's length."""
+    static shapes and the selection's length.  ``passes`` is the sum
+    over the dispatched programs, not one program's."""
+    M_SWEEP_PASSES.inc(passes)
     M_WINDOW_ROWS.inc(programs * padded * slab_w)
     # by 0 off a narrow layout: the counter is there to be read as 0
     M_WIDE_ROWS.inc(programs * padded * slab_w if wide else 0)

@@ -221,9 +221,10 @@ SWEPT = {"promql-fused": (PROM_SEL, 61, PROM_W),
 
 
 def _operands_of_step_passes(text: str, s: int, t: int) -> dict[str, int]:
-    """{instruction: its widest [s, X] operand} over the fusions of the
-    compiled program whose result holds an [s, t] array: the compare-
-    select-reduce passes over [s, t, X] (and the epilogue over [s, t])."""
+    """{instruction: its widest [s, X] or [X, s] operand} over the fusions
+    of the compiled program whose result holds an [s, t] or [t, s] array:
+    the compare-select-reduce passes over the swept cube (and the epilogue
+    over [s, t])."""
     shape_of, passes = {}, {}
     for line in text.splitlines():
         name, eq, rhs = line.strip().removeprefix("ROOT ").partition(" = ")
@@ -231,13 +232,15 @@ def _operands_of_step_passes(text: str, s: int, t: int) -> dict[str, int]:
         if not eq or not name.startswith("%") or op is None:
             continue
         shape_of[name] = rhs[:op.start()]
-        if op.group(1) == "fusion" and f"[{s},{t}]" in shape_of[name]:
+        if op.group(1) == "fusion" and (f"[{s},{t}]" in shape_of[name]
+                                        or f"[{t},{s}]" in shape_of[name]):
             passes[name] = re.findall(
                 r"%[\w.-]+", rhs[op.end():].split("), kind=")[0])
     widths = {}
     for name, operands in passes.items():
-        cols = [int(x) for o in operands for x in re.findall(
-            rf"\[{s},(\d+)\]", shape_of.get(o, ""))]
+        cols = [int(x) for o in operands for pair in re.findall(
+            rf"\[{s},(\d+)\]|\[(\d+),{s}\]", shape_of.get(o, ""))
+            for x in pair if x]
         widths[name] = max(cols, default=0)
     return widths
 
@@ -262,6 +265,8 @@ def test_compiles_for_one_v5e(one_chip, case):
         assert " while(" not in text
         passes = _operands_of_step_passes(text, s, t)
         assert max(passes.values()) == width, passes
+        # one traversal a window edge: two fusions read the slab's columns
+        assert list(passes.values()).count(width) == 2, passes
 
 
 def test_bucket_major_shards_over_the_mesh(topo, one_chip):

@@ -18,7 +18,10 @@ by a tolerance suited to f32.
 import importlib.util
 import math
 import os
+import re
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -491,11 +494,11 @@ def _counted(db, name, query, start_s=T0_S + 4000, span_s=3600):
     return REGISTRY.value(name, ()) - before
 
 
-def _slab_fill_reader():
-    """benchmark/layer_metrics/slab_fill_pct.py, loaded by its path."""
+def _reader(name):
+    """benchmark/layer_metrics/<name>.py, loaded by its path."""
     path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                        "benchmark", "layer_metrics", "slab_fill_pct.py")
-    spec = importlib.util.spec_from_file_location("slab_fill_pct", path)
+                        "benchmark", "layer_metrics", name + ".py")
+    spec = importlib.util.spec_from_file_location(name, path)
     reader = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(reader)
     return reader
@@ -519,7 +522,7 @@ def test_swept_columns_counter_advances_by_folded_cells(db):
     def snap():
         return {name: REGISTRY.value(name, ()) for name in (ROWS, SWEPT)}
 
-    read = _slab_fill_reader().read
+    read = _reader("slab_fill_pct").read
     before = snap()
     _counted(db, SWEPT, 'rate(cpu[5m])')
     after = snap()
@@ -559,3 +562,154 @@ def test_window_rows_counter_advances_by_slab_cells(db):
                         'quantile_over_time(0.5, net{mode="idle"}[5m])') \
             == 2 * 4 * 512
         assert _counted(db, name, "net", span_s=0) == 8 * 32
+
+
+# ---------------------------------------------------------------------------
+# one traversal a window edge
+# ---------------------------------------------------------------------------
+
+PASSES = "greptime_promql_sweep_passes_total"
+_ROWS, _SERIES, _SEL, _STEPS = 4096, 12, 8, 7
+
+
+def _class_args(p, wide):
+    sd = jax.ShapeDtypeStruct
+    f32 = sd((_ROWS,), jnp.float32)
+    return (pe.SortLayout(sd((_ROWS,), jnp.int32), sd((_ROWS,), jnp.uint32),
+                          f32, sd((_SERIES + 1,), jnp.int32),
+                          f32 if wide else None),
+            sd((p.num_sel,), jnp.int32), sd((), jnp.int64))
+
+
+def _cube_reduces(text, p):
+    """Reduces of the lowered program whose operands are the swept cube:
+    padded series x steps x swept columns, in whichever order."""
+    cube = sorted((p.num_sel, p.num_steps, pe.swept_columns(p.slab_w, _ROWS)))
+    n = 0
+    for m in re.finditer(r"stablehlo\.reduce\(.*? : \(tensor<([0-9x]+)x\w+>",
+                         text):
+        dims = sorted(int(d) for d in m.group(1).split("x"))
+        n += dims == cube
+    return n
+
+
+@pytest.mark.parametrize("kind", ["counter", "instant", "gauge_window",
+                                  "irate", "regression", "counter_rc",
+                                  "minmax"])
+@pytest.mark.parametrize("slab_w", [64, 128, 512])
+@pytest.mark.parametrize("wide", [False, True], ids=["narrow", "wide"])
+def test_sweep_passes_counted_are_the_traversals_lowered(wide, slab_w, kind):
+    """The counter a dispatch advances by is the number of reduces over
+    [S, T, F] in the program's own lowered text: one a window edge, the
+    count and every word read there under one mask; an f64 crosses as two
+    f32 words, so no operand of such a reduce is 64 bits wide."""
+    p = pe.WindowParams(
+        step_ms=30_000, num_steps=_STEPS, range_ms=300_000, num_sel=_SEL,
+        total_series=_SERIES, kind=kind, slab_w=slab_w, run_bits=10,
+        wide=wide)
+    args = _class_args(p, wide)
+    text = jax.jit(pe._window_body(p)).lower(*args).as_text()
+    before = REGISTRY.value(PASSES, ())
+    pe.count_dispatch(p, args, selected=5)
+    counted = REGISTRY.value(PASSES, ()) - before
+    assert counted == pe.sweep_passes(kind, slab_w) == _cube_reduces(text, p)
+    assert counted == (4 if kind == "minmax" else 2)
+    if kind == "counter":
+        assert counted <= 3
+    cube = "x".join(str(d) for d in (pe.swept_columns(slab_w, _ROWS), _STEPS,
+                                     _SEL))
+    assert f"tensor<{cube}xf32>" in text or kind == "minmax"
+    assert f"tensor<{cube}xf64>" not in text
+    assert f"tensor<{cube}xi64>" not in text
+    # the searched form sweeps nothing, and counts nothing
+    wide_slab = pe.WindowParams(**{**p.__dict__, "slab_w": 16384})
+    before = REGISTRY.value(PASSES, ())
+    pe.count_dispatch(wide_slab, args, selected=5)
+    assert REGISTRY.value(PASSES, ()) == before
+
+
+def _word_slab(sweep: bool):
+    """A gathered slab by hand, 128 + 128 columns: five series whose first
+    readable sample sits at column 0, 1, 64, 127 and 5 of the first chunk,
+    40, 128, 3, 90 and 0 samples long, a scrape every 15 s."""
+    S, F, c, T = 5, 128, 128, 9
+    off = np.array([0, 1, 64, 127, 5], np.int32)
+    run = np.array([40, 128, 3, 90, 0])
+    g = np.arange(F + c)[None, :]
+    ok = (g >= off[:, None]) & (g < (off + run)[:, None])
+    big = (1 << 31) - 1
+    rel = np.where(ok, -280_000 + 15_000 * (g - off[:, None]) + 7 * off[:, None],
+                   np.where(g < off[:, None], -big - 1, big)).astype(np.int32)
+    steps = 60_000 * np.arange(T, dtype=np.int64)
+    val = np.where(ok, 1.0, 0.0).astype(np.float32)
+    return pe.Slab(
+        jnp.asarray(rel), jnp.asarray(val), (jnp.asarray(val),),
+        jnp.asarray(ok), jnp.asarray(off), jnp.asarray(run > 0),
+        ((steps - 300_000).astype(np.int32), steps.astype(np.int32)),
+        sweep, F if sweep else F + c), ok
+
+
+@pytest.mark.parametrize("form", ["swept", "searched"])
+def test_grouped_pick_returns_both_words_of_an_f64(form):
+    """f64 arrays with both f32 words in use (a counter at 2^40 + a
+    fraction, a sum of drops beside it, an int32 beside both) read at a
+    window's two edges in one group come back as ``take_along_axis``
+    returns them, bit for bit, and the edges as the counting pass places
+    them."""
+    slab, ok = _word_slab(form == "swept")
+    rng = np.random.default_rng(3)
+    frac = rng.integers(0, 128, ok.shape) / 128.0
+    x = np.where(ok, float(1 << 40) + np.cumsum(
+        rng.integers(1, 9000, ok.shape), axis=1) + frac, 0.0)
+    drops = np.where(ok, 3.0 * (1 << 33) + np.cumsum(frac, axis=1), 0.0)
+    hi = x.astype(np.float32).astype(np.float64)
+    assert (np.abs(x - hi)[ok] > 0).any() and (x != hi)[ok].mean() > 0.9
+    arrays = {"x": jnp.asarray(x), "drops": jnp.asarray(drops),
+              "rel": slab.rel,
+              "words": tuple(jnp.asarray(a.astype(np.float32))
+                             for a in (hi, x - hi))}
+    lo, hi_, first, last = jax.jit(
+        lambda a: pe._read_edges(slab, a, dict(a)))(arrays)
+    want_lo, want_hi = pe._read_edges(slab)[:2]
+    assert np.array_equal(lo, want_lo) and np.array_equal(hi_, want_hi)
+    has = np.asarray(hi_ > lo)
+    assert has.any() and not has.all()
+    w = slab.width
+    for got, i in ((first, lo), (last, hi_ - 1)):
+        col = slab.col(jnp.clip(i, 0, w - 1))
+        for name, a in arrays.items():
+            if name == "words":
+                a = arrays["x"]
+            want = np.asarray(jnp.take_along_axis(slab.fold(a), col, axis=1))
+            assert got[name].dtype == want.dtype
+            assert np.array_equal(np.asarray(got[name])[has], want[has]), name
+
+
+def test_sweep_passes_counter_and_its_reader(db):
+    """Two traversals a dispatch of the counter kind, fused or not; a
+    matrix kind's program and its sizing pass place the edges, two each;
+    the benchmark's reader divides by the window's requests."""
+    _node_fleet(db)
+    assert _counted(db, PASSES,
+                    'sum by (cpu)(rate(cpu{mode="user"}[5m]))') == 2
+    assert _counted(db, PASSES, "rate(cpu[5m])") == 2
+    assert _counted(db, PASSES, "max_over_time(cpu[5m])") == 4
+    assert _counted(db, PASSES,
+                    'quantile_over_time(0.5, cpu{mode="idle"}[5m])') == 4
+    read = _reader("sweep_passes_per_query").read
+
+    def snap():
+        return {name: REGISTRY.value(name, ()) for name in (ROWS, PASSES)}
+
+    before = snap()
+    for mode in ("user", "idle", "user"):
+        _counted(db, PASSES, 'sum by (cpu)(rate(cpu{mode="%s"}[5m]))' % mode)
+    after = snap()
+    log = [{}] * 3
+    assert read({"metrics_before": before, "metrics_after": after,
+                 "log": log}) == 2.0
+    # the parent's program has no such counter; an idle window no dispatch
+    assert read({"metrics_before": {}, "metrics_after": {ROWS: 9.0},
+                 "log": log}) is None
+    assert read({"metrics_before": after, "metrics_after": after,
+                 "log": log}) is None
