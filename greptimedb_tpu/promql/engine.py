@@ -354,26 +354,39 @@ class SortLayout(typing.NamedTuple):
         return self.val_lo is not None
 
 
+def search_rounds(run_bits: int, n: int) -> int:
+    """Scalar rounds of the first-sample search on a layout of ``n`` rows
+    read in chunks of c = gcd(n, 128): the rounds for bits ``run_bits − 1``
+    down to log2(c); the last log2(c) are one count over the two gathered
+    chunks that hold the c candidates left (``_first_rows``).  Static,
+    like ``sweep_passes``: the program's rounds and the dispatch counter
+    both come from here."""
+    return max(run_bits - (math.gcd(n, 128).bit_length() - 1), 0)
+
+
 def count_dispatch(p: WindowParams, args, selected: int,
                    programs: int = 1) -> None:
     """Count one dispatch of ``programs`` window programs of class ``p``
     over the kernel arguments ``args`` (``_prep_window``'s): ``p``'s own
     and, with two, the sizing pass that places the edges for it."""
+    n = args[0].val_s.shape[0]
     count_window_dispatch(
-        selected, p.num_sel, p.slab_w,
-        swept_columns(p.slab_w, args[0].val_s.shape[0]), programs,
+        selected, p.num_sel, p.slab_w, swept_columns(p.slab_w, n), programs,
         wide=p.wide, passes=sweep_passes(p.kind, p.slab_w)
-        + (programs - 1) * sweep_passes("cnt_max", p.slab_w))
+        + (programs - 1) * sweep_passes("cnt_max", p.slab_w),
+        rounds=search_rounds(p.run_bits, n))
 
 
-def _count_le(probe, length, thr, bits: int):
+def _count_le(probe, length, thr, bits: int, lowest: int = 0):
     """For each query, how many of the first ``length`` elements of its
     ascending run are ≤ ``thr``; ``probe(i)`` reads element ``i`` of each
     query's run.  A branchless binary search unrolled over ``bits``
     rounds (2**bits must exceed every length), each round one gather of
-    a scalar a query — no loop the compiler carries an operand through."""
+    a scalar a query — no loop the compiler carries an operand through.
+    With ``lowest`` the rounds below that bit are left out: the count
+    comes out rounded down to a multiple of 2**lowest."""
     c = jnp.zeros_like(length)
-    for k in reversed(range(bits)):
+    for k in reversed(range(lowest, bits)):
         cand = c + (1 << k)
         c = jnp.where((cand <= length) & (probe(cand - 1) <= thr), cand, c)
     return c
@@ -639,6 +652,65 @@ def _read_edges(slab: Slab, first=None, last=None):
     return lo, hi, out[0], out[1]
 
 
+def _chunks(a, chunk, c: int):
+    """[S, k·c]: the aligned ``c``-row chunks ``chunk`` [S, k] of the 1-D
+    table column ``a``, read as [n/c, c] (a bitcast of the TPU's 1-D
+    tiling): ONE gather op, where the TPU compiler turns a slice-gather
+    from a 1-D operand into a loop with an iteration a series.  A chunk
+    past the table's last reads the last."""
+    n = a.shape[0]
+    return a.reshape(n // c, c)[jnp.clip(chunk, 0, n // c - 1)].reshape(
+        chunk.shape[0], -1)
+
+
+def _chunk_rows(chunk, c: int):
+    """[S, k·c] the table row each column of ``_chunks(a, chunk, c)``
+    stands for (past the table where a chunk was clipped)."""
+    rows = chunk[:, :, None] * c + jnp.arange(c, dtype=jnp.int32)
+    return rows.reshape(chunk.shape[0], -1)
+
+
+def _first_rows(layout: SortLayout, sel_tsids, thr, run_bits: int):
+    """(sel_ok, r0, run, base) [S] of the selected series: whether the
+    slot is a series of the layout, its run [r0, r0 + run) through
+    ``row_ptr``, and ``base``, the first row of the run whose timestamp
+    is past ``thr`` (r0 + the run's count of timestamps ≤ ``thr``).
+
+    The count is ``_count_le``'s search down to bit log2(c), c = gcd(n,
+    128) (``search_rounds`` scalar rounds, none where every run is under c
+    long): r0 + that is q, and the count less q's is under c.  The last
+    log2(c) rounds are one count over rows [q, q + c), which lie in the two
+    aligned chunks from q's on: two [S, 2] gathers of chunk rows
+    (``_chunks``) where the rounds would be 2·log2(c) scalar gathers, at
+    about the price of one each (PERF.md).  The chunks compare as the
+    timestamps' two words against the threshold's, so nothing [S, 2c] is
+    64 bits wide.  The same integer as the whole search, for every slot."""
+    ts_hi, ts_lo, row_ptr = layout.ts_hi, layout.ts_lo, layout.row_ptr
+    n = ts_hi.shape[0]
+    # padding slots (-1) and series newer than the layout own no rows
+    sel_ok = (sel_tsids >= 0) & (sel_tsids < row_ptr.shape[0] - 1)
+    sid = jnp.clip(sel_tsids, 0, row_ptr.shape[0] - 2)
+    r0 = row_ptr[sid]
+    run = jnp.where(sel_ok, row_ptr[sid + 1] - r0, 0)
+
+    def ts_at(i):
+        at = jnp.clip(r0 + i, 0, n - 1)
+        return _join_i64(ts_hi[at], ts_lo[at])
+
+    c = math.gcd(n, 128)
+    q = r0 + _count_le(ts_at, run, thr, run_bits,
+                       run_bits - search_rounds(run_bits, n))
+    pair = (q // c)[:, None] + jnp.arange(2, dtype=jnp.int32)[None, :]
+    rows = _chunk_rows(pair, c)
+    hi, lo = _chunks(ts_hi, pair, c), _chunks(ts_lo, pair, c)
+    thr_hi, thr_lo = _split_i64(thr)
+    le = (hi < thr_hi) | ((hi == thr_hi) & (lo <= thr_lo))
+    # the run ascends and fewer than c of its rows from q on are ≤ thr, so
+    # the run's rows of the two chunks from q on count as rows [q, q + c)
+    cand = (rows >= q[:, None]) & (rows < (r0 + run)[:, None])
+    return sel_ok, r0, run, q + jnp.sum(le & cand, axis=1, dtype=jnp.int32)
+
+
 def _slab_gather(p: WindowParams, layout: SortLayout, sel_tsids,
                  start_ms) -> Slab:
     """The slab of ``_slab_geometry`` before its window edges are placed.
@@ -653,48 +725,30 @@ def _slab_gather(p: WindowParams, layout: SortLayout, sel_tsids,
     one by the same chunks: ``Slab.val`` is the two joined (f64), so no
     consumer of the slab reads half a value, and ``Slab.val_words`` the
     two as they are."""
-    ts_hi, ts_lo, val_s, row_ptr = (layout.ts_hi, layout.ts_lo,
-                                    layout.val_s, layout.row_ptr)
-    T, S, w = p.num_steps, p.num_sel, p.slab_w
+    val_s = layout.val_s
+    T, w = p.num_steps, p.slab_w
     n = val_s.shape[0]
-    # padding slots (-1) and series newer than the layout own no rows
-    sel_ok = (sel_tsids >= 0) & (sel_tsids < row_ptr.shape[0] - 1)
-    sid = jnp.clip(sel_tsids, 0, row_ptr.shape[0] - 2)
-    r0 = row_ptr[sid]
-    run = jnp.where(sel_ok, row_ptr[sid + 1] - r0, 0)
-
-    def ts_at(i):
-        at = jnp.clip(r0 + i, 0, n - 1)
-        return _join_i64(ts_hi[at], ts_lo[at])
-
-    base = r0 + _count_le(ts_at, run, start_ms - p.range_ms, p.run_bits)
-    # the table read as [n/128, 128] (a bitcast of the TPU's 1-D tiling),
-    # whole chunks gathered from the one that holds ``base``: ONE gather op
-    # a column (the TPU compiler turns a W-long slice-gather from a 1-D
-    # operand into a loop with an iteration a series).  One chunk more
-    # than W fills, because ``base`` lies anywhere in the first: columns
-    # before ``base`` or past the run are masked.
+    sel_ok, r0, run, base = _first_rows(layout, sel_tsids,
+                                        start_ms - p.range_ms, p.run_bits)
+    # whole chunks gathered from the one that holds ``base`` (``_chunks``).
+    # One chunk more than W fills, because ``base`` lies anywhere in the
+    # first: columns before ``base`` or past the run are masked.
     c = math.gcd(n, 128)
     k = -(-w // c) + 1
     chunk = (base // c)[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
-    rows = (chunk[:, :, None] * c
-            + jnp.arange(c, dtype=jnp.int32)[None, None, :]).reshape(S, k * c)
+    rows = _chunk_rows(chunk, c)
     before = rows < base[:, None]
     ok = ~before & (rows < (r0 + run)[:, None])
-
-    def take(a):
-        return a.reshape(n // c, c)[
-            jnp.clip(chunk, 0, n // c - 1)].reshape(S, k * c)
 
     if layout.wide:
         # the two words are joined here, once, so that whatever reads
         # ``Slab.val`` reads all of the value (exact: |low| is under half
         # an ulp of high)
-        val_words = tuple(jnp.where(ok, take(a), 0.0)
+        val_words = tuple(jnp.where(ok, _chunks(a, chunk, c), 0.0)
                           for a in (val_s, layout.val_lo))
         val = _join_f64(*val_words)
     else:
-        val = jnp.where(ok, take(val_s), 0.0)
+        val = jnp.where(ok, _chunks(val_s, chunk, c), 0.0)
         val_words = (val,)
     # timestamps rebased to start_ms; int32 where the query's span fits
     # (the compare sweep is the slab's widest pass): integer compares stay
@@ -704,7 +758,8 @@ def _slab_gather(p: WindowParams, layout: SortLayout, sel_tsids,
         tdt, big = np.int32, (1 << 31) - 1
     else:
         tdt, big = np.int64, 1 << 62
-    rel = jnp.clip(_join_i64(take(ts_hi), take(ts_lo)) - start_ms,
+    rel = jnp.clip(_join_i64(_chunks(layout.ts_hi, chunk, c),
+                             _chunks(layout.ts_lo, chunk, c)) - start_ms,
                    -big, big - 1).astype(tdt)
     rel = jnp.where(ok, rel, jnp.where(before, -big - 1, big).astype(tdt))
     # the sweeps read the folded slab, the searches the gathered one

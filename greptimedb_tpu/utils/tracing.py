@@ -94,15 +94,27 @@ M_SWEEP_PASSES = REGISTRY.counter(
     "Compare-select-reduce traversals of the swept slab that dispatched "
     "PromQL window programs emit",
 )
+# Says the chunk count engaged: scalar rounds of the first-sample search
+# in the source of the dispatched programs (promql/engine.py
+# ``search_rounds``): run_bits less log2(gcd(table rows, 128)), 0 where
+# every run is under a chunk; the whole search would count run_bits.
+M_SEARCH_ROUNDS = REGISTRY.counter(
+    "greptime_promql_search_rounds_total",
+    "Scalar gather rounds of the first-sample search that dispatched "
+    "PromQL window programs emit",
+)
 
 
 def count_window_dispatch(selected: int, padded: int, slab_w: int,
                           swept: int, programs: int = 1,
-                          wide: bool = False, passes: int = 0) -> None:
+                          wide: bool = False, passes: int = 0,
+                          rounds: int = 0) -> None:
     """The counters of one PromQL window dispatch: host integers off
     static shapes and the selection's length.  ``passes`` is the sum
-    over the dispatched programs, not one program's."""
+    over the dispatched programs, not one program's; ``rounds`` is one
+    program's."""
     M_SWEEP_PASSES.inc(passes)
+    M_SEARCH_ROUNDS.inc(programs * rounds)
     M_WINDOW_ROWS.inc(programs * padded * slab_w)
     # by 0 off a narrow layout: the counter is there to be read as 0
     M_WIDE_ROWS.inc(programs * padded * slab_w if wide else 0)

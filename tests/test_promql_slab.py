@@ -713,3 +713,187 @@ def test_sweep_passes_counter_and_its_reader(db):
                  "log": log}) is None
     assert read({"metrics_before": after, "metrics_after": after,
                  "log": log}) is None
+
+
+# ---------------------------------------------------------------------------
+# the first-sample search
+# ---------------------------------------------------------------------------
+
+ROUNDS = "greptime_promql_search_rounds_total"
+# the timestamps' high word steps from 2 to 3 here (and the low one wraps)
+T_WRAP = 3 << 32
+
+
+def _runs(n, c, bits, pad):
+    """Run lengths of a layout of ``n`` rows, ``pad`` of them invalid at
+    its end, read in chunks of ``c``: c − 1 (so the next run, c + 1 long,
+    starts at a chunk's last row), 0, 1, c − 1, c, c + 1 and 2**bits − 1,
+    each kept where it is under 2**bits; fillers; and a run that ends at
+    the last valid row, inside the last chunk where nothing is padded."""
+    longest = (1 << bits) - 1
+    lengths = [c - 1] + [m for m in (c + 1, 0, 1, c - 1, c, c + 1, longest, 2)
+                         if m <= longest]
+    tail = max(c // 2, 1)
+    rest = n - pad - sum(lengths) - tail
+    assert rest >= 0
+    while rest:
+        lengths.append(min(rest, longest))
+        rest -= lengths[-1]
+    return lengths + [tail]
+
+
+def _search_layout(n, c, bits, pad, wide):
+    """A (tsid, ts)-sorted layout of ``_runs``' series, every run 30 s
+    apart and laid so that it straddles ``T_WRAP`` at its own phase; the
+    invalid rows hold timestamps below every threshold."""
+    lengths = _runs(n, c, bits, pad)
+    ts = np.full(n, -(1 << 40), np.int64)
+    ptr = np.concatenate([[0], np.cumsum(lengths)]).astype(np.int32)
+    for s, m in enumerate(lengths):
+        phase = (37 * s) % max(m, 1)
+        ts[ptr[s]:ptr[s + 1]] = T_WRAP + 30_000 * (np.arange(m) - phase) + s % 7
+    hi, lo = (np.asarray(a) for a in pe._split_i64(jnp.asarray(ts)))
+    val = jnp.zeros(n, jnp.float32)
+    layout = pe.SortLayout(jnp.asarray(hi), jnp.asarray(lo), val,
+                           jnp.asarray(ptr), val if wide else None)
+    return layout, ts, ptr, lengths
+
+
+@pytest.mark.parametrize("wide", [False, True], ids=["narrow", "wide"])
+@pytest.mark.parametrize("bits", [7, 9])
+@pytest.mark.parametrize("n, c, pad", [
+    (2048, 128, 0), (2048, 128, 300), (64 * 21, 64, 0), (8 * 125, 8, 0),
+    (999, 1, 0)])
+def test_first_rows_are_the_whole_search(n, c, pad, bits, wide):
+    """``base`` found by the search's top rounds and one count over two
+    gathered chunks is, slot for slot, the integer the whole search and
+    numpy's ``searchsorted`` give: runs of 0, 1, c − 1, c, c + 1 and
+    2**bits − 1 samples, a run from a chunk's last row, one in the last
+    chunk (its second chunk clipped), padding slots and series newer than
+    the layout, thresholds before and after every run and across the high
+    word's step; and the slab gathered from it starts there."""
+    assert math.gcd(n, 128) == c
+    layout, ts, ptr, lengths = _search_layout(n, c, bits, pad, wide)
+    assert max(lengths) == (1 << bits) - 1
+    assert pe.search_bits(max(lengths)) == bits
+    total = len(lengths)
+    starts = ptr[:-1][np.asarray(lengths) > 0]
+    assert ((starts % c) == c - 1).any()
+    if not pad:
+        assert ptr[-2] >= n - c     # the last run lies in the last chunk
+    sel = np.concatenate([np.arange(total), [-1, total, total + 5, -1]])
+    sel = jnp.asarray(sel, jnp.int32)
+    first_rows = jax.jit(pe._first_rows, static_argnums=3)
+
+    def whole(thr):
+        sel_ok, r0, run, _base = first_rows(layout, sel, thr, bits)
+
+        def ts_at(i):
+            at = jnp.clip(r0 + i, 0, n - 1)
+            return pe._join_i64(layout.ts_hi[at], layout.ts_lo[at])
+        return r0 + pe._count_le(ts_at, run, thr, bits)
+
+    thresholds = [T_WRAP - (1 << 40), T_WRAP + (1 << 40), T_WRAP - 1, T_WRAP,
+                  T_WRAP + 1, T_WRAP + 3] + [
+        T_WRAP + 30_000 * m + d for m in (-500, -130, -64, -1, 2, 63, 127, 400)
+        for d in (0, 5, 29_999)]
+    for thr in thresholds:
+        thr = jnp.int64(thr)
+        sel_ok, r0, run, base = (np.asarray(a) for a in first_rows(
+            layout, sel, thr, bits))
+        want = np.array([
+            ptr[s] + np.searchsorted(ts[ptr[s]:ptr[s + 1]], int(thr), "right")
+            if 0 <= s < total else ptr[min(max(s, 0), total - 1)]
+            for s in np.asarray(sel)], np.int32)
+        assert np.array_equal(base, want), int(thr)
+        assert np.array_equal(base, np.asarray(whole(thr))), int(thr)
+        assert np.array_equal(sel_ok, (np.asarray(sel) >= 0)
+                              & (np.asarray(sel) < total))
+    # the slab gathered from ``base``: its first readable column, and as
+    # many readable columns as the run has rows from ``base`` on
+    p = pe.WindowParams(step_ms=30_000, num_steps=3, range_ms=300_000,
+                        num_sel=sel.shape[0], total_series=total,
+                        kind="counter", slab_w=128, run_bits=bits, wide=wide)
+    thr = T_WRAP + 30_000 * 2 + 5
+    slab = jax.jit(lambda lay, s, t: pe._slab_gather(p, lay, s, t))(
+        layout, sel, jnp.int64(thr + p.range_ms))
+    _ok, r0, run, base = (np.asarray(a) for a in first_rows(
+        layout, sel, jnp.int64(thr), bits))
+    assert np.array_equal(np.asarray(slab.off), base % c)
+    gathered = slab.ok.shape[1] - base % c
+    assert np.array_equal(np.asarray(slab.ok).sum(axis=1),
+                          np.minimum(r0 + run - base, gathered))
+
+
+@pytest.mark.parametrize("run_bits, rounds", [(7, 0), (12, 5)])
+@pytest.mark.parametrize("wide", [False, True], ids=["narrow", "wide"])
+def test_search_rounds_counted_are_the_scalar_gathers_lowered(wide, run_bits,
+                                                              rounds):
+    """On a table of whole 128-row chunks the search gathers a scalar a
+    series from each timestamp word in ``search_rounds`` rounds (the
+    run's bits less 7), the program's lowered text says so, and the
+    dispatch counter advances by it a program; the rounds it leaves out
+    are one gather of two chunk rows a word."""
+    p = pe.WindowParams(
+        step_ms=30_000, num_steps=_STEPS, range_ms=300_000, num_sel=_SEL,
+        total_series=_SERIES, kind="counter", slab_w=128, run_bits=run_bits,
+        wide=wide)
+    args = _class_args(p, wide)
+    text = jax.jit(pe._window_body(p)).lower(*args).as_text()
+    scalar = re.findall(rf": \(tensor<{_ROWS}xu?i32>, tensor<{_SEL}x1xi32>\)"
+                        rf" -> tensor<{_SEL}xu?i32>", text)
+    # the count's two chunk rows and the slab's (W = 128: two), a word
+    chunk_rows = re.findall(
+        rf": \(tensor<{_ROWS // 128}x128xu?i32>, tensor<{_SEL}x2x1xi32>\)",
+        text)
+    assert pe.search_rounds(run_bits, _ROWS) == rounds
+    assert len(scalar) == 2 * rounds
+    assert len(chunk_rows) == 4
+    for programs in (1, 2):
+        before = REGISTRY.value(ROUNDS, ())
+        pe.count_dispatch(p, args, selected=5, programs=programs)
+        assert REGISTRY.value(ROUNDS, ()) - before == programs * rounds
+
+
+@pytest.mark.parametrize("run_bits, n, rounds", [
+    (7, 4096, 0), (12, 4096, 5), (3, 4096, 0), (16, 2048 * 125, 9),
+    (9, 64 * 21, 3), (9, 999, 9)])
+def test_search_rounds_of_a_shape_class(run_bits, n, rounds):
+    assert pe.search_rounds(run_bits, n) == rounds
+
+
+def test_search_rounds_counter_and_its_reader(db):
+    """A dispatch advances the counter by the rounds of its layout's class,
+    fused or not, twice for a matrix kind (its sizing pass searches too);
+    the benchmark's reader divides by the window's requests."""
+    _node_fleet(db)
+    q = 'sum by (cpu)(rate(cpu{mode="user"}[5m]))'
+    ev = pe.PromEvaluator(db, T0_S + 4000, T0_S + 7600, 60)
+    args, p, *_rest = ev._prep_window(
+        parse_promql(q).expr.args[0], "counter")
+    # 600 samples a series (10 bits) in a table of whole 128-row chunks
+    assert (p.run_bits, args[0].val_s.shape[0] % 128) == (10, 0)
+    rounds = pe.search_rounds(p.run_bits, args[0].val_s.shape[0])
+    assert rounds == 3
+    assert _counted(db, ROUNDS, q) == rounds
+    assert _counted(db, ROUNDS, "rate(cpu[5m])") == rounds
+    assert _counted(db, ROUNDS,
+                    'quantile_over_time(0.5, cpu{mode="idle"}[5m])') \
+        == 2 * rounds
+    read = _reader("search_rounds_per_query").read
+
+    def snap():
+        return {name: REGISTRY.value(name, ()) for name in (ROWS, ROUNDS)}
+
+    before = snap()
+    for mode in ("user", "idle", "user"):
+        _counted(db, ROUNDS, 'sum by (cpu)(rate(cpu{mode="%s"}[5m]))' % mode)
+    after = snap()
+    log = [{}] * 3
+    assert read({"metrics_before": before, "metrics_after": after,
+                 "log": log}) == rounds
+    # the parent's program has no such counter; an idle window no dispatch
+    assert read({"metrics_before": {}, "metrics_after": {ROWS: 9.0},
+                 "log": log}) is None
+    assert read({"metrics_before": after, "metrics_after": after,
+                 "log": log}) is None

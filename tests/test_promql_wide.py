@@ -248,35 +248,35 @@ def test_samples_rounded_to_float32_miss_the_tolerance(db, func):
 
 
 # lowered text (sha256, 16 hex) of the narrow programs, padded series 8 of
-# 12, 7 steps of 30 s over [5m], a layout of 4,096 rows: PR 38's tree (one
-# traversal a window edge), but ``minmax-*``, which are PR 35's still.
+# 12, 7 steps of 30 s over [5m], a layout of 4,096 rows: one traversal a
+# window edge, and a first-sample search that finishes on a gathered chunk.
 # `python tests/test_promql_wide.py` prints this table for the tree it is
 # run in.  A PR that changes the f32 program on purpose replaces them.
 NARROW_PROGRAMS = {
-    "counter-128": "fc402417e200b5a8",
-    "counter-512": "6286130b19563b0c",
-    "counter-64": "fc402417e200b5a8",
-    "counter_rc-128": "0525f546319493b6",
-    "counter_rc-512": "d8233249028f3bb9",
-    "counter_rc-64": "0525f546319493b6",
-    "fused-rate-sum-128": "dbddba2a42cacbdc",
-    "fused-rate-sum-512": "3b4a94099216a334",
-    "fused-rate-sum-64": "dbddba2a42cacbdc",
-    "gauge_window-128": "8607c57812f80875",
-    "gauge_window-512": "3c168501e9dae454",
-    "gauge_window-64": "8607c57812f80875",
-    "instant-128": "e1e397ccd3ed67b3",
-    "instant-512": "6c78f2b455934fbd",
-    "instant-64": "e1e397ccd3ed67b3",
-    "irate-128": "2509e5d5c27ba973",
-    "irate-512": "2822eadf6728d70e",
-    "irate-64": "2509e5d5c27ba973",
-    "minmax-128": "1a379d25f3ef37fc",
-    "minmax-512": "c0747ffabcd1142e",
-    "minmax-64": "1a379d25f3ef37fc",
-    "regression-128": "2b73eee2142ef6be",
-    "regression-512": "49cd306fcbf85ad3",
-    "regression-64": "2b73eee2142ef6be",
+    "counter-128": "0bfb1735ef9541d9",
+    "counter-512": "f485705fa959afa0",
+    "counter-64": "0bfb1735ef9541d9",
+    "counter_rc-128": "bface2dca77351a4",
+    "counter_rc-512": "6531cce050ca8f78",
+    "counter_rc-64": "bface2dca77351a4",
+    "fused-rate-sum-128": "0acd63ef76a8616e",
+    "fused-rate-sum-512": "fdd1f23ff2a7af6f",
+    "fused-rate-sum-64": "0acd63ef76a8616e",
+    "gauge_window-128": "91d0b66a2fa3c3db",
+    "gauge_window-512": "f45af23366d7808d",
+    "gauge_window-64": "91d0b66a2fa3c3db",
+    "instant-128": "00121bfeeefcef17",
+    "instant-512": "ff757543591d85df",
+    "instant-64": "00121bfeeefcef17",
+    "irate-128": "bee19e58920b4c28",
+    "irate-512": "68a4d4c940a91629",
+    "irate-64": "bee19e58920b4c28",
+    "minmax-128": "48df3d7ef8ed437e",
+    "minmax-512": "750694d1b412a2c6",
+    "minmax-64": "48df3d7ef8ed437e",
+    "regression-128": "c318a813300cad9e",
+    "regression-512": "5405542656c91d77",
+    "regression-64": "c318a813300cad9e",
 }
 # the same of ``_build_sort_layout`` over a table of 4,096 rows: a compile
 # cache that holds the narrow layout's program keeps serving it
@@ -328,8 +328,8 @@ def narrow_programs(widths=(64, 128, 512)) -> dict:
 def test_narrow_column_keeps_layout_key_and_program(db, w):
     """Magnitudes under 2^24: no low word in the resident table, the
     four-array layout with f32 values, the class key without the new
-    field, and the programs PR 35's tree lowered, text for text — so the
-    outputs are that tree's too."""
+    field, and the programs of ``NARROW_PROGRAMS``, text for text — so
+    the outputs are theirs too."""
     data = {name: (ts, vals % 1e6) for name, (ts, vals) in series().items()}
     load(db, data)
     cols = db.cache.get(db._region_of("m")).columns
