@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from greptimedb_tpu.compile import named_jit
 from greptimedb_tpu.errors import TableNotFound
-from greptimedb_tpu.utils.tracing import TRACER
+from greptimedb_tpu.utils.tracing import TRACER, count_histogram_dispatch
 
 # diagnostics: fused dispatches this process (tests/bench read it)
 FUSED_DISPATCHES = {"count": 0}
@@ -104,9 +104,12 @@ def _apply_func(func, p, out, start_ms, range_s):
     return table[func]()
 
 
-def _build_fused(p, func, op, ng, n_sel, range_s):  # gl: warm-path
+def _build_fused(p, func, op, ng, n_sel, range_s, hist=None):  # gl: warm-path
     """One program: window stats → epilogue → group reduce.  Returned
-    unjitted; the caller jits (and AOT-persists) it."""
+    unjitted; the caller jits (and AOT-persists) it.  With ``hist`` =
+    (q, K) the ``ng`` = G·K sums are the buckets of G histograms, folded
+    to their q-quantile (``histogram_fold``) in the same program, which
+    then takes the K bounds as a fifth argument."""
     from greptimedb_tpu.promql import engine as pe
 
     body = pe._window_body(p)
@@ -145,13 +148,20 @@ def _build_fused(p, func, op, ng, n_sel, range_s):  # gl: warm-path
         red = gseg(jnp.where(present, v, fill), segf)
         return jnp.where(has, red, jnp.nan)
 
-    return fused
+    if hist is None:
+        return fused
+    q, k = hist
+
+    def fused_histogram(layout, sel_tsids, start_ms, gid, bounds):
+        sums = fused(layout, sel_tsids, start_ms, gid)  # [G·K, T]
+        return pe.histogram_fold(sums.reshape(ng // k, k, -1), bounds, q)
+
+    return fused_histogram
 
 
-def try_fused_aggregation(ev, e):
-    """Fused evaluation of one Aggregation node, or None (evaluator
-    falls back to the multi-kernel path).  ``ev`` is the PromEvaluator."""
-    from greptimedb_tpu.promql import engine as pe
+def _chain(e):
+    """(function, selector) of an aggregation over one window function
+    of one selector — the chain the fused program runs — or None."""
     from greptimedb_tpu.promql.parser import FunctionCall, VectorSelector
 
     inner = e.expr
@@ -171,24 +181,29 @@ def try_fused_aggregation(ev, e):
             return None  # unfused raises the canonical PlanError
     else:
         return None
-    if e.op not in _FUSED_AGGS or e.param is not None:
-        return None
     if sel.at_ts is not None:
         return None  # pinned @: broadcast semantics stay unfused
-    kind = _FUNC_KIND[func]
+    return func, sel
+
+
+def _prepare(ev, func, sel):
+    """``_prep_window``'s (args, p, tsids, labels), or None where the
+    chain stays unfused (unknown metric, pinned, nothing selected)."""
     try:
-        prep = ev._prep_window(sel, kind)
+        prep = ev._prep_window(sel, _FUNC_KIND[func])
     except TableNotFound:
         return None  # unknown metric: unfused produces the empty vector
-    args, p, tsids, labels, pinned, _start, rng = prep
+    args, p, tsids, labels, pinned, _start, _rng = prep
     if pinned or len(tsids) == 0:
         return None
-    with TRACER.stage("group_agg", op=e.op) as st:
-        gid_dev, ng, out_labels, _ro, _ss = ev._group_series_of(
-            e, labels, len(tsids))
-    ev._stage_mark("group_agg", st)
-    range_s = sel.range_s if func in _NEEDS_RANGE else None
-    key = ("promql_fused", p, func, e.op, ng, len(tsids), range_s)
+    return args, p, tsids, labels
+
+
+def _dispatch(ev, key, build, args, tsids, gid_dev, p, extra=(), **attrs):
+    """Run the fused program of ``key`` (built by ``build`` on a miss)
+    over the window arguments and the group ids (+ ``extra``)."""
+    from greptimedb_tpu.promql import engine as pe
+
     kern = pe._KERNEL_CACHE.get(key)
     jit_miss = kern is None
     if kern is None:
@@ -196,11 +211,7 @@ def try_fused_aggregation(ev, e):
 
         compiler = getattr(ev.db, "plan_compiler", None) or \
             default_compiler()
-        kern = compiler.get_or_build(
-            "promql", key,
-            lambda: named_jit(f"promql_fused_{e.op}")(_build_fused(
-                p, func, e.op, ng, len(tsids), range_s)),
-            persist=True)
+        kern = compiler.get_or_build("promql", key, build, persist=True)
         pe._KERNEL_CACHE[key] = kern
     fused_args = args + (gid_dev,)
     mesh = getattr(ev.db, "mesh", None)
@@ -227,10 +238,81 @@ def try_fused_aggregation(ev, e):
     compiling = jit_miss and not getattr(kern, "aot", False)
     pe.count_dispatch(p, args, len(tsids))
     vals = ev._timed_kernel(
-        "fused_kernel", lambda: kern(*fused_args), jit_miss, compiling,
-        op=e.op, func=func or "instant")
+        "fused_kernel", lambda: kern(*fused_args, *extra), jit_miss,
+        compiling, **attrs)
     from greptimedb_tpu.compile.service import M_FUSED_DISPATCH
 
     M_FUSED_DISPATCH.labels("promql").inc()
     FUSED_DISPATCHES["count"] += 1
+    return vals
+
+
+def try_fused_aggregation(ev, e):
+    """Fused evaluation of one Aggregation node, or None (evaluator
+    falls back to the multi-kernel path).  ``ev`` is the PromEvaluator."""
+    from greptimedb_tpu.promql import engine as pe
+
+    chain = _chain(e)
+    if chain is None or e.op not in _FUSED_AGGS or e.param is not None:
+        return None
+    func, sel = chain
+    prep = _prepare(ev, func, sel)
+    if prep is None:
+        return None
+    args, p, tsids, labels = prep
+    with TRACER.stage("group_agg", op=e.op) as st:
+        gid_dev, ng, out_labels, _ro, _ss = ev._group_series_of(
+            e, labels, len(tsids))
+    ev._stage_mark("group_agg", st)
+    range_s = sel.range_s if func in _NEEDS_RANGE else None
+    key = ("promql_fused", p, func, e.op, ng, len(tsids), range_s)
+    vals = _dispatch(
+        ev, key,
+        lambda: named_jit(f"promql_fused_{e.op}")(_build_fused(
+            p, func, e.op, ng, len(tsids), range_s)),
+        args, tsids, gid_dev, p, op=e.op, func=func or "instant")
+    return pe.EvalResult(vals, out_labels)
+
+
+def try_fused_histogram(ev, e, q: float):
+    """``histogram_quantile(q, sum by (le, …)(<window fn>(sel[r])))`` as
+    ONE program, or None (the evaluator folds the evaluated argument).
+    The series are numbered o·K + k (outer group, bucket slot; promql/
+    engine.py ``_series_bucket_slots``, cached with the group ids), so the
+    program's own ``sum`` segment reduce yields the [G·K, T] buckets, and
+    the fold follows in the same program.  Its key is the ``sum``
+    program's with the quantile's (q, K, G) appended; every other key,
+    program and output is the one ``try_fused_aggregation`` builds."""
+    from greptimedb_tpu.promql import engine as pe
+    from greptimedb_tpu.promql.parser import Aggregation
+
+    agg = e.args[1]
+    if (type(agg) is not Aggregation or agg.op != "sum" or agg.without
+            or agg.param is not None or "le" not in (agg.grouping or ())):
+        return None
+    chain = _chain(agg)
+    if chain is None:
+        return None
+    func, sel = chain
+    prep = _prepare(ev, func, sel)
+    if prep is None:
+        return None
+    args, p, tsids, labels = prep
+    with TRACER.stage("group_agg", op="histogram_quantile") as st:
+        slots = ev._bucket_slots_of(agg, labels, len(tsids))
+    ev._stage_mark("group_agg", st)
+    if slots is None:
+        return None
+    gid_dev, g, k, bounds_dev, out_labels, filled = slots
+    ng = g * k
+    range_s = sel.range_s if func in _NEEDS_RANGE else None
+    spec = ("histogram_quantile", q if q == q else "NaN", k, g)
+    key = ("promql_fused", p, func, "sum", ng, len(tsids), range_s, spec)
+    count_histogram_dispatch("fused", g, k, filled)
+    vals = _dispatch(
+        ev, key,
+        lambda: named_jit("promql_fused_histogram_quantile")(_build_fused(
+            p, func, "sum", ng, len(tsids), range_s, hist=(q, k))),
+        args, tsids, gid_dev, p, extra=(bounds_dev,), op="sum",
+        func="histogram_quantile", groups=g, buckets=k)
     return pe.EvalResult(vals, out_labels)

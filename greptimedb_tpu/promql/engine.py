@@ -38,7 +38,9 @@ from greptimedb_tpu.promql.parser import (
     StringLit, SubqueryExpr, UnaryExpr, VectorSelector, parse_promql,
 )
 from greptimedb_tpu.storage.memtable import TSID
-from greptimedb_tpu.utils.tracing import TRACER, count_window_dispatch
+from greptimedb_tpu.utils.tracing import (
+    TRACER, count_histogram_dispatch, count_window_dispatch,
+)
 
 DEFAULT_LOOKBACK_S = 300.0
 
@@ -185,6 +187,16 @@ def _series_group_ids(idx, tsids: np.ndarray, grouping, without: bool):
 
     Returns (gid_dev [S] i32, ng, rep_rows np [ng], row_order_dev [S],
     seg_start np [ng])."""
+    gids, ng, rep_rows = _group_ids_host(idx, tsids, grouping, without)
+    row_order = np.argsort(gids, kind="stable")
+    seg_start = np.searchsorted(gids[row_order], np.arange(ng))
+    return (jnp.asarray(gids), ng, rep_rows, jnp.asarray(row_order),
+            seg_start)
+
+
+def _group_ids_host(idx, tsids: np.ndarray, grouping, without: bool):
+    """``_series_group_ids``' numbering on the host: (gids np [S] i32,
+    ng, rep_rows np [ng])."""
     if without:
         use = sorted(n for n in idx.tag_names if n not in grouping)
     else:
@@ -209,13 +221,84 @@ def _series_group_ids(idx, tsids: np.ndarray, grouping, without: bool):
     order = np.argsort(first_idx, kind="stable")
     rank = np.empty(len(_uniq), dtype=np.int64)
     rank[order] = np.arange(len(_uniq))
-    gids = rank[inv].astype(np.int32)
-    ng = len(_uniq)
-    rep_rows = first_idx[order]
-    row_order = np.argsort(gids, kind="stable")
-    seg_start = np.searchsorted(gids[row_order], np.arange(ng))
-    return (jnp.asarray(gids), ng, rep_rows, jnp.asarray(row_order),
-            seg_start)
+    return rank[inv].astype(np.int32), len(_uniq), first_idx[order]
+
+
+def _group_key(grouping, without: bool):
+    """The host group-key rule: label dict → ((name, str value), ...),
+    the one definition both group-id providers and the output labels
+    read."""
+
+    def key(lab: dict) -> tuple:
+        if without:
+            keys = sorted(k for k in lab if k not in grouping)
+        else:
+            keys = sorted(grouping or ())
+        return tuple((k, str(lab.get(k, ""))) for k in keys)
+
+    return key
+
+
+def _bucket_bound(le) -> float:
+    """An ``le`` label's upper bound (``+Inf`` included), or NaN where it
+    is missing or does not parse: Prometheus skips such a series."""
+    try:
+        return float(str(le))
+    except ValueError:
+        return math.nan
+
+
+def _series_bucket_slots(idx, tsids: np.ndarray, outer):
+    """The histogram numbering of a selection, vectorized from the
+    dictionary codes like ``_series_group_ids``: outer group o by the
+    labels ``outer`` (``by`` semantics), bucket slot k by the parsed
+    ``le`` value among the selection's distinct bounds in ascending
+    order (``1`` and ``1.0`` are one slot), series id o·K + k, so that a
+    segment sum into G·K slots reshapes to [G, K, ·].  A series whose
+    ``le`` does not parse goes to the dead slot G·K.  Returns (slots np
+    [S] i32, G, K, bounds np f64 [K], rep_rows np [G], filled slots), or
+    None where no series has a bucket."""
+    gids, ng, rep_rows = _group_ids_host(idx, tsids, outer, False)
+    vocab = idx.vocabs.get("le", [])
+    parsed = np.array([_bucket_bound(t) for t in vocab] + [math.nan])
+    codes = idx.codes_for("le", tsids.astype(np.int64))
+    bound = parsed[np.where((codes >= 0) & (codes < len(vocab)), codes,
+                            len(vocab))]
+    return _number_slots(gids, ng, bound, rep_rows)
+
+
+def _number_slots(gids: np.ndarray, ng: int, bound: np.ndarray, rep_rows):
+    ok = ~np.isnan(bound)
+    bounds = np.unique(bound[ok])
+    if not len(bounds):
+        return None
+    k = len(bounds)
+    slots = np.where(ok, gids.astype(np.int64) * k
+                     + np.searchsorted(bounds, np.where(ok, bound, 0.0)),
+                     ng * k)
+    filled = len(np.unique(slots[ok]))
+    return slots.astype(np.int32), ng, k, bounds, rep_rows, filled
+
+
+def _label_bucket_slots(labels):
+    """``_series_bucket_slots`` over label dicts (whatever produced the
+    series): the outer group is every label but ``le``.  Returns the same
+    tuple with the outer groups' label dicts in place of rep_rows."""
+    n = len(labels)
+    gids = np.zeros(n, np.int32)
+    bound = np.empty(n)
+    groups: dict[tuple, int] = {}
+    out_labels: list[dict] = []
+    for i, lab in enumerate(labels):
+        bound[i] = _bucket_bound(lab.get("le", ""))
+        if math.isnan(bound[i]):
+            continue
+        key = tuple(sorted((k, str(v)) for k, v in lab.items() if k != "le"))
+        if key not in groups:
+            groups[key] = len(out_labels)
+            out_labels.append({k: v for k, v in lab.items() if k != "le"})
+        gids[i] = groups[key]
+    return _number_slots(gids, len(out_labels), bound, out_labels)
 
 
 # ---------------------------------------------------------------------------
@@ -1934,39 +2017,13 @@ class PromEvaluator:
         """_group_series over bare (labels, n) — the fused chain
         (compile/fused.py) groups straight off the selection, before any
         EvalResult exists.  Same providers, same caches, one definition."""
-
-        def group_key(lab: dict) -> tuple:
-            if e.without:
-                keys = sorted(k for k in lab if k not in e.grouping)
-            elif e.grouping:
-                keys = [k for k in sorted(e.grouping)]
-            else:
-                keys = []
-            return tuple((k, str(lab.get(k, ""))) for k in keys)
-
+        group_key = _group_key(e.grouping, e.without)
         gspec = ("without" if e.without else "by",
                  tuple(sorted(e.grouping or ())))
         if isinstance(labels, LazySeriesLabels) and n == len(labels.tsids):
-            cache = labels.cache
-            ckey = (labels.matcher_key, gspec)
-            payload = None
-            if cache is not None:
-                payload = cache.lookup("group", labels.region_id, ckey,
-                                       labels.generation)
-                self.cache_events["group_hit" if payload is not None
-                                  else "group_miss"] += 1
-            if payload is None:
-                payload = _series_group_ids(labels.idx, labels.tsids,
-                                            e.grouping or [], e.without)
-                if cache is not None:
-                    nbytes = sum(
-                        int(a.nbytes) for a in payload
-                        if hasattr(a, "nbytes"))
-                    if cache.admit(nbytes):
-                        cache.store("group", labels.region_id, ckey,
-                                    labels.generation, payload, nbytes)
-                    else:
-                        self.cache_events["group_reject"] += 1
+            payload = self._cached_group(
+                labels, gspec, lambda: _series_group_ids(
+                    labels.idx, labels.tsids, e.grouping or [], e.without))
             gid_dev, ng, rep_rows, row_order_dev, seg_start = payload
             out_labels = LazyGroupLabels(labels, rep_rows, group_key)
             return gid_dev, ng, out_labels, row_order_dev, seg_start
@@ -1985,6 +2042,59 @@ class PromEvaluator:
         seg_start = np.searchsorted(gids[row_order], np.arange(ng))
         return (jnp.asarray(gids), ng, out_labels, jnp.asarray(row_order),
                 seg_start)
+
+    def _cached_group(self, labels: LazySeriesLabels, gspec: tuple, build):
+        """A selection's grouping payload from the group-id cache (key:
+        the matchers and ``gspec``), ``build()`` on a miss and kept where
+        the cache admits it; None where ``build`` finds nothing."""
+        cache = labels.cache
+        ckey = (labels.matcher_key, gspec)
+        payload = None
+        if cache is not None:
+            payload = cache.lookup("group", labels.region_id, ckey,
+                                   labels.generation)
+            self.cache_events["group_hit" if payload is not None
+                              else "group_miss"] += 1
+        if payload is None:
+            payload = build()
+            if cache is not None and payload is not None:
+                nbytes = sum(
+                    int(a.nbytes) for a in payload
+                    if hasattr(a, "nbytes"))
+                if cache.admit(nbytes):
+                    cache.store("group", labels.region_id, ckey,
+                                labels.generation, payload, nbytes)
+                else:
+                    self.cache_events["group_reject"] += 1
+        return payload
+
+    def _bucket_slots_of(self, e: Aggregation, labels, n: int):
+        """``_series_bucket_slots`` of a selection under ``sum by (le,
+        …)``, held in the group-id cache beside the plain group ids (key:
+        the matchers and the grouping): (slots_dev [S] i32, G, K,
+        bounds_dev [K] f32, outer labels, filled slots), or None where the
+        labels are not the selection's or no series has a bucket."""
+        if not (isinstance(labels, LazySeriesLabels)
+                and n == len(labels.tsids)):
+            return None
+        outer = sorted(k for k in e.grouping if k != "le")
+
+        def build():
+            slots = _series_bucket_slots(labels.idx, labels.tsids, outer)
+            if slots is None:
+                return None
+            gids, ng, k, bounds, rep_rows, filled = slots
+            return (jnp.asarray(gids), ng, k,
+                    jnp.asarray(bounds, jnp.float32), rep_rows, filled)
+
+        payload = self._cached_group(labels, ("histogram", tuple(outer)),
+                                     build)
+        if payload is None:
+            return None
+        gid_dev, ng, k, bounds_dev, rep_rows, filled = payload
+        return (gid_dev, ng, k, bounds_dev,
+                LazyGroupLabels(labels, rep_rows, _group_key(outer, False)),
+                filled)
 
     def eval_aggregation(self, e: Aggregation) -> EvalResult:
         # whole-plan fusion: selection→window→group as ONE device
@@ -2212,52 +2322,32 @@ class PromEvaluator:
 
     # ---- histogram_quantile -------------------------------------------------
     def _histogram_quantile(self, e: FunctionCall) -> EvalResult:
-        q = e.args[0].value if isinstance(e.args[0], NumberLit) else 0.5
+        """``histogram_quantile(φ, buckets)``: ``sum by (le, …)`` over a
+        window function of one selector runs as one fused program
+        (compile/fused.py ``try_fused_histogram``); anything else is
+        evaluated, numbered on the host (``_label_bucket_slots``) and
+        folded by one program.  Both fold with ``histogram_fold``."""
+        if len(e.args) != 2:
+            raise PlanError("histogram_quantile(φ, buckets)")
+        q = self._scalar_param(e.args[0], "histogram_quantile")
+        from greptimedb_tpu.compile.fused import try_fused_histogram
+
+        fused = try_fused_histogram(self, e, q)
+        if fused is not None:
+            return fused
         r = self.eval(e.args[1])
-        groups: dict[tuple, list[tuple[float, int]]] = {}
-        glabels: dict[tuple, dict] = {}
-        for i, lab in enumerate(r.labels):
-            le_raw = str(lab.get("le", ""))
-            try:
-                le = float(le_raw.replace("+Inf", "inf"))
-            except ValueError:
-                continue
-            key = tuple(sorted((k, str(v)) for k, v in lab.items() if k != "le"))
-            groups.setdefault(key, []).append((le, i))
-            glabels[key] = {k: v for k, v in lab.items() if k != "le"}
-        out_vals = []
-        out_labels = []
-        for key, buckets in groups.items():
-            buckets.sort()
-            les = np.array([b[0] for b in buckets], dtype=np.float64)
-            rows = jnp.asarray([b[1] for b in buckets])
-            counts = r.values[rows]  # [B, T] cumulative
-            if not math.isinf(les[-1]):
-                continue  # spec: need +Inf bucket
-            total = counts[-1]
-            rank = q * total
-            # first bucket with count >= rank
-            ge = counts >= rank[None, :]
-            idx = jnp.argmax(ge, axis=0)
-            idx = jnp.clip(idx, 0, len(buckets) - 1)
-            lo_le = jnp.asarray(
-                np.concatenate([[0.0], les[:-1]]), dtype=jnp.float32
-            )[idx]
-            hi_le = jnp.asarray(les, dtype=jnp.float32)[idx]
-            lo_cnt = jnp.concatenate(
-                [jnp.zeros((1, counts.shape[1]), counts.dtype), counts[:-1]], axis=0
-            )[idx, jnp.arange(counts.shape[1])]
-            hi_cnt = counts[idx, jnp.arange(counts.shape[1])]
-            frac = jnp.where(hi_cnt > lo_cnt, (rank - lo_cnt) / (hi_cnt - lo_cnt), 1.0)
-            val = lo_le + (hi_le - lo_le) * jnp.clip(frac, 0, 1)
-            # highest bucket: return lower bound of +Inf bucket
-            val = jnp.where(jnp.isinf(hi_le), lo_le, val)
-            val = jnp.where(total > 0, val, jnp.nan)
-            out_vals.append(val.astype(jnp.float32))
-            out_labels.append(glabels[key])
-        if not out_vals:
+        slots = None if r.is_scalar else _label_bucket_slots(r.labels)
+        if slots is None:
             return EvalResult(jnp.zeros((0, self.num_steps), jnp.float32), [])
-        return EvalResult(jnp.stack(out_vals), out_labels)
+        gids, ng, k, bounds, out_labels, filled = slots
+        with TRACER.stage("group_agg", op="histogram_quantile", groups=ng,
+                          buckets=k) as st:
+            count_histogram_dispatch("unfused", ng, k, filled)
+            vals = _series_histogram(r.values, jnp.asarray(gids),
+                                     jnp.asarray(bounds, jnp.float32),
+                                     jnp.float32(q), ng * k, k)
+        self._stage_mark("group_agg", st)
+        return EvalResult(vals, out_labels)
 
 
 def _instant_pair(f: str, last_ts, prev_ts, last_val, prev_val,
@@ -2305,6 +2395,106 @@ def _extrapolated(out: dict, range_s: float, range_end_ms: np.ndarray,
     if is_rate:
         result = result / range_s
     return jnp.where(cnt >= 2, result.astype(jnp.float32), jnp.nan)
+
+
+# A rank this close above a bucket's count, as a share of the histogram's
+# total, is a tie with it where the next bucket is empty.  Buckets summed
+# from rates in float32 carry errors of a few 2^-24 of the total; where
+# the exact rank equals a bucket's count (every integer count, one
+# extrapolation: the rank is q * total) it would otherwise land above or
+# below at random, and with an empty bucket next a whole bucket's width
+# apart.  Within this much every side keeps the tie's answer, as
+# Prometheus's ``>=`` gives it in exact arithmetic.  Where the next
+# bucket is not empty Prometheus's answer has no step there, and the rank
+# interpolates into that bucket as Prometheus's does.
+RANK_TOLERANCE = 2.0 ** -18
+
+
+def histogram_fold(counts, bounds, q):
+    """Prometheus's ``bucketQuantile`` (promql/quantile.go) for every
+    group and step at once: ``counts`` [G, K, T] cumulative bucket counts
+    with NaN where a bucket has no sample at a step, ``bounds`` [K]
+    ascending and distinct (equal bounds are one slot: their counts were
+    summed where the slots were numbered), ``q`` a scalar; [G, T] f32,
+    NaN where there is no answer.
+
+    At each step only the buckets present count: the highest must be
+    ``+Inf`` and there must be two, else NaN; counts are forced monotonic
+    (a running max along K); no observations is NaN; ``b`` is the first
+    bucket with count ≥ q · total (within ``RANK_TOLERANCE``) over all
+    but the last, found by compares along K; ``b`` the last gives the
+    highest finite bound; a rank within the tolerance above ``b``'s
+    count interpolates into the next present bucket from ``b``'s bound,
+    or is that bound where the next is empty or ``+Inf``; ``b`` the first
+    with a bound ≤ 0 that bound; else linear interpolation inside ``b``.  q < 0 is −Inf, q > 1 +Inf,
+    NaN NaN wherever a bucket is present.  No gather and no loop: K is a
+    histogram's buckets, a few dozen."""
+    counts = counts.astype(jnp.float32)
+    g, k, t = counts.shape
+    q = jnp.asarray(q, jnp.float32)
+    neg = jnp.float32(-jnp.inf)
+    bound = jnp.broadcast_to(
+        jnp.asarray(bounds, jnp.float32).reshape(1, k, 1), counts.shape)
+    valid = ~jnp.isnan(counts)
+    present = valid.any(axis=1)
+
+    def before(x):  # x at the bucket below each slot, −inf below the first
+        return jnp.concatenate([jnp.full((g, 1, t), neg), x[:, :-1]], axis=1)
+
+    def after(x):  # x at the nearest present bucket above each slot
+        x = jax.lax.cummin(jnp.where(valid, x, -neg), axis=1, reverse=True)
+        return jnp.concatenate([x[:, 1:], jnp.full((g, 1, t), -neg)], axis=1)
+
+    # over the present buckets: the monotonic counts, the bound of each
+    # one's predecessor; absent slots carry their predecessor's
+    mono = jax.lax.cummax(jnp.where(valid, counts, neg), axis=1)
+    below = before(mono)
+    below_bound = before(jax.lax.cummax(jnp.where(valid, bound, neg), axis=1))
+    total = mono[:, -1]
+    ok = (valid[:, -1] & (bound[:, -1] == jnp.inf) & (valid.sum(axis=1) >= 2)
+          & (total > 0))
+    rank = q * total
+    reach = (rank - RANK_TOLERANCE * total)[:, None]
+    first = valid & (below == neg)
+    hit = (valid & (jnp.arange(k) < k - 1)[None, :, None]
+           & (mono >= reach) & (below < reach))
+
+    def at_b(x):
+        return jnp.where(hit, x, 0).sum(axis=1)
+
+    lo = at_b(jnp.where(first, 0, below))
+    start = at_b(jnp.where(first, 0, below_bound))
+    end = at_b(bound)
+    top = at_b(mono)
+    frac = jnp.minimum((rank - lo) / (top - lo), 1)
+    val = start + (end - start) * frac
+    val = jnp.where((hit & first & (bound <= 0)).any(axis=1), end, val)
+    # the rank within the tolerance above b's count: into the next bucket
+    next_top, next_end = at_b(after(mono)), at_b(after(bound))
+    runs_on = (next_top > top) & (next_end < -neg)
+    past = end + (next_end - end) * jnp.minimum(
+        (rank - top) / (next_top - top), 1)
+    val = jnp.where(top < rank, jnp.where(runs_on, past, end), val)
+    val = jnp.where(hit.any(axis=1), val, below_bound[:, -1])
+    special = jnp.where(q < 0, -jnp.inf, jnp.where(q > 1, jnp.inf, jnp.nan))
+    inside = (q >= 0) & (q <= 1)
+    return jnp.where(inside, jnp.where(ok, val, jnp.nan),
+                     jnp.where(present, special, jnp.nan)).astype(jnp.float32)
+
+
+@named_jit("promql_histogram_quantile", static_argnums=(4, 5))
+def _series_histogram(values, slots, bounds, q, n_slots: int, k: int):
+    """The unfused fold: [S, T] bucket series, each with its slot
+    (o · K + k, the dead slot ``n_slots`` for none), summed into their
+    slots as ``sum`` sums them (NaN where no member has a sample), then
+    ``histogram_fold``; one program a shape."""
+    present = ~jnp.isnan(values)
+    cnt = jax.ops.segment_sum(present.astype(jnp.int32), slots,
+                              num_segments=n_slots + 1)[:n_slots]
+    s = jax.ops.segment_sum(jnp.where(present, values, 0), slots,
+                            num_segments=n_slots + 1)[:n_slots]
+    counts = jnp.where(cnt > 0, s, jnp.nan)
+    return histogram_fold(counts.reshape(n_slots // k, k, -1), bounds, q)
 
 
 # ---------------------------------------------------------------------------

@@ -105,6 +105,35 @@ M_SEARCH_ROUNDS = REGISTRY.counter(
 )
 
 
+# Say which road histogram_quantile took and how full its [G, K] was
+# (promql/engine.py ``histogram_fold``): the quantile series a program
+# computes by road, and the (group, bucket) slots it folds, all and those
+# that hold a bucket of the selection.
+M_HISTOGRAM_QUANTILES = REGISTRY.counter(
+    "greptime_promql_histogram_quantiles_total",
+    "Quantile series computed by dispatched histogram_quantile programs",
+    labels=("path",),
+)
+M_HISTOGRAM_BUCKETS = REGISTRY.counter(
+    "greptime_promql_histogram_buckets_total",
+    "Group x bucket slots folded by dispatched histogram_quantile programs",
+)
+M_HISTOGRAM_FILLED = REGISTRY.counter(
+    "greptime_promql_histogram_filled_buckets_total",
+    "Group x bucket slots of dispatched histogram_quantile programs that "
+    "hold a bucket series",
+)
+
+
+def count_histogram_dispatch(path: str, groups: int, buckets: int,
+                             filled: int) -> None:
+    """The counters of one histogram_quantile dispatch on ``path``
+    (``fused`` or ``unfused``): host integers off its numbering."""
+    M_HISTOGRAM_QUANTILES.labels(path).inc(groups)
+    M_HISTOGRAM_BUCKETS.inc(groups * buckets)
+    M_HISTOGRAM_FILLED.inc(filled)
+
+
 def count_window_dispatch(selected: int, padded: int, slab_w: int,
                           swept: int, programs: int = 1,
                           wide: bool = False, passes: int = 0,

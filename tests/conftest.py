@@ -47,6 +47,9 @@ _FAST_ROADS = {
     # whole-plan fused PromQL chain -> multi-kernel path
     "fusion": ("greptimedb_tpu.compile.fused.try_fused_aggregation",
                lambda ev, e: None),
+    # fused histogram_quantile -> the evaluated argument folded apart
+    "fused_histogram": ("greptimedb_tpu.compile.fused.try_fused_histogram",
+                        lambda ev, e, q: None),
     # packed-key radix merge of scan parts -> global lexsort
     "packed_merge": ("greptimedb_tpu.storage.scan._pack_keys",
                      lambda *a: None),

@@ -45,6 +45,11 @@ K8S_SERIES, K8S_SEL, K8S_MATCHED, K8S_GROUPS, K8S_W = (
 # padded rows (the values as two f32 words); every series matches (131,072
 # padded) into 200 namespaces, the same 128 samples each
 NET_ROWS, NET_SERIES, NET_SEL = 14_680_064, 126_000, 131_072
+# apiserver116k.resource_p99 (benchmark/configs/prom-apiserver-116k.json):
+# 116,424 bucket counters x 1 h at 30 s in a WIDE layout of the same padded
+# rows; the write rule keeps 79,200 series (131,072 padded), summed into
+# 200 resources x 22 buckets and folded to their 99th percentile
+API_SERIES, API_MATCHED, API_BUCKETS = 116_424, 79_200, 22
 
 
 @pytest.fixture(scope="module")
@@ -181,6 +186,18 @@ def _k8s_net_fused():
     return _build_fused(p, "rate", "sum", K8S_GROUPS, NET_SERIES, 300)
 
 
+def _apiserver_fused():
+    """histogram_quantile(0.99, sum by (le, resource)(rate(..._bucket[5m])))
+    over the wide layout: the window program, a segment sum into 4,401
+    slots and the fold, one program."""
+    from greptimedb_tpu.compile.fused import _build_fused
+
+    p = _window_params(30_000, 30_000, 120, NET_SEL, API_SERIES, K8S_W,
+                       wide=True)
+    return _build_fused(p, "rate", "sum", K8S_GROUPS * API_BUCKETS,
+                        API_MATCHED, 300, hist=(0.99, API_BUCKETS))
+
+
 def _segment(form, op, rows, sh):
     from greptimedb_tpu.ops import segment
 
@@ -205,6 +222,11 @@ CASES = {
         _k8s_net_fused(),
         _layout_args(sh, NET_SERIES, NET_SEL, NET_ROWS, value_words=2)
         + (_shape((NET_SERIES,), jnp.int32, sh),)),
+    "promql-fused-apiserver-131072": lambda sh: (
+        _apiserver_fused(),
+        _layout_args(sh, API_SERIES, NET_SEL, NET_ROWS, value_words=2)
+        + (_shape((API_MATCHED,), jnp.int32, sh),
+           _shape((API_BUCKETS,), jnp.float32, sh))),
     # the form `auto` takes on every backend, at table size
     "segment-scatter-mean": lambda sh: _segment("scatter", "mean", ROWS, sh),
     # the form only `force` reaches: its scan is minutes slow past this
@@ -217,7 +239,8 @@ CASES = {
 # max(W, 128)): what every [S, T, .] pass of the program may read
 SWEPT = {"promql-fused": (PROM_SEL, 61, PROM_W),
          "promql-fused-k8s-65536": (K8S_SEL, 61, K8S_W),
-         "promql-fused-k8snet-131072": (NET_SEL, 61, K8S_W)}
+         "promql-fused-k8snet-131072": (NET_SEL, 61, K8S_W),
+         "promql-fused-apiserver-131072": (NET_SEL, 61, K8S_W)}
 
 
 def _operands_of_step_passes(text: str, s: int, t: int) -> dict[str, int]:

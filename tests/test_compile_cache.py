@@ -395,6 +395,45 @@ class TestFusionParity:
         # BIT-exact: float cells compare with ==, not approx
         assert fused.rows == plain.rows
 
+    @pytest.mark.parametrize("grouping", ["le", "le, h"])
+    def test_fused_histogram_vs_unfused(self, db, grouping, ineligible):
+        """A seeded random histogram (cumulative bucket counters, a reset,
+        a bucket a host lacks): ``histogram_quantile`` over ``sum by (le,
+        …)(rate(…))`` as one fused program agrees with the evaluated
+        ``sum`` folded apart."""
+        from greptimedb_tpu.compile.fused import FUSED_DISPATCHES
+
+        if ["lat_bucket"] not in db.sql("SHOW TABLES").rows:
+            db.sql("CREATE TABLE lat_bucket (h STRING, le STRING, ts "
+                   "TIMESTAMP(3) TIME INDEX, v DOUBLE, PRIMARY KEY (h, le))")
+            rng = np.random.default_rng(5)
+            bounds = ["0.05", "0.1", "0.25", "0.5", "1", "2.5", "+Inf"]
+            rows = []
+            for hh in range(3):
+                obs = rng.poisson(4, size=(STEPS, len(bounds)))
+                cum = np.cumsum(np.cumsum(obs, axis=1), axis=0)
+                cum[200:] -= cum[199] if hh == 2 else 0  # a restart
+                for j, le in enumerate(bounds):
+                    if hh == 1 and le == "0.25":
+                        continue  # this host lacks a bucket
+                    rows += [f"('host_{hh}', '{le}', {T0 + i * 10_000}, "
+                             f"{float(cum[i, j])})" for i in range(STEPS)]
+            for c in range(0, len(rows), 500):
+                db.sql("INSERT INTO lat_bucket VALUES "
+                       + ",".join(rows[c:c + 500]))
+        q = _tql(f"histogram_quantile(0.9, sum by ({grouping}) "
+                 "(rate(lat_bucket[5m])))")
+        before = FUSED_DISPATCHES["count"]
+        fused = db.sql(q)
+        assert FUSED_DISPATCHES["count"] == before + 1
+        with ineligible("fused_histogram"):
+            plain = db.sql(q)
+        assert fused.column_names == plain.column_names
+        assert len(fused.rows) == len(plain.rows) > 0
+        for a, b in zip(fused.rows, plain.rows):
+            assert a[:-1] == b[:-1]
+            assert a[-1] == pytest.approx(b[-1], rel=1e-6)
+
     def test_unfusable_shapes_fall_back(self, db):
         from greptimedb_tpu.compile.fused import FUSED_DISPATCHES
 

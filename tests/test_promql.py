@@ -341,6 +341,81 @@ class TestBinaryOps:
         assert res.rows[0][-1] == pytest.approx(0.2, rel=1e-6)
 
 
+def bucket_quantile(q: float, buckets) -> float:
+    """Prometheus's ``bucketQuantile`` (promql/quantile.go) over one
+    histogram's (bound, cumulative count) pairs, in float64, written out
+    plainly; a rank within the engine's ``RANK_TOLERANCE`` of the total
+    above a bucket's count, with the next bucket empty, is a tie with it
+    (promql/engine.py says why)."""
+    from greptimedb_tpu.promql.engine import RANK_TOLERANCE
+
+    if np.isnan(q):
+        return np.nan
+    if q < 0:
+        return -np.inf
+    if q > 1:
+        return np.inf
+    merged: dict[float, float] = {}
+    for bound, count in buckets:          # equal bounds are one bucket
+        merged[bound] = merged.get(bound, 0.0) + count
+    bounds = sorted(merged)
+    if not bounds or bounds[-1] != np.inf:
+        return np.nan
+    counts, top = [], -np.inf
+    for b in bounds:                      # forced monotonic
+        top = max(top, merged[b])
+        counts.append(top)
+    if len(bounds) < 2 or counts[-1] == 0:
+        return np.nan
+    rank = q * counts[-1]
+    b = next((i for i in range(len(bounds) - 1)
+              if counts[i] >= rank - RANK_TOLERANCE * counts[-1]),
+             len(bounds) - 1)
+    if b == len(bounds) - 1:
+        return bounds[-2]
+    if counts[b] < rank:          # within the tolerance above bucket b
+        if counts[b + 1] == counts[b] or bounds[b + 1] == np.inf:
+            return bounds[b]
+        frac = (rank - counts[b]) / (counts[b + 1] - counts[b])
+        return bounds[b] + (bounds[b + 1] - bounds[b]) * min(frac, 1.0)
+    if b == 0 and bounds[0] <= 0:
+        return bounds[0]
+    start = 0.0 if b == 0 else bounds[b - 1]
+    below = 0.0 if b == 0 else counts[b - 1]
+    frac = (rank - below) / (counts[b] - below)
+    return start + (bounds[b] - start) * min(frac, 1.0)
+
+
+# (case, q, [(le, cumulative count)]) at one instant
+_HISTOGRAMS = {
+    "interpolation": [("0.1", 10.0), ("0.5", 55.0), ("1", 60.0),
+                      ("+Inf", 60.0)],
+    "rank_in_inf": [("0.1", 1.0), ("0.5", 2.0), ("+Inf", 10.0)],
+    "first_bound_negative": [("-1", 5.0), ("0", 8.0), ("+Inf", 10.0)],
+    "first_bound_zero": [("0", 5.0), ("1", 10.0), ("+Inf", 10.0)],
+    "non_monotonic": [("0.1", 10.0), ("0.5", 8.0), ("1", 20.0),
+                      ("+Inf", 20.0)],
+    "equal_bounds": [("0.5", 4.0), ("1", 3.0), ("1.0", 3.0),
+                     ("+Inf", 10.0)],
+    "no_inf": [("0.1", 1.0), ("1", 5.0)],
+    "single_bucket": [("+Inf", 5.0)],
+    "zero_total": [("0.1", 0.0), ("+Inf", 0.0)],
+    "empty_bucket_tie": [("0.1", 99.0), ("0.5", 99.0), ("1", 99.0),
+                         ("+Inf", 100.0)],
+    # q = 0.99: the rank 2^-12 above the first count, inside the tolerance
+    "tolerance_runs_on": [("0.1", 99.0 - 2.0 ** -12), ("0.5", 99.5),
+                          ("+Inf", 100.0)],
+    "tolerance_then_empty": [("0.1", 99.0 - 2.0 ** -12),
+                             ("0.5", 99.0 - 2.0 ** -12), ("+Inf", 100.0)],
+    "tolerance_then_inf": [("0.1", 99.0 - 2.0 ** -12), ("+Inf", 100.0)],
+    "bad_le": [("0.1", 2.0), ("fast", 7.0), ("+Inf", 4.0)],
+}
+_HQ_CASES = ([(name, q) for name in _HISTOGRAMS for q in (0.25, 0.5, 0.99)]
+             + [("interpolation", q)
+                for q in (-0.1, 0.0, 1.0, 1.1, float("nan"))]
+             + [("zero_total", -0.1), ("no_inf", 1.1)])
+
+
 class TestHistogramQuantile:
     def test_interpolation(self, db):
         db.sql("CREATE TABLE hist (le STRING, ts TIMESTAMP(3) TIME INDEX, val DOUBLE, PRIMARY KEY (le))")
@@ -352,6 +427,45 @@ class TestHistogramQuantile:
         # rank = 30 → bucket (0.1, 0.5]: 0.1 + (30-10)/(55-10)*0.4
         expect = 0.1 + (30 - 10) / (55 - 10) * 0.4
         assert res.rows[0][-1] == pytest.approx(expect, rel=1e-4)
+
+    @pytest.mark.parametrize("case,q", _HQ_CASES,
+                             ids=[f"{c}-{q}" for c, q in _HQ_CASES])
+    def test_against_bucket_quantile(self, db, case, q):
+        """Both roads — the bare selector (folded on the host's
+        numbering) and ``sum by (le, g)`` (the fused program) — give the
+        plain float64 ``bucketQuantile`` of the same buckets; a second
+        group with other buckets pads the first's slots."""
+        from greptimedb_tpu.compile.fused import FUSED_DISPATCHES
+
+        db.sql("CREATE TABLE hist (g STRING, le STRING, ts TIMESTAMP(3) "
+               "TIME INDEX, val DOUBLE, PRIMARY KEY (g, le))")
+        r = db._region_of("hist")
+        buckets = _HISTOGRAMS[case]
+        other = [("0.2", 3.0), ("+Inf", 6.0)]
+        for g, rows in (("a", buckets), ("b", other)):
+            for le, v in rows:
+                r.write({"g": [g], "le": [le], "ts": [1000], "val": [v]})
+
+        def parsed(rows):
+            out = []
+            for le, v in rows:
+                try:
+                    out.append((float(le), v))
+                except ValueError:
+                    pass
+            return out
+
+        want = {g: bucket_quantile(q, parsed(rows))
+                for g, rows in (("a", buckets), ("b", other))}
+        for arg in ("hist", "sum by (le, g) (hist)"):
+            before = FUSED_DISPATCHES["count"]
+            res = db.sql(
+                f"TQL EVAL (1, 1, '60') histogram_quantile({q}, {arg})")
+            assert (FUSED_DISPATCHES["count"] > before) == (arg != "hist")
+            got = {row[0]: row[-1] for row in res.rows}
+            assert set(got) == {g for g, v in want.items() if v == v}, arg
+            for g, v in got.items():
+                assert v == pytest.approx(want[g], rel=1e-6), (arg, g)
 
 
 class TestMiscFunctions:

@@ -358,6 +358,25 @@ def test_narrow_column_keeps_layout_key_and_program(db, w):
                         if name.endswith(f"-{w}")}
 
 
+def test_histogram_program_is_a_sibling():
+    """The fused histogram_quantile program (the ``sum`` program's body,
+    its G·K sums folded) leaves the ``sum`` program's text and key as
+    ``NARROW_PROGRAMS`` and ``NARROW_KEY`` have them, so no cell's
+    compile cache is re-keyed; its own text and key differ."""
+    sd = jax.ShapeDtypeStruct
+    narrow = _narrow_params("counter", 128)
+    hist = _lowered(
+        _build_fused(narrow, "rate", "sum", 3 * 2, 6, 300, hist=(0.99, 2)),
+        sd((6,), jnp.int32), sd((2,), jnp.float32))
+    summed = _lowered(_build_fused(narrow, "rate", "sum", 3, 6, 300),
+                      sd((6,), jnp.int32))
+    assert summed == NARROW_PROGRAMS["fused-rate-sum-128"] != hist
+    key = canon_key("promql", ("promql_fused", narrow, "rate", "sum", 6, 6,
+                               300, ("histogram_quantile", 0.99, 2, 3)))
+    assert key.startswith((NARROW_KEY % 128)[:-len(",i3,i6,i300)")])
+    assert key.endswith(",(s'histogram_quantile',f0.99,i2,i3))")
+
+
 if __name__ == "__main__":
     import json
 
